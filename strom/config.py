@@ -391,12 +391,6 @@ class StromConfig:
     fault_plan: str = ""
 
     # observability
-    trace_annotations: bool = True     # jax.profiler traces around delivery.
-                                       # Event-ring spans (strom/obs) are
-                                       # NOT gated here: the ring has its
-                                       # own switch, and all sites follow
-                                       # it uniformly so no stall bucket
-                                       # can be zeroed in isolation
     metrics_port: int = 0              # >0: StromContext serves /metrics
                                        # (Prometheus), /stats (JSON),
                                        # /trace (event-ring dump) and

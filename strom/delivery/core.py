@@ -1107,16 +1107,13 @@ class StromContext:
     def device_put(self, arr: np.ndarray, device: Any) -> Any:
         """One host->device dispatch under the context's put policy: the
         `serialize_device_put` lock (concurrent puts interleave poorly on a
-        shared host link) and the trace annotation. Pipelines route their
-        per-device shard puts here — including the decode path's overlapped
-        per-group puts — so every host->HBM byte obeys one policy."""
+        shared host link) and the ``strom.device_put`` span. Pipelines
+        route their per-device shard puts here — including the decode
+        path's overlapped per-group puts — so every host->HBM byte obeys
+        one policy."""
         import jax
 
-        from strom.utils.tracing import trace_span
-
-        with self._put_lock, \
-                trace_span("strom.device_put", cat="put",
-                           enabled=self.config.trace_annotations):
+        with self._put_lock, _request.span("strom.device_put", cat="put"):
             return jax.device_put(arr, device)
 
     def extent_map(self, path: str) -> list | None:
@@ -1751,8 +1748,6 @@ class StromContext:
         the same pieces put to each device)."""
         import jax
 
-        from strom.utils.tracing import trace_span
-
         chunk = self.config.overlap_chunk_bytes
         pieces = split_segments(segments, chunk)
         buf_shape = _assembly_shape(nbytes // np_dtype.itemsize,
@@ -1818,8 +1813,7 @@ class StromContext:
                 _, slab = item
                 arr_host = slab.view(np_dtype).reshape(-1, *buf_shape[1:])
                 with self._put_lock, \
-                        trace_span("strom.device_put", cat="put",
-                                   enabled=self.config.trace_annotations):
+                        _request.span("strom.device_put", cat="put"):
                     put_t0 = time.perf_counter()
                     for i, d in enumerate(devices):
                         piece = jax.device_put(arr_host, d)
@@ -1912,8 +1906,6 @@ class StromContext:
             label = f"{source!r}@{offset}"
 
         def run() -> Any:
-            from strom.utils.tracing import trace_span
-
             # slab recycling: only when device_put COPIES host bytes (every
             # real accelerator backend; the jax CPU backend aliases instead),
             # and released strictly after the transfer retires. Gate on the
@@ -1948,7 +1940,7 @@ class StromContext:
                             cfg.overlap_chunk_bytes // np_dtype.itemsize)
                         is not None)
 
-            with trace_span("strom.memcpy_ssd2tpu", enabled=cfg.trace_annotations):
+            with _request.span("strom.memcpy_ssd2tpu"):
                 if sharding is None:
                     if (self._hot_cache is not None
                             and self._hot_cache.enabled
@@ -1969,9 +1961,8 @@ class StromContext:
                             try:
                                 arr_host = view.view(np_dtype).reshape(shape)
                                 with self._put_lock, \
-                                        trace_span("strom.device_put",
-                                                   cat="put",
-                                                   enabled=cfg.trace_annotations):
+                                        _request.span("strom.device_put",
+                                                      cat="put"):
                                     out = jax.device_put(arr_host, device)
                                 out.block_until_ready()
                             finally:
@@ -1987,8 +1978,7 @@ class StromContext:
                                         dest, offset, tenant=tenant)
                     arr_host = dest.view(np_dtype).reshape(shape)
                     with self._put_lock, \
-                            trace_span("strom.device_put", cat="put",
-                                       enabled=cfg.trace_annotations):
+                            _request.span("strom.device_put", cat="put"):
                         out = jax.device_put(arr_host, device)  # device=None → default
                     if pool is not None:
                         out.block_until_ready()
@@ -2009,8 +1999,8 @@ class StromContext:
                         arr_host = dest.view(np_dtype).reshape(group[0].local_shape)
                         for p in group:
                             with self._put_lock, \
-                                    trace_span("strom.device_put", cat="put",
-                                               enabled=cfg.trace_annotations):
+                                    _request.span("strom.device_put",
+                                                  cat="put"):
                                 out.append(jax.device_put(arr_host, p.device))
                     except BaseException:
                         # recycle the slab on failure: dropping it silently

@@ -660,12 +660,15 @@ class DecodePool:
         time, because the worker thread has no contextvar of its own),
         else the plain ring span — or None when the ring is disabled
         (ISSUE 12 satellite: span construction is pure overhead with
-        telemetry off, and the fused-run micro numbers must not pay it)."""
+        telemetry off, and the fused-run micro numbers must not pay it).
+        Either span bills the worker's CPU time to the totals, a clock
+        read every ``CPU_LAP_SPANS`` images: wall well above CPU says the
+        decode threads wait for a core."""
         if req is not None:
-            return req.span("decode.worker", cat="decode")
+            return req.span("decode.worker", cat="decode", cpu=True)
         if not ring.enabled:
             return None
-        return ring.span("decode.worker", cat="decode")
+        return ring.span("decode.worker", cat="decode", cpu=True)
 
     def run_size(self, n: int) -> int:
         """Fused-run length for an *n*-sample batch: enough samples per

@@ -134,8 +134,8 @@ class Request:
                   "req")
 
     def record(self, name: str, cat: str, ts_us: float, dur_us: float,
-               args: "dict | None" = None, parent: "str | None" = None
-               ) -> None:
+               args: "dict | None" = None, parent: "str | None" = None,
+               cpu_us: float = 0.0) -> None:
         """One finished span: ring emission (req/parent in args) + tree
         append. The explicit-timestamp twin of :meth:`span` for callers
         that measured the window themselves (scheduler queue waits)."""
@@ -146,7 +146,7 @@ class Request:
         if args:
             full.update(args)
         self._flow(name, cat)
-        ring.complete(ts_us, dur_us, cat, name, full)
+        ring.complete(ts_us, dur_us, cat, name, full, cpu_us)
         with self._lock:
             if len(self.spans) < MAX_SPANS_PER_REQUEST:
                 self.spans.append((name, cat, round(ts_us, 1),
@@ -155,9 +155,11 @@ class Request:
                 self.spans_dropped += 1
 
     @contextlib.contextmanager
-    def span(self, name: str, cat: str = "", args: "dict | None" = None):
+    def span(self, name: str, cat: str = "", args: "dict | None" = None, *,
+             cpu: bool = False):
         """Record the with-block as one parent-linked request span (parent =
-        the innermost still-open request span on THIS thread)."""
+        the innermost still-open request span on THIS thread); *cpu* as in
+        :meth:`EventRing.span`."""
         tid = threading.get_ident()
         with self._lock:
             stack = self._open.setdefault(tid, [])
@@ -167,12 +169,13 @@ class Request:
         try:
             yield
         finally:
+            cpu_us = ring.span_cpu_us() if cpu else 0.0
             with self._lock:
                 st = self._open.get(tid)
                 if st and st[-1] == name:
                     st.pop()
             self.record(name, cat, t0, ring.now_us() - t0, args,
-                        parent=parent)
+                        parent=parent, cpu_us=cpu_us)
 
     def parent_of(self, tid: "int | None" = None) -> "str | None":
         """The innermost open request span on *tid* (default: the calling

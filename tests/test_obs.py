@@ -73,6 +73,97 @@ class TestEventRing:
         r.clear()
         assert r.snapshot() == [] and r.events_dropped == 0
 
+    def test_span_totals_count_wall_and_cpu(self):
+        r = EventRing(capacity=16)
+        r.complete(0.0, 5.0, "read", "a")
+        r.complete(1.0, 7.5, "read", "a", cpu_us=2.0)
+        r.instant("a")           # instants and flows are not spans
+        r.flow("s", 1, "a")
+        r.cpu_lap_us()           # the lap before the span starts
+        with r.span("b", cpu=True):
+            x = 0
+            for i in range(20000):
+                x += i
+        tot = r.totals()
+        assert tot["a"] == (2, 12.5, 2.0)
+        count, wall, cpu = tot["b"]
+        assert count == 1 and wall > 0 and 0 < cpu <= wall + 1000
+        # without cpu= a span adds wall time only
+        with r.span("c"):
+            pass
+        assert r.totals()["c"][2] == 0.0
+
+    def test_cpu_spans_read_the_clock_once_per_lap(self, monkeypatch):
+        from strom.obs import events
+
+        reads = []
+
+        def clock():
+            reads.append(1)
+            return len(reads) * 1e-3
+
+        monkeypatch.setattr(events.time, "thread_time", clock)
+        r = EventRing(capacity=8)
+        for _ in range(2 * events.CPU_LAP_SPANS + 8):
+            with r.span("w", cpu=True):
+                pass
+        # spans 1, 17 and 33 read it; each lap bills 1 ms
+        assert len(reads) == 3
+        assert r.totals()["w"][0] == 40 and r.totals()["w"][2] == 3000.0
+
+    def test_span_totals_survive_wrap(self):
+        r = EventRing(capacity=8)
+        for i in range(20):
+            r.complete(float(i), 2.0, "decode", "w", cpu_us=1.0)
+        assert len(r) == 8 and r.events_dropped == 12
+        assert r.totals() == {"w": (20, 40.0, 20.0)}
+        r.clear()
+        assert r.totals() == {}
+
+    def test_disabled_ring_adds_no_totals(self):
+        r = EventRing(capacity=8, enabled=False)
+        with r.span("x", cpu=True):
+            pass
+        r.complete(0.0, 3.0, "read", "y")
+        assert r.totals() == {} and len(r) == 0
+
+    def test_anchor_maps_ring_onto_profiler_clock(self, tmp_path):
+        """A ring span and an annotation opened back to back inside an
+        anchor start within 100 us of each other once the anchor's start
+        and end pairs map ring time onto the profiler's clock."""
+        import glob
+        import time
+
+        import jax
+        from jax.profiler import ProfileData, TraceAnnotation
+
+        r = EventRing(capacity=64)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with r.anchor("obs.test.window"):
+                time.sleep(0.01)
+                with r.span("obs.test.ring"), \
+                        TraceAnnotation("obs.test.annot"):
+                    time.sleep(0.005)
+                time.sleep(0.01)
+        finally:
+            jax.profiler.stop_trace()
+        xplane, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                            recursive=True)
+        prof = {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+                for plane in ProfileData.from_file(xplane).planes
+                if plane.name.startswith("/host:")
+                for line in plane.lines for e in line.events
+                if e.name.startswith("obs.test.")}
+        ring = {e["name"]: e for e in r.snapshot()}
+        win = ring["obs.test.window"]
+        assert win["cat"] == "anchor"
+        a0, a1 = win["ts_us"], win["ts_us"] + win["dur_us"]
+        (p0, p1) = prof["obs.test.window"]
+        ns_per_us = (p1 - p0) / (a1 - a0)
+        mapped = p0 + (ring["obs.test.ring"]["ts_us"] - a0) * ns_per_us
+        assert abs(mapped - prof["obs.test.annot"][0]) < 100e3
+
     def test_concurrent_writers_never_corrupt(self):
         r = EventRing(capacity=64)
 
@@ -88,6 +179,31 @@ class TestEventRing:
         evs = r.snapshot()
         assert len(evs) == 64 + 1  # 64 retained + the events_dropped marker
         assert r.events_dropped == 4 * 500 - 64
+
+    def test_concurrent_span_totals_lose_no_update(self):
+        import os
+        import sys
+
+        r = EventRing(capacity=32)
+        n = (os.cpu_count() or 4) + 4
+
+        def spam():  # each name's first span races its insertion
+            for i in range(500):
+                r.complete(0.0, 1.0, "read", f"t{i % 100}", cpu_us=2.0)
+
+        prev = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            ts = [threading.Thread(target=spam) for _ in range(n)]
+            for t in ts:
+                t.start()
+            for t in ts:
+                t.join(timeout=60)
+        finally:
+            sys.setswitchinterval(prev)
+        assert not any(t.is_alive() for t in ts)
+        assert r.totals() == {f"t{i}": (n * 5, n * 5.0, n * 10.0)
+                              for i in range(100)}
 
 
 class TestChromeTrace:
@@ -363,43 +479,57 @@ class TestMetricsServer:
 
 class TestWiring:
     """The instrumentation sites actually emit: one pread lights up the
-    read spans; a context exposes the steps section; trace_span feeds the
-    ring even without a jax profiler session."""
+    read spans; a context exposes the steps section; the put span is a
+    ring span, request-linked inside a request."""
 
     def test_trace_span_dual_emit(self):
-        from strom.obs.events import ring as groll
-        from strom.utils.tracing import trace_span
+        """The context's put span lands in the ring (the program's one
+        record of spans) and, inside a request, joins its lane: req id in
+        the args, a flow event of the same id."""
+        import jax
 
-        before = len(groll)
-        with trace_span("obs.test.span", cat="put"):
-            pass
-        evs = [e for e in groll.snapshot()
-               if e["name"] == "obs.test.span"]
-        assert evs and evs[-1]["cat"] == "put"
-        assert len(groll) > before
+        from strom.config import StromConfig
+        from strom.delivery.core import StromContext
+        from strom.obs import request as _request
+        from strom.obs.events import ring as groll
+
+        ctx = StromContext(StromConfig(engine="python"))
+        try:
+            with _request.active("batch") as req:
+                ctx.device_put(np.arange(4, dtype=np.uint8),
+                               jax.devices()[0])
+        finally:
+            ctx.close()
+        evs = groll.snapshot()
+        puts = [e for e in evs if e["name"] == "strom.device_put"
+                and e.get("args", {}).get("req") == req.id]
+        assert len(puts) == 1 and puts[0]["cat"] == "put"
+        assert any(e["ph"] in ("s", "t") and e["id"] == req.id for e in evs)
 
     def test_trace_span_enabled_false_still_feeds_ring(self):
-        """enabled= gates the jax annotation only: turning annotations off
-        must not zero the put bucket while directly-instrumented sites
-        (read/decode/step) keep recording."""
+        """Outside a request the put span is a plain ring span, and it
+        adds to the span totals like every other site."""
+        from strom.obs import request as _request
         from strom.obs.events import ring as groll
-        from strom.utils.tracing import trace_span
 
-        with trace_span("obs.test.annot_off", cat="put", enabled=False):
+        before = groll.totals().get("obs.test.plain", (0, 0.0, 0.0))[0]
+        with _request.span("obs.test.plain", cat="put"):
             pass
-        assert any(e["name"] == "obs.test.annot_off"
-                   for e in groll.snapshot())
+        ev = [e for e in groll.snapshot() if e["name"] == "obs.test.plain"]
+        assert ev and ev[-1]["cat"] == "put" and "args" not in ev[-1]
+        assert groll.totals()["obs.test.plain"][0] == before + 1
 
     def test_trace_span_respects_ring_switch(self):
+        from strom.obs import request as _request
         from strom.obs.events import ring as groll
-        from strom.utils.tracing import trace_span
 
         groll.enabled = False
         try:
             before = len(groll)
-            with trace_span("obs.test.ring_off", cat="put"):
+            with _request.span("obs.test.ring_off", cat="put"):
                 pass
             assert len(groll) == before
+            assert "obs.test.ring_off" not in groll.totals()
         finally:
             groll.enabled = True
 
@@ -472,6 +602,37 @@ class TestWiring:
         decs = [e for e in groll.snapshot()
                 if e["name"] == "decode.worker" and e["cat"] == "decode"]
         assert len(decs) >= 4
+
+    @pytest.mark.parametrize("in_request", [False, True])
+    def test_decode_worker_totals_carry_thread_cpu(self, in_request):
+        """Each decode span adds its worker's CPU time since the span
+        before to the ``decode.worker`` totals, plain or request-linked:
+        the decode, and a fresh worker's start-up, but no idle time."""
+        import contextlib
+
+        from strom.formats.jpeg import DecodePool
+        from strom.obs import request as _request
+        from strom.obs.events import ring as groll
+
+        def tf(item, rng_, out=None):
+            acc = np.zeros(4096)
+            for _ in range(50):
+                acc = np.sqrt(acc + item)
+            out[...] = int(acc[0]) % 256
+            return out
+
+        c0, w0, u0 = groll.totals().get("decode.worker", (0, 0.0, 0.0))
+        pool = DecodePool(workers=2)
+        try:
+            out = np.zeros((6, 2, 2, 3), dtype=np.uint8)
+            with (_request.active("batch") if in_request
+                  else contextlib.nullcontext()):
+                pool.map_into(tf, [1, 2, 3, 4, 5, 6], [None] * 6, out)
+        finally:
+            pool.close()
+        c1, w1, u1 = groll.totals()["decode.worker"]
+        assert c1 - c0 >= 6
+        assert 0 < u1 - u0 <= (w1 - w0) + 5000
 
     def test_prefetcher_stall_events_and_global_gauge(self):
         import time as _time
