@@ -2126,7 +2126,8 @@ class StromContext:
     def pread(self, source: "Source", offset: int = 0,
               length: int | None = None, *,
               tenant: str | None = None,
-              deadline_s: "float | None" = None) -> np.ndarray:
+              deadline_s: "float | None" = None,
+              dest: "np.ndarray | None" = None) -> np.ndarray:
         """Read bytes from *source* into a fresh aligned host slab (no device
         transfer). The staging path format readers use for metadata and member
         payloads before decode. *deadline_s* arms a request deadline: the
@@ -2134,7 +2135,9 @@ class StromContext:
         engine (default: config ``request_deadline_s``; 0/None = none).
         The deadline binds the gather-kind request this read mints; inside
         an enclosing traced request (a pipeline batch/step) the enclosing
-        contract stands and *deadline_s* is not applied."""
+        contract stands and *deadline_s* is not applied. *dest* (a flat
+        uint8 array of at least the length) receives the bytes instead of
+        a fresh slab: the decode pool's shared memory, for one."""
         if self._closed:
             raise RuntimeError("StromContext is closed")
         source = self.resolve_source(source)
@@ -2142,10 +2145,13 @@ class StromContext:
             length = source_size(source) - offset
         if length == 0:
             return np.empty(0, dtype=np.uint8)
-        dest = alloc_aligned(length)
-        if self._numa is not None and \
-                self._numa.resolve(self._numa_path(source)) is not None:
-            self._numa.bind(dest)
+        if dest is not None:
+            dest = dest[:length]
+        else:
+            dest = alloc_aligned(length)
+            if self._numa is not None and \
+                    self._numa.resolve(self._numa_path(source)) is not None:
+                self._numa.bind(dest)
         self._read_segments(source, [Segment(0, 0, length)], dest, offset,
                             tenant=tenant, deadline_s=deadline_s)
         return dest
@@ -2319,6 +2325,17 @@ class StromContext:
                     global_stats.counter("decode_cache_hit_bytes").value,
                 "decode_cache_admitted_bytes":
                     global_stats.counter("decode_cache_admitted_bytes").value,
+                # the two executors (strom/formats/decode_procs.py): samples
+                # decoded in worker processes and on threads, worker
+                # restarts, and the parent's own time on process tasks
+                "decode_proc_samples":
+                    global_stats.counter("decode_proc_samples").value,
+                "decode_thread_samples":
+                    global_stats.counter("decode_thread_samples").value,
+                "decode_proc_restarts":
+                    global_stats.counter("decode_proc_restarts").value,
+                "decode_proc_parent_us":
+                    global_stats.counter("decode_proc_parent_us").value,
                 "decode_batch_p50_us": dh.percentile(0.50),
                 "decode_batch_mean_us": dh.mean_us,
                 "decode_batch_total_us": dh.total_us,

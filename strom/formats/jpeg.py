@@ -1,13 +1,24 @@
 """JPEG host decode + ImageNet-style transforms (SURVEY.md §7.2 step 7:
 "JPEG (host decode worker pool)").
 
-The engine lands compressed bytes in host slabs; decode runs on a thread pool
-(cv2 releases the GIL inside imdecode, so threads scale) and the decoded
-uint8 tensor is what gets `device_put`.  Keeping decode on host mirrors the
-division of labor in the reference's consumer (PG-Strom decompresses on GPU —
-strom-tpu instead keeps the TPU's MXU for the model and spends host cores on
-decode; the "0 data-stall" overlap hides both).  Consumer: the ResNet-50
-pipeline (BASELINE config #2, BASELINE.json:8).
+The engine lands compressed bytes in host slabs; decode runs on a host
+worker pool and the decoded uint8 tensor is what gets `device_put`.  Keeping
+decode on host mirrors the division of labor in the reference's consumer
+(PG-Strom decompresses on GPU — strom-tpu instead keeps the TPU's MXU for
+the model and spends host cores on decode; the "0 data-stall" overlap hides
+both).  Consumer: the ResNet-50 pipeline (BASELINE config #2,
+BASELINE.json:8).
+
+The pool has two executors for one algorithm (the algorithm itself lives in
+:mod:`strom.formats.jpeg_core`). The native decode, ``cv2.resize`` and
+``cv2.flip`` release the interpreter lock, but the Python around each image
+(header parse, crop geometry, RNG, counters, span, future) holds it, and on
+threads that work competes with the training loop for the one lock that
+launches its step: on a TPU v5e host 13 decode threads stretched the
+ResNet-50 step's dispatch from 7 to 126 ms. So the built-in train transform
+runs in worker processes (:mod:`strom.formats.decode_procs`), with image
+bytes and output rows in shared memory; everything the processes cannot
+take stays on threads (see :class:`DecodePool`).
 
 Decode-path scheduling (ISSUE 2 tentpole):
 
@@ -49,65 +60,42 @@ Decode path v2 (ISSUE 12 tentpole; knobs ``decode_native`` /
   partial-scanline API silently produces wrong pixels on multi-scan files
   (:func:`parse_jpeg_info` carries the flag).
 - **Fused-run dispatch**: :meth:`DecodePool.submit_run_into` decodes a run
-  of samples per pool task, amortizing the per-task queue/contextvar/span
-  overhead that dominates at ~1ms/image; run length auto-tunes from the
-  pool's per-image decode-time EWMA (seeded off the same timing stream the
-  ``decode_batch`` histogram records) and is capped for load balance.
+  of samples per pool task, amortizing the per-task dispatch overhead;
+  run length auto-tunes from the pool's per-image decode-time EWMA (on
+  processes, the workers' own wall time per image) and is capped for load
+  balance.
 - **Decoded-output cache**: with a :class:`~strom.formats.decoded_cache.
   DecodedCache` attached (pipelines build one over the hot cache when
   ``decode_cache`` is on), the transform serves post-decode full-frame
   pixels from RAM on repeat epochs and admits them on first decode —
   epoch >= 2 runs at predecoded speed (see decoded_cache.py for keying and
-  budget accounting).
+  budget accounting). Its frames live in this process, so such a
+  transform decodes on threads.
 """
 
 from __future__ import annotations
 
 import concurrent.futures
 import ctypes
+import math
 import os
-import threading
 import time
-from typing import Callable, Iterable, NamedTuple, Sequence
+from typing import Callable, Iterable, Sequence
 
 import numpy as np
 
 from strom.formats.decoded_cache import ServedFrame
+from strom.formats.jpeg_core import (  # noqa: F401  (the public names)
+    _HAVE_CV2, _resize_into, bind_native, center_crop_resize, decode_jpeg,
+    finish_crop, parse_jpeg_dims, parse_jpeg_info, random_resized_crop,
+    reduced_denom, sample_rrc_geometry, train_crop)
 from strom.obs.events import ring
 from strom.utils.stats import global_stats
 from strom.utils.locks import make_lock
 
-try:
+if _HAVE_CV2:
     import cv2
 
-    _HAVE_CV2 = True
-# stromlint: ignore[swallowed-exceptions] -- capability probe: cv2 can
-# fail to import with non-ImportError (missing libGL raises OSError);
-# either way the flag flips and every decode path branches on it
-except Exception:  # pragma: no cover - cv2 is present in the target image
-    _HAVE_CV2 = False
-
-try:
-    from PIL import Image
-    import io
-
-    _HAVE_PIL = True
-# stromlint: ignore[swallowed-exceptions] -- capability probe, same
-# contract as the cv2 probe above: the flag is the observable outcome
-except Exception:  # pragma: no cover
-    _HAVE_PIL = False
-
-
-# -- SOF header parsing (no decode) -----------------------------------------
-
-# SOF0..SOF15 carry frame dimensions, except DHT (C4), JPG (C8), DAC (CC)
-_SOF_MARKERS = frozenset(range(0xC0, 0xD0)) - {0xC4, 0xC8, 0xCC}
-# the multi-scan (progressive) subset: SOF2/6 (Huffman), SOF10/14
-# (arithmetic). These decode fine at full/reduced scale, but the turbo
-# partial-scanline API (jpeg_crop_scanline/jpeg_skip_scanlines) silently
-# produces WRONG pixels on them — the router must send progressive members
-# down the full-decode path (ISSUE 12 satellite).
-_PROGRESSIVE_MARKERS = frozenset({0xC2, 0xC6, 0xCA, 0xCE})
 
 # bench-JSON columns the decode-v2 phase set emits (cli._decode2_phases),
 # single-sourced so the driver's per-arm copy loop (bench.py), the
@@ -132,79 +120,6 @@ DECODE2_FIELDS = (
 )
 
 
-class JpegInfo(NamedTuple):
-    """SOF frame header facts: dimensions plus the progressive flag the
-    ROI router branches on."""
-
-    h: int
-    w: int
-    progressive: bool
-
-
-def parse_jpeg_info(data: bytes | np.ndarray) -> JpegInfo | None:
-    """Frame dims + progressive flag from a JPEG's SOF header, walking
-    marker segments only — no entropy decode, no IDCT. Returns None for
-    anything that is not parseable JPEG (PNG members, truncated headers):
-    callers fall back to the full-scale decode path, which raises its own
-    clear error."""
-    if isinstance(data, np.ndarray):
-        b = data.view(np.uint8).reshape(-1)
-    else:
-        b = np.frombuffer(data, dtype=np.uint8)
-    n = b.shape[0]
-    if n < 4 or b[0] != 0xFF or b[1] != 0xD8:
-        return None
-    i = 2
-    while i + 3 < n:
-        if b[i] != 0xFF:
-            return None  # desynced: not walking marker segments anymore
-        marker = int(b[i + 1])
-        if marker == 0xFF:  # fill byte before a marker
-            i += 1
-            continue
-        if marker == 0x01 or 0xD0 <= marker <= 0xD7:  # standalone TEM/RSTn
-            i += 2
-            continue
-        if marker in (0xD9, 0xDA):  # EOI / SOS before any SOF: give up
-            return None
-        seg_len = (int(b[i + 2]) << 8) | int(b[i + 3])
-        if seg_len < 2:
-            return None
-        if marker in _SOF_MARKERS:
-            if i + 9 > n:
-                return None
-            h = (int(b[i + 5]) << 8) | int(b[i + 6])
-            w = (int(b[i + 7]) << 8) | int(b[i + 8])
-            if h <= 0 or w <= 0:
-                return None
-            return JpegInfo(h, w, marker in _PROGRESSIVE_MARKERS)
-        i += 2 + seg_len
-    return None
-
-
-def parse_jpeg_dims(data: bytes | np.ndarray) -> tuple[int, int] | None:
-    """(height, width) from a JPEG's SOF header (see
-    :func:`parse_jpeg_info`, which also carries the progressive flag)."""
-    info = parse_jpeg_info(data)
-    return None if info is None else (info.h, info.w)
-
-
-def reduced_denom(h: int, w: int, size: int) -> int:
-    """Largest decode denominator d in (8, 4, 2) at which an (h, w) crop
-    still covers the size×size target: min(h, w) >= size * d. Callers pass
-    the CROP rectangle's dimensions, not the encoded image's — a reduced
-    decode whose crop region lands below the target size would be bilinearly
-    UPSCALED where the full path downsamples real pixels, a silent training
-    -quality regression. 1 = decode full scale."""
-    if size <= 0:
-        return 1
-    shorter = min(h, w)
-    for d in (8, 4, 2):
-        if shorter >= size * d:
-            return d
-    return 1
-
-
 # -- native libjpeg-turbo binding (ISSUE 12 tentpole) ------------------------
 
 # lazy resolution state: None = resolved-and-absent, callable = resolved;
@@ -213,6 +128,9 @@ def reduced_denom(h: int, w: int, size: int) -> int:
 # resolution can never entangle with the core build lock hierarchy.
 _NATIVE_UNRESOLVED = object()
 _native_decode: "Callable | None | object" = _NATIVE_UNRESOLVED
+# the library the live binding was loaded from: decode worker processes
+# load the same file
+_native_lib = ""
 
 
 def _resolve_native() -> "Callable | None":
@@ -220,29 +138,20 @@ def _resolve_native() -> "Callable | None":
     unavailable (no libjpeg-turbo headers at build time, no compiler, a
     poisoned include path, ...). Import of this module never builds or
     loads anything — the first *access* of ``decode_native`` does."""
-    global _native_decode
+    global _native_decode, _native_lib
     if _native_decode is not _NATIVE_UNRESOLVED:
         return _native_decode  # type: ignore[return-value]
     fn: "Callable | None" = None
     try:
         from strom._core.build import ensure_built
 
-        lib = ctypes.CDLL(ensure_built())
-        if lib.sc_jpeg_available() == 1:
-            lib.sc_jpeg_decode.restype = ctypes.c_int
-            lib.sc_jpeg_decode.argtypes = [
-                ctypes.c_void_p, ctypes.c_uint64, ctypes.c_void_p,
-                ctypes.c_uint64, ctypes.c_int64, ctypes.c_int32,
-                ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-                ctypes.c_int32, ctypes.POINTER(ctypes.c_int32)]
-
-            def fn(data, *, reduced=1, roi=None, out=None,  # type: ignore[misc]
-                   _lib=lib):
-                return _decode_native_call(_lib, data, reduced=reduced,
-                                           roi=roi, out=out)
+        path = ensure_built()
+        fn = bind_native(ctypes.CDLL(path))
+        if fn is not None:
+            _native_lib = path
     # stromlint: ignore[swallowed-exceptions] -- capability probe, same
-    # contract as the cv2/PIL probes above: build/link/dlopen failure of
-    # the OPTIONAL native path resolves to None and callers keep cv2
+    # contract as the cv2/PIL probes: build/link/dlopen failure of the
+    # OPTIONAL native path resolves to None and callers keep cv2
     except Exception:
         fn = None
     _native_decode = fn
@@ -254,69 +163,6 @@ def native_available() -> bool:
     return _resolve_native() is not None
 
 
-# horizontal widening for ROI decodes: jpeg_crop_scanline grants an
-# iMCU-aligned superset, but fancy upsampling lacks context at the granted
-# boundary — its left/rightmost output columns can differ from a full
-# decode. Requesting 2 extra columns each side keeps the RETURNED rect
-# strictly interior (where partial decode is bit-exact against full),
-# except at true image edges, where full decode has no context either.
-_ROI_X_MARGIN = 2
-
-
-def _decode_native_call(lib, data, *, reduced: int = 1,
-                        roi: "tuple[int, int, int, int] | None" = None,
-                        out: "np.ndarray | None" = None) -> np.ndarray:
-    """ctypes shim over ``sc_jpeg_decode``. With *roi* = (y, x, h, w) in
-    SCALED (post-*reduced*) coordinates, decodes only the crop's scanlines
-    / iMCU columns and returns exactly the requested (h, w, 3) rect (a view
-    into a fresh decode buffer). Without, returns the full (scaled) frame,
-    into *out* when given. Raises ValueError on anything undecodable —
-    same contract as :func:`decode_jpeg`, so the pool's per-sample failure
-    policy applies unchanged."""
-    buf = np.frombuffer(data, dtype=np.uint8) \
-        if isinstance(data, (bytes, bytearray, memoryview)) \
-        else data.view(np.uint8).reshape(-1)
-    if not buf.flags.c_contiguous:
-        buf = np.ascontiguousarray(buf)
-    info = parse_jpeg_info(buf)
-    if info is None:
-        raise ValueError("not a decodable image")
-    oh, ow = -(-info.h // reduced), -(-info.w // reduced)
-    got = (ctypes.c_int32 * 4)()
-    if roi is None:
-        dst = out
-        if dst is None:
-            dst = np.empty((oh, ow, 3), dtype=np.uint8)
-        elif dst.shape != (oh, ow, 3) or dst.dtype != np.uint8 \
-                or not dst.flags.c_contiguous:
-            raise ValueError("out must be a C-contiguous uint8 array of "
-                             f"shape {(oh, ow, 3)}")
-        rc = lib.sc_jpeg_decode(buf.ctypes.data, buf.size, dst.ctypes.data,
-                                dst.nbytes, ow * 3, reduced,
-                                0, 0, 0, 0, got)
-        if rc != 0 or (got[0], got[1]) != (oh, ow):
-            raise ValueError(f"native jpeg decode failed (rc={rc})")
-        return dst
-    y, x, h, w = roi
-    if not (0 <= y and 0 <= x and h > 0 and w > 0
-            and y + h <= oh and x + w <= ow):
-        raise ValueError(f"roi {roi} outside scaled frame {(oh, ow)}")
-    rx = max(x - _ROI_X_MARGIN, 0)
-    rw = min(x + w + _ROI_X_MARGIN, ow) - rx
-    # granted width exceeds the request by at most one iMCU each side —
-    # up to 32px with h_samp_factor 4 (4:1:1/4:1:0 chroma), so budget 62
-    # extra columns; rows pack at the granted width (stride <= 0 in the
-    # C ABI) and the capacity check there rejects anything wider
-    flat = np.empty(h * (rw + 64) * 3, dtype=np.uint8)
-    rc = lib.sc_jpeg_decode(buf.ctypes.data, buf.size, flat.ctypes.data,
-                            flat.nbytes, 0, reduced, y, rx, h, rw, got)
-    if rc != 0:
-        raise ValueError(f"native jpeg roi decode failed (rc={rc})")
-    gh, gw, gx0, _ = got
-    img = flat[: gh * gw * 3].reshape(gh, gw, 3)
-    return img[:, x - gx0: x - gx0 + w]
-
-
 def __getattr__(name: str):
     """PEP 562: ``jpeg.decode_native`` resolves the native binding on first
     access (None when absent — the ISSUE 12 build-probe fallback contract)
@@ -324,138 +170,6 @@ def __getattr__(name: str):
     if name == "decode_native":
         return _resolve_native()
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-def decode_jpeg(data: bytes | np.ndarray, *, reduced: int = 1) -> np.ndarray:
-    """Decode JPEG/PNG bytes → HWC uint8 RGB array.
-
-    *reduced* in (2, 4, 8) decodes JPEGs at 1/reduced scale (libjpeg
-    skips the corresponding IDCT work); the caller owns rescaling any
-    crop geometry onto the reduced image (:func:`make_train_transform`).
-    """
-    if _HAVE_CV2:
-        flag = {1: cv2.IMREAD_COLOR,
-                2: cv2.IMREAD_REDUCED_COLOR_2,
-                4: cv2.IMREAD_REDUCED_COLOR_4,
-                8: cv2.IMREAD_REDUCED_COLOR_8}[reduced]
-        buf = np.frombuffer(data, dtype=np.uint8) if isinstance(data, (bytes, memoryview)) \
-            else data.view(np.uint8).reshape(-1)
-        img = cv2.imdecode(buf, flag)
-        if img is None:
-            raise ValueError("not a decodable image")
-        return cv2.cvtColor(img, cv2.COLOR_BGR2RGB)
-    if _HAVE_PIL:
-        raw = data.tobytes() if isinstance(data, np.ndarray) else bytes(data)
-        try:
-            with Image.open(io.BytesIO(raw)) as im:
-                if reduced > 1:
-                    # draft mode: JPEG power-of-2 reduced decode, same trick
-                    im.draft("RGB", (max(1, im.width // reduced),
-                                     max(1, im.height // reduced)))
-                return np.asarray(im.convert("RGB"))
-        except Exception as e:  # UnidentifiedImageError etc. → one contract
-            raise ValueError("not a decodable image") from e
-    raise RuntimeError("no JPEG decoder available (need cv2 or PIL)")
-
-
-def _resize(img: np.ndarray, h: int, w: int) -> np.ndarray:
-    if _HAVE_CV2:
-        return cv2.resize(img, (w, h), interpolation=cv2.INTER_LINEAR)
-    return np.asarray(Image.fromarray(img).resize((w, h), Image.BILINEAR))
-
-
-def _resize_into(img: np.ndarray, size: int,
-                 out: np.ndarray | None) -> np.ndarray:
-    """Bilinear resize to size x size, into *out* when given (cv2 writes the
-    pixels straight into the destination row — the zero-copy half of the
-    slot-decode story)."""
-    if out is None:
-        return _resize(img, size, size)
-    if _HAVE_CV2:
-        cv2.resize(img, (size, size), dst=out,
-                   interpolation=cv2.INTER_LINEAR)
-    else:
-        out[:] = _resize(img, size, size)
-    return out
-
-
-def _flip_h(dst: np.ndarray, out: np.ndarray | None) -> np.ndarray:
-    """Horizontal flip; in place (cv2.flip supports src==dst) on the slot
-    path, a fresh contiguous mirror otherwise — values identical."""
-    if out is None:
-        return np.ascontiguousarray(dst[:, ::-1])
-    if _HAVE_CV2:
-        cv2.flip(dst, 1, dst=dst)
-    else:
-        dst[:] = dst[:, ::-1].copy()
-    return dst
-
-
-def center_crop_resize(img: np.ndarray, size: int,
-                       *, resize_shorter: int | None = None) -> np.ndarray:
-    """Eval transform: resize shorter side (default size*1.15), center crop."""
-    shorter = resize_shorter or int(size * 1.15)
-    h, w = img.shape[:2]
-    scale = shorter / min(h, w)
-    img = _resize(img, max(size, round(h * scale)), max(size, round(w * scale)))
-    h, w = img.shape[:2]
-    top, left = (h - size) // 2, (w - size) // 2
-    return img[top: top + size, left: left + size]
-
-
-def sample_rrc_geometry(h: int, w: int, rng: np.random.Generator,
-                        *, scale: tuple[float, float] = (0.08, 1.0),
-                        ratio: tuple[float, float] = (3 / 4, 4 / 3)
-                        ) -> tuple[int, int, int, int]:
-    """(top, left, crop_h, crop_w) of an Inception-style random area/aspect
-    crop in (h, w) coordinates; falls back to the center square. Pure RNG +
-    arithmetic — the full-scale and reduced-scale decode paths both sample
-    here in FULL-resolution coordinates, so their random streams (and
-    therefore checkpoint-resume determinism) are identical."""
-    area = h * w
-    for _ in range(10):
-        target = area * rng.uniform(*scale)
-        log_r = rng.uniform(np.log(ratio[0]), np.log(ratio[1]))
-        ar = np.exp(log_r)
-        cw = round(np.sqrt(target * ar))
-        ch = round(np.sqrt(target / ar))
-        if 0 < cw <= w and 0 < ch <= h:
-            top = int(rng.integers(0, h - ch + 1))
-            left = int(rng.integers(0, w - cw + 1))
-            return top, left, ch, cw
-    side = min(h, w)
-    return (h - side) // 2, (w - side) // 2, side, side
-
-
-def random_resized_crop(img: np.ndarray, size: int, rng: np.random.Generator,
-                        *, scale: tuple[float, float] = (0.08, 1.0),
-                        ratio: tuple[float, float] = (3 / 4, 4 / 3),
-                        out: np.ndarray | None = None) -> np.ndarray:
-    """Train transform: Inception-style random area/aspect crop → size×size,
-    plus a horizontal flip coin. With *out*, the result lands in the given
-    row (bit-identical values to the allocating path)."""
-    h, w = img.shape[:2]
-    top, left, ch, cw = sample_rrc_geometry(h, w, rng, scale=scale,
-                                            ratio=ratio)
-    dst = _resize_into(img[top: top + ch, left: left + cw], size, out)
-    if rng.random() < 0.5:
-        return _flip_h(dst, out)
-    return np.ascontiguousarray(dst) if out is None else dst
-
-
-def _scale_crop(top: int, left: int, ch: int, cw: int,
-                fh: int, fw: int, rh: int, rw: int
-                ) -> tuple[int, int, int, int]:
-    """Map a full-resolution crop rectangle onto a reduced decode of actual
-    shape (rh, rw) (libjpeg reduced sizes are ceil(dim/d), so the exact
-    ratio comes from the decoded image, not the nominal denominator).
-    Clamped non-empty."""
-    sy, sx = rh / fh, rw / fw
-    r0 = min(int(round(top * sy)), rh - 1)
-    c0 = min(int(round(left * sx)), rw - 1)
-    r1 = max(r0 + 1, min(int(round((top + ch) * sy)), rh))
-    c1 = max(c0 + 1, min(int(round((left + cw) * sx)), rw))
-    return r0, c0, r1 - r0, c1 - c0
 
 
 def make_train_transform(size: int, *, reduced_scale: bool = True,
@@ -485,7 +199,19 @@ def make_train_transform(size: int, *, reduced_scale: bool = True,
     repeat epoch pays only crop+resize — note this serves full-fidelity
     pixels where the reduced path would have approximated, identical to
     the ``reduced_scale=False`` path. Every knob off reproduces the
-    pre-v2 transform bit-identically."""
+    pre-v2 transform bit-identically.
+
+    Without *dcache* the transform carries its parameters as ``tf.spec``:
+    a :class:`DecodePool` given it rebuilds the same transform in its
+    worker processes (:func:`strom.formats.jpeg_core.train_crop`)."""
+    scale, ratio = tuple(scale), tuple(ratio)
+
+    def crop(data, rng, out):
+        return train_crop(data, rng, out, size=size,
+                          reduced_scale=reduced_scale, scale=scale,
+                          ratio=ratio, native=native, roi=roi,
+                          nat=_resolve_native() if native else None,
+                          count=global_stats.add)
 
     def tf(data, rng: np.random.Generator,
            out: np.ndarray | None = None, ckey=None) -> np.ndarray:
@@ -504,105 +230,100 @@ def make_train_transform(size: int, *, reduced_scale: bool = True,
                                    size, out)
             finally:
                 data.release()
-            if rng.random() < 0.5:
-                return _flip_h(dst, out)
-            return np.ascontiguousarray(dst) if out is None else dst
-        info = parse_jpeg_info(data) if (reduced_scale or native
-                                         or dcache is not None) else None
+            return finish_crop(dst, rng, out)
+        if dcache is None or ckey is None or not dcache.enabled:
+            return crop(data, rng, out)
+        info = parse_jpeg_info(data)
         if info is None:
             return random_resized_crop(decode_jpeg(data), size, rng,
                                        scale=scale, ratio=ratio, out=out)
         fh, fw = info.h, info.w
         top, left, ch, cw = sample_rrc_geometry(fh, fw, rng, scale=scale,
                                                 ratio=ratio)
-
-        def finish(dst):
-            # one flip draw in every path, AFTER the resize — the RNG
-            # stream is identical across full/reduced/native/roi/cached
-            if rng.random() < 0.5:
-                return _flip_h(dst, out)
-            return np.ascontiguousarray(dst) if out is None else dst
-
-        nat = _resolve_native() if native else None
         # decoded-output cache (front 4): serve post-decode pixels from
         # RAM; on a miss decode the FULL frame (cache fidelity = full-res
         # pixels; forgoing ROI/reduced on the admitting pass is what buys
         # epoch >= 2 the predecoded-speed serve)
-        if dcache is not None and ckey is not None and dcache.enabled:
-            hit = dcache.get(ckey, fh, fw)
-            if hit is not None:
-                img, pin = hit
-                try:
-                    dst = _resize_into(img[top: top + ch, left: left + cw],
-                                       size, out)
-                finally:
-                    dcache.release(pin)
-                return finish(dst)
-            img = None
-            if nat is not None:
-                try:
-                    img = nat(data)
-                    global_stats.add("decode_native_imgs")
-                except ValueError:
-                    global_stats.add("decode_native_fallbacks")
-            if img is None:
-                img = decode_jpeg(data)
-            dcache.offer(ckey, img)
-            return finish(_resize_into(
-                img[top: top + ch, left: left + cw], size, out))
-
-        denom = reduced_denom(ch, cw, size) if reduced_scale else 1
-        if denom == 1:
-            rh, rw = fh, fw
-            r0, c0, rch, rcw = top, left, ch, cw
-        else:
-            # libjpeg reduced sizes are ceil(dim/d) — computable without
-            # decoding, so the ROI path can plan scaled coordinates upfront
-            rh, rw = -(-fh // denom), -(-fw // denom)
-            r0, c0, rch, rcw = _scale_crop(top, left, ch, cw, fh, fw,
-                                           rh, rw)
-        img = None
-        if nat is not None:
-            # ROI engages when partial decode actually skips work; a crop
-            # spanning the frame rides the plain (full/reduced) decode.
-            # Progressive members never take ROI (wrong pixels — see
-            # parse_jpeg_info); full/reduced native decode handles them.
-            roi_ok = roi and not info.progressive \
-                and (rch < rh or rcw < rw)
+        hit = dcache.get(ckey, fh, fw)
+        if hit is not None:
+            img, pin = hit
             try:
-                if roi_ok:
-                    rect = nat(data, reduced=denom,
-                               roi=(r0, c0, rch, rcw))
-                    global_stats.add("decode_native_imgs")
-                    global_stats.add("decode_roi_hits")
-                    global_stats.add("decode_roi_rows_skipped", rh - rch)
-                    if denom > 1:
-                        global_stats.add(f"decode_reduced_hits_{denom}")
-                    return finish(_resize_into(rect, size, out))
-                img = nat(data, reduced=denom)
+                dst = _resize_into(img[top: top + ch, left: left + cw],
+                                   size, out)
+            finally:
+                dcache.release(pin)
+            return finish_crop(dst, rng, out)
+        img = None
+        nat = _resolve_native() if native else None
+        if nat is not None:
+            try:
+                img = nat(data)
                 global_stats.add("decode_native_imgs")
             except ValueError:
-                # per-sample fallback: a member the native path rejects
-                # (exotic colorspace, arithmetic coding build, truncation
-                # the two libraries tolerate differently) rides cv2 — the
-                # counter keeps "native silently off" diagnosable
                 global_stats.add("decode_native_fallbacks")
-                img = None
         if img is None:
-            img = decode_jpeg(data, reduced=denom)
-        if denom > 1:
-            global_stats.add(f"decode_reduced_hits_{denom}")
-        dst = _resize_into(img[r0: r0 + rch, c0: c0 + rcw], size, out)
-        return finish(dst)
+            img = decode_jpeg(data)
+        dcache.offer(ckey, img)
+        return finish_crop(_resize_into(
+            img[top: top + ch, left: left + cw], size, out), rng, out)
 
+    if dcache is None:
+        tf.spec = {"size": size, "reduced_scale": bool(reduced_scale),
+                   "scale": scale, "ratio": ratio, "native": bool(native),
+                   "roi": bool(roi)}
     return tf
 
 
+def philox_key(rng) -> "tuple[int, int] | None":
+    """The (key0, key1) a per-sample RNG travels as: a key tuple as given,
+    or the key of a fresh ``Philox`` generator (nothing drawn yet); None
+    for anything else, which a worker process could not rebuild."""
+    if isinstance(rng, tuple):
+        return rng
+    bg = getattr(rng, "bit_generator", None)
+    if not isinstance(bg, np.random.Philox):
+        return None
+    st = bg.state
+    if st["buffer_pos"] != 4 or st["has_uint32"] or \
+            st["state"]["counter"].any():
+        return None
+    k0, k1 = st["state"]["key"]
+    return int(k0), int(k1)
+
+
+def as_generator(rng) -> np.random.Generator:
+    """A per-sample RNG as the transform takes it: a Philox key tuple
+    becomes its generator, anything else passes through."""
+    if isinstance(rng, tuple):
+        return np.random.Generator(np.random.Philox(key=list(rng)))
+    return rng
+
+
 class DecodePool:
-    """Thread pool mapping decode+transform over batches of member payloads.
+    """Decode+transform over batches of member payloads, on worker
+    processes or threads.
+
+    **Processes** (*transform* given and built by
+    :func:`make_train_transform` without a decoded cache, so it carries
+    ``tf.spec``): *workers* decode processes start now, in parallel
+    (:class:`strom.formats.decode_procs.DecodeProcs`), and every
+    ``submit_into`` / ``submit_run_into`` / ``map_into`` call with that
+    transform runs there when its bytes and rows lie in the pool's shared
+    memory (:meth:`slab`, :meth:`buffer`; :meth:`recycle` returns them)
+    and its RNGs are Philox keys or fresh Philox generators. The training
+    process keeps its interpreter lock for the loop; counters
+    ``decode_proc_samples``, ``decode_proc_restarts`` and
+    ``decode_proc_parent_us`` (the parent's own time per task, submit plus
+    completion) say how that went.
+
+    **Threads**: everything else — a custom transform (it may not pickle),
+    a transform over a decoded cache or a served frame (their pixels live
+    in this process), rows outside shared memory, and the legacy
+    :meth:`map`. ``decode_thread_samples`` counts these. One algorithm,
+    two executors, routed by what each call brings; no switch.
 
     Worker count is clamped to the host's core count (decode has no I/O
-    waits to hide; extra threads only add GIL churn and context switches).
+    waits to hide; extra workers only add churn and context switches).
 
     cv2's internal threading is disabled while a pool lives (parallelism
     comes from this pool, not from within one image); the prior thread count
@@ -612,21 +333,28 @@ class DecodePool:
     cv2 keeps one global setting, there is nothing finer to restore.)
 
     Fused-run dispatch (ISSUE 12 tentpole, *fuse_runs*): one pool task
-    decodes a RUN of samples, amortizing the per-task future/queue/
-    contextvar/span overhead that dominates at ~1ms images. Run length
-    auto-tunes from a per-image decode-time EWMA the fused workers
-    maintain (the same timing stream the ``decode_batch`` histogram
-    aggregates) against a fixed per-task work target, capped so every
-    worker still sees >= 2 runs per batch. ``fuse_runs=False`` (or run
-    length 1) keeps the one-task-per-sample shape bit-identically.
+    decodes a RUN of samples, amortizing the per-task dispatch overhead.
+    Run length auto-tunes from a per-image decode-time EWMA (thread runs
+    time themselves; process runs report the workers' wall per image)
+    against a fixed per-task work target, capped so every worker still
+    sees >= 2 runs per batch. ``fuse_runs=False`` (or run length 1) keeps
+    the one-task-per-sample shape bit-identically.
+
+    *slabs* bounds the shared regions kept per kind (output slabs, member
+    bytes): pipelines give their prefetch depth plus one.
     """
 
     # per-task decode-work target: enough decode per dispatch that the
     # ~tens-of-us task overhead amortizes below ~2%, small enough that
     # run granularity doesn't serialize a batch's tail
     _RUN_TARGET_US = 4000.0
+    # on worker processes a task's overhead is the parent's: a message each
+    # way, handled under its interpreter lock (~0.3 ms a task on a TPU v5e
+    # host), so a task carries twice the work (5 images at ~1.5 ms)
+    _PROC_RUN_TARGET_US = 8000.0
 
-    def __init__(self, workers: int = 8, *, fuse_runs: bool = True):
+    def __init__(self, workers: int = 8, *, fuse_runs: bool = True,
+                 transform: "Callable | None" = None, slabs: int = 3):
         self._cv2_threads_prev: int | None = None
         if _HAVE_CV2:
             self._cv2_threads_prev = cv2.getNumThreads()
@@ -647,11 +375,24 @@ class DecodePool:
         # so the autotuner's decode_run_target_us knob steers run
         # granularity on a running pool; class default = the measured
         # sweet spot
-        self.run_target_us = float(self._RUN_TARGET_US)
+        self.run_target_us = float(self._PROC_RUN_TARGET_US
+                                   if getattr(transform, "spec", None)
+                                   else self._RUN_TARGET_US)
         # EWMA of per-image decode+transform micros, seeded at 1ms (the
         # measured pre-v2 cost on the bench host); updated by fused runs
         self._img_us = 1000.0
         self._closed = False
+        self._procs = None
+        self._proc_tf = None
+        spec = getattr(transform, "spec", None)
+        if spec is not None:
+            from strom.formats.decode_procs import DecodeProcs
+
+            lib = _native_lib if spec["native"] and native_available() \
+                else ""
+            self._procs = DecodeProcs(workers, spec, lib, self._proc_done,
+                                      ring=slabs)
+            self._proc_tf = transform
 
     @staticmethod
     def _worker_span(req):
@@ -688,10 +429,12 @@ class DecodePool:
 
         req = _request.current()
 
-        def traced(*a) -> np.ndarray:
+        def traced(item, rng=None, *rest) -> np.ndarray:
             # worker span on the shared timeline: per-sample decode+transform
             # (the legacy allocating path; the slot path traces in
             # _one_sample); None = telemetry off, skip the span entirely
+            global_stats.add("decode_thread_samples")
+            a = (item,) if rng is None else (item, as_generator(rng), *rest)
             cm = self._worker_span(req)
             if cm is None:
                 return fn(*a)
@@ -699,6 +442,47 @@ class DecodePool:
                 return fn(*a)
 
         return list(self._pool.map(traced, items, *extra))
+
+    # -- shared memory for the process executor ------------------------------
+    def offloads(self, fn: Callable) -> bool:
+        """True when calls with *fn* run in this pool's worker processes
+        (given bytes and rows in its shared memory)."""
+        return self._procs is not None and fn is self._proc_tf
+
+    def slab(self, shape: tuple) -> np.ndarray:
+        """A uint8 array of *shape* in shared memory, for output rows."""
+        return self._procs.lease("rows", math.prod(shape)).reshape(shape)
+
+    def buffer(self, nbytes: int) -> np.ndarray:
+        """A flat uint8 array of *nbytes* in shared memory, for member
+        bytes (a streamed gather's destination)."""
+        return self._procs.lease("bytes", nbytes)
+
+    def recycle(self, *arrays: "np.ndarray | None",
+                fence: Sequence = ()) -> None:
+        """Return :meth:`slab` / :meth:`buffer` arrays; each is handed out
+        again only after every array in *fence* (the device arrays put
+        from it) is ready."""
+        for a in arrays:
+            if a is not None:
+                self._procs.give_back(a, fence)
+
+    def _proc_done(self, n: int, zeroed: int, wall_s: float) -> None:
+        """A process task finished (the reaper thread, which adds the
+        counters): the pool's error tally and the per-image EWMA, as
+        :meth:`_one_sample` / :meth:`_run_into` keep them on threads."""
+        with self._err_lock:
+            self.decode_errors += zeroed
+            self._img_us += 0.2 * (wall_s * 1e6 / n - self._img_us)
+
+    def _offload(self, fn: Callable, items: Sequence, rngs: Sequence,
+                 rows: Sequence, req) -> "concurrent.futures.Future | None":
+        if not self.offloads(fn):
+            return None
+        keys = [philox_key(r) for r in rngs]
+        if any(k is None for k in keys):
+            return None
+        return self._procs.submit(items, keys, rows, req)
 
     # -- direct-to-slot mapping --------------------------------------------
     def _call(self, fn: Callable[..., np.ndarray], item, rng,
@@ -710,6 +494,8 @@ class DecodePool:
 
     def _one_sample(self, fn: Callable[..., np.ndarray], item, rng,
                     row: np.ndarray, req, ckey) -> None:
+        global_stats.add("decode_thread_samples")
+        rng = as_generator(rng)
         try:
             cm = self._worker_span(req)
             if cm is None:  # telemetry off: no span object, no now_us
@@ -753,8 +539,12 @@ class DecodePool:
         delivery completes on."""
         from strom.obs import request as _request
 
-        return self._pool.submit(self._one_into, fn, item, rng, row,
-                                 _request.current(), ckey)
+        req = _request.current()
+        fut = self._offload(fn, (item,), (rng,), (row,), req)
+        if fut is not None:
+            return fut
+        return self._pool.submit(self._one_into, fn, item, rng, row, req,
+                                 ckey)
 
     def submit_run_into(self, fn: Callable[..., np.ndarray],
                         items: Sequence, rngs: Sequence, rows: Sequence,
@@ -765,15 +555,21 @@ class DecodePool:
         paid once per run instead of once per sample."""
         from strom.obs import request as _request
 
+        req = _request.current()
+        fut = self._offload(fn, items, rngs, rows, req)
+        if fut is not None:
+            return fut
         return self._pool.submit(self._run_into, fn, items, rngs, rows,
-                                 _request.current(), ckeys)
+                                 req, ckeys)
 
     def map_into(self, fn: Callable[..., np.ndarray], items: Sequence,
                  rngs: Sequence, out: np.ndarray,
                  ckeys: "Sequence | None" = None) -> np.ndarray:
         """Map fn(item, rng, out=out[i]) over the batch, every worker
         writing straight into its slot row; contiguous runs fuse into one
-        task each per :meth:`run_size`. Returns *out*."""
+        task each per :meth:`run_size`. Returns *out* once every task has
+        finished (the first failure is raised after the rest retire: they
+        write into *out*)."""
         n = len(items)
         run = self.run_size(n)
         if run <= 1:
@@ -786,6 +582,7 @@ class DecodePool:
                         [out[j] for j in range(i, min(i + run, n))],
                         None if ckeys is None else ckeys[i: i + run])
                     for i in range(0, n, run)]
+        concurrent.futures.wait(futs)
         for f in futs:
             f.result()
         return out
@@ -794,6 +591,8 @@ class DecodePool:
         if self._closed:
             return
         self._closed = True
+        if self._procs is not None:
+            self._procs.close()
         self._pool.shutdown(wait=True)
         if _HAVE_CV2 and self._cv2_threads_prev is not None:
             cv2.setNumThreads(self._cv2_threads_prev)

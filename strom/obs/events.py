@@ -100,15 +100,25 @@ class EventRing:
                 t[1] += ev[1]
                 t[2] += cpu_us
 
+    def us_at(self, t: float) -> float:
+        """Ring microseconds of a ``time.perf_counter()`` reading. On Linux
+        that clock is ``CLOCK_MONOTONIC``, one clock for every process of
+        the host, so a reading taken in a worker process maps too."""
+        return (t - self._t0) * 1e6
+
     def complete(self, ts_us: float, dur_us: float, cat: str, name: str,
-                 args: dict | None = None, cpu_us: float = 0.0) -> None:
+                 args: dict | None = None, cpu_us: float = 0.0,
+                 tid: int | None = None) -> None:
         """Record a finished span (chrome 'X' event) and add it to its
         name's totals; *cpu_us* is the thread CPU time the site measured
-        over the span, where it measures one."""
+        over the span, where it measures one. *tid* names the lane of a
+        span measured elsewhere (a decode worker's pid); default the
+        calling thread."""
         if not self.enabled:
             return
-        self._append((ts_us, dur_us, threading.get_ident(), cat, name, args),
-                     cpu_us)
+        self._append((ts_us, dur_us,
+                      threading.get_ident() if tid is None else tid, cat,
+                      name, args), cpu_us)
 
     def instant(self, name: str, cat: str = "",
                 args: dict | None = None) -> None:

@@ -135,18 +135,19 @@ class Request:
 
     def record(self, name: str, cat: str, ts_us: float, dur_us: float,
                args: "dict | None" = None, parent: "str | None" = None,
-               cpu_us: float = 0.0) -> None:
+               cpu_us: float = 0.0, tid: "int | None" = None) -> None:
         """One finished span: ring emission (req/parent in args) + tree
         append. The explicit-timestamp twin of :meth:`span` for callers
-        that measured the window themselves (scheduler queue waits)."""
-        tid = threading.get_ident()
+        that measured the window themselves (scheduler queue waits, decode
+        worker processes: *tid* is then the worker's lane)."""
+        tid = threading.get_ident() if tid is None else tid
         full = {"req": self.id}
         if parent:
             full["parent"] = parent
         if args:
             full.update(args)
         self._flow(name, cat)
-        ring.complete(ts_us, dur_us, cat, name, full, cpu_us)
+        ring.complete(ts_us, dur_us, cat, name, full, cpu_us, tid)
         with self._lock:
             if len(self.spans) < MAX_SPANS_PER_REQUEST:
                 self.spans.append((name, cat, round(ts_us, 1),

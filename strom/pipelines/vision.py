@@ -3,10 +3,13 @@ config #2, BASELINE.json:8) and WebDataset→ViT-B/16 on RAID0 (config #3,
 BASELINE.json:9).
 
 Per batch: gather-read the local samples' JPEG members (engine, O_DIRECT),
-decode+augment on the host worker pool (cv2 releases the GIL), device_put
-each device's rows, assemble the global sharded array — each host only ever
-reads and decodes the rows its own devices consume (SURVEY.md §2.3
-"Mesh-sharded delivery").
+decode+augment on the host worker pool, device_put each device's rows,
+assemble the global sharded array — each host only ever reads and decodes
+the rows its own devices consume (SURVEY.md §2.3 "Mesh-sharded delivery").
+With the built-in transform and no decoded cache the pool decodes in worker
+processes (strom/formats/decode_procs.py): the gather then lands in the
+pool's shared memory and the batch's rows are a view of one of its shared
+slabs, recycled once the puts from it are ready.
 
 Decode-path scheduling (ISSUE 2 tentpole; knobs `decode_reduced_scale`,
 `decode_to_slot`, `decode_overlap_put` in StromConfig): bytes flow
@@ -160,6 +163,17 @@ def _init_group_state(ctx: StromContext, images: np.ndarray,
     return pos_devs, pending, shards
 
 
+def _put_rows(ctx: StromContext, rows: np.ndarray, device: Any,
+              shared: bool) -> Any:
+    """``ctx.device_put`` of one device's row group. Rows in the decode
+    pool's shared slab are copied first for a CPU device: the jax CPU
+    backend aliases host memory instead of copying it, and the slab is
+    rewritten by a later batch."""
+    if shared and device.platform == "cpu":
+        rows = rows.copy()
+    return ctx.device_put(rows, device)
+
+
 def _note_decode_overlap(scope, t_decode0: float | None,
                          t_first_put: float | None,
                          t_last_decode: float | None) -> None:
@@ -187,7 +201,8 @@ def _decode_put_overlapped(ctx: StromContext, pool: DecodePool, tf: Transform,
                            images: np.ndarray, dev_items: Sequence,
                            row_pos: dict, scope=None,
                            ckeys: "Sequence | None" = None,
-                           prep: "Callable | None" = None) -> list:
+                           prep: "Callable | None" = None,
+                           shared: bool = False) -> list:
     """Decode every row into its slot and `device_put` each device's row
     group the moment its rows finish (completion-ordered — the per-group
     analogue of `_deliver_streamed`'s read/transfer overlap: early groups
@@ -198,7 +213,9 @@ def _decode_put_overlapped(ctx: StromContext, pool: DecodePool, tf: Transform,
 
     Returns one put shard per entry of *dev_items*, in order. Observability:
     `decode_batch` histogram (per-batch decode wall), `decode_put_overlap_ms`
-    (the window during which puts overlapped in-flight decode)."""
+    (the window during which puts overlapped in-flight decode). A failed
+    task raises only after every task has retired: they write *images*.
+    *shared*: *images* is a decode-pool slab (see :func:`_put_rows`)."""
     n = images.shape[0]
     pos_devs, pending, shards = _init_group_state(ctx, images, dev_items,
                                                   row_pos, prep)
@@ -219,8 +236,11 @@ def _decode_put_overlapped(ctx: StromContext, pool: DecodePool, tf: Transform,
     t_first_put = None
     t_last_decode = t0
     for f in concurrent.futures.as_completed(futs):
-        f.result()  # decode ValueErrors are absorbed per-row by the pool;
-        # anything else (a transform bug) must still abort the batch
+        if f.exception() is not None:
+            # decode ValueErrors are absorbed per-row by the pool; anything
+            # else (a transform bug, a lost worker) aborts the batch
+            concurrent.futures.wait(futs)
+            f.result()
         t_last_decode = time.perf_counter()
         for p in futs[f]:
             for di in pos_devs[p]:
@@ -236,7 +256,7 @@ def _decode_put_overlapped(ctx: StromContext, pool: DecodePool, tf: Transform,
                         # completed device group, overlapping the remaining
                         # in-flight decode exactly like the put it feeds
                         rows = prep(rows)
-                    shards[di] = ctx.device_put(rows, device)
+                    shards[di] = _put_rows(ctx, rows, device, shared)
     _note_decode_overlap(scope or global_stats, t0, t_first_put,
                          t_last_decode)
     return shards
@@ -248,7 +268,9 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
                          dev_items: Sequence, row_pos: dict, scope=None,
                          ckeys: "Sequence | None" = None,
                          served: "Sequence | None" = None,
-                         prep: "Callable | None" = None
+                         prep: "Callable | None" = None,
+                         buf: "np.ndarray | None" = None,
+                         shared: bool = False
                          ) -> tuple[list, list[int]]:
     """Completion-driven batch assembly (ISSUE 5 tentpole): the member
     gather is submitted through ``ctx.stream_segments`` and each sample is
@@ -268,7 +290,11 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
     countdown → decode submit), so the engine's queue refills at read pace
     no matter how long the consumer side spends in device_put; decode
     completions flow back to THIS thread over a queue, which fires each
-    device's put the moment its row group finishes decoding."""
+    device's put the moment its row group finishes decoding.
+
+    *buf* is where the gather lands (the decode pool's shared memory when
+    it decodes in processes; default a fresh aligned buffer); *shared* as
+    in :func:`_decode_put_overlapped`."""
     from strom.delivery.shard import Segment
     from strom.obs.events import ring
 
@@ -282,7 +308,8 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
         ends.append(pos)
     remaining = [e - s for s, e in zip(starts, ends)]
     labels: list[int] = [0] * n
-    buf = ctx.alloc_read_buffer(el, max(el.size, 1))
+    if buf is None:
+        buf = ctx.alloc_read_buffer(el, max(el.size, 1))
 
     pos_devs, pending, shards = _init_group_state(ctx, images, dev_items,
                                                   row_pos, prep)
@@ -304,8 +331,11 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
     # decode together — runs are flushed after EVERY poll drain (a lone
     # early sample never waits for company; the streaming overlap is
     # untouched), bounded at run_size so one task can't serialize a
-    # fully-instant cache-warm batch on one worker
+    # fully-instant cache-warm batch on one worker. On worker processes a
+    # short run waits for company while the gather is in flight: a task
+    # costs the parent two messages, and decode there has cores to spare.
     run = pool.run_size(n)
+    hold = pool.offloads(tf)
     ready: list[int] = []
 
     def mark_ready(i: int) -> None:
@@ -334,6 +364,8 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
 
     def flush_ready() -> None:
         while ready:
+            if hold and len(ready) < run and not g.done:
+                return
             grp = tuple(ready[:run])
             del ready[: run]
             if len(grp) == 1:
@@ -380,6 +412,7 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
                                 mark_ready(i)
                         i += 1
                 flush_ready()
+            flush_ready()  # the gather is done: no run waits any longer
             g.finish()
             events.put(("done", None))
         except BaseException as e:  # surfaced on the consumer side
@@ -414,7 +447,8 @@ def _decode_put_streamed(ctx: StromContext, pool: DecodePool, tf: Transform,
                             rows = images[base: base + hi - lo]
                             if prep is not None:
                                 rows = prep(rows)
-                            shards[di] = ctx.device_put(rows, device)
+                            shards[di] = _put_rows(ctx, rows, device,
+                                                   shared)
             elif kind == "done":
                 gather_done = True
             elif kind == "error":
@@ -573,8 +607,6 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
                 return cgraph.apply_batch(rows)
     else:
         prep = None
-    pool = DecodePool(decode_workers, fuse_runs=fuse)
-    ctx.register_tunable("decode_pool", pool)
     label_sharding = NamedSharding(
         sharding.mesh,
         P(sharding.spec[0] if len(sharding.spec) else None))
@@ -587,6 +619,17 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
                          for r in range(lo, hi)})
     row_pos = {r: i for i, r in enumerate(local_rows)}
     dev_items = list(rows_by_device.items())
+    depth = prefetch_depth if prefetch_depth is not None else ctx.config.prefetch_depth
+    auto, max_depth = _auto_depth_bounds(
+        ctx, auto_prefetch, len(local_rows) * image_size * image_size * 3)
+    # the built-in transform decodes in worker processes (the pool sees
+    # tf.spec): one shared slab per batch being built or still landing on
+    # the device, so the ring is one deeper than the batches in flight
+    pool = DecodePool(decode_workers, fuse_runs=fuse, transform=tf,
+                      slabs=(max_depth if auto else depth) + 1)
+    ctx.register_tunable("decode_pool", pool)
+    shared = to_slot and pool.offloads(tf)
+    slab_shape = (len(local_rows), image_size, image_size, 3)
 
     def shard_view(arr: np.ndarray, lo: int, hi: int) -> np.ndarray:
         # each device's row range is contiguous in the sorted local union,
@@ -598,10 +641,9 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
 
     def make_batch(indices: np.ndarray, serial: int) -> tuple[Any, Any]:
         samples = [ss.samples[int(indices[r])] for r in local_rows]
-        # Philox keys are two 64-bit words: (seed, serial ‖ row)
-        rngs = [np.random.Generator(np.random.Philox(
-                    key=[seed, (serial << 32) + r]))
-                for r in local_rows]
+        # Philox keys are two 64-bit words: (seed, serial ‖ row); the pool
+        # builds each generator where the sample decodes
+        rngs = [(seed, (serial << 32) + r) for r in local_rows]
         # decoded-output cache keys (ISSUE 12): the image member's physical
         # extent — stable across epochs, exactly like the extent cache
         ckeys = None
@@ -668,15 +710,35 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
             raise
 
     def _assemble_batch(el, sizes, rngs, ckeys, served) -> tuple[Any, Any]:
+        if not shared:
+            return _assemble_into(el, sizes, rngs, ckeys, served, None, None)
+        images = inbuf = None
+        fence: Sequence = ()
+        try:
+            images = pool.slab(slab_shape)
+            if el.size:
+                inbuf = pool.buffer(el.size)
+            out = _assemble_into(el, sizes, rngs, ckeys, served, images,
+                                 inbuf)
+            fence = (out[0],)  # ready = every put from the slab landed
+            return out
+        finally:
+            pool.recycle(images, inbuf, fence=fence)
+
+    def _assemble_into(el, sizes, rngs, ckeys, served, images, inbuf
+                       ) -> tuple[Any, Any]:
+        """One batch; *images* / *inbuf* are the pool's shared slab and
+        gather buffer when it decodes in processes (else None)."""
         if stream:
             # completion-driven dataflow (ISSUE 5): samples decode the
             # moment their extents land, device groups put the moment their
             # rows decode — no gather barrier anywhere in the batch
-            images = np.empty((len(local_rows), image_size, image_size, 3),
-                              dtype=np.uint8)
+            if images is None:
+                images = np.empty(slab_shape, dtype=np.uint8)
             img_shards, labels = _decode_put_streamed(
                 ctx, pool, tf, el, sizes, rngs, images, dev_items, row_pos,
-                scope=pscope, ckeys=ckeys, served=served, prep=prep)
+                scope=pscope, ckeys=ckeys, served=served, prep=prep,
+                buf=inbuf, shared=shared)
             labels_np = np.asarray(labels, dtype=np.int32)
             pscope.add("decode_slot_bytes", images.nbytes)
             lbl_shards = [ctx.device_put(shard_view(labels_np, lo, hi), d)
@@ -687,7 +749,7 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
                 (batch,), label_sharding, lbl_shards)
             return imgs, lbls
 
-        buf = ctx.pread(el, tenant=tname)
+        buf = ctx.pread(el, tenant=tname, dest=inbuf)
         # split the concatenated buffer back into per-sample members; a
         # plan-time decoded-cache hit (isz == 0) rides its ServedFrame in
         # place of bytes that were never gathered
@@ -705,12 +767,12 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
             # workers write final rows straight into the batch slot: the
             # np.stack full-batch copy and per-row output temporaries of
             # the legacy path never exist
-            images = np.empty((len(local_rows), image_size, image_size, 3),
-                              dtype=np.uint8)
+            if images is None:
+                images = np.empty(slab_shape, dtype=np.uint8)
             if overlap_put:
                 img_shards = _decode_put_overlapped(
                     ctx, pool, tf, blobs, rngs, images, dev_items, row_pos,
-                    scope=pscope, ckeys=ckeys, prep=prep)
+                    scope=pscope, ckeys=ckeys, prep=prep, shared=shared)
             else:
                 with pscope.timer_us("decode_batch"):
                     pool.map_into(tf, blobs, rngs, images, ckeys=ckeys)
@@ -718,7 +780,8 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
                 # apply after the decode barrier — same per-sample kernel
                 # as the fused dispatch, so outputs are bit-identical
                 out = images if prep is None else prep(images)
-                img_shards = [ctx.device_put(shard_view(out, lo, hi), d)
+                img_shards = [_put_rows(ctx, shard_view(out, lo, hi), d,
+                                        shared)
                               for d, (lo, hi) in dev_items]
             # billed after the decode completes: an aborted batch never
             # claims slot bytes it didn't deliver (zero-substituted rows DO
@@ -746,9 +809,6 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
         with _request.active("batch", tname, owner=ctx._req_owner):
             return make_batch(indices, serial)
 
-    depth = prefetch_depth if prefetch_depth is not None else ctx.config.prefetch_depth
-    auto, max_depth = _auto_depth_bounds(
-        ctx, auto_prefetch, len(local_rows) * image_size * image_size * 3)
     # warm this host's member bytes for the upcoming batches (tar payloads
     # re-read every epoch; decode still runs per-step, the NVMe gather not)
     ra = _make_readahead(

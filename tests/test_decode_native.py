@@ -577,3 +577,70 @@ def test_decode2_fields_are_counters_or_phase_keys():
         # NAME is identical to what the producers feed (lint enforces the
         # near-duplicate half, this pins exact membership)
         assert global_stats.counter(k).value >= 0
+
+
+# -------------------------------------- decode worker processes, native path
+# the pipelines' default transform at a size where ROI and reduced-scale
+# decode both engage on these members
+NATIVE_TF = make_train_transform(64)
+
+
+@pytest.fixture(scope="module")
+def native_batch(tmp_path_factory):
+    """Baseline members (some wide enough for 1/2 decode), progressive
+    members (ROI refused: full decode) and one truncated member, in a tar
+    shard: (blobs, tar path)."""
+    from tests.test_decode import _truncated
+    from tests.test_formats import make_wds_shard
+
+    rng = np.random.default_rng(29)
+    blobs = []
+    for i in range(9):
+        h, w = 200 + 40 * i, 260 + 30 * i
+        img = cv2.GaussianBlur(noise(rng, h, w), (7, 7), 2)
+        blobs.append(enc(img, progressive=(i % 4 == 2)))
+    blobs[5] = _truncated(blobs[5])
+    p = str(tmp_path_factory.mktemp("native_proc_wds") / "native.tar")
+    make_wds_shard(p, [(f"s{i:04d}", {"jpg": b, "cls": str(i).encode()})
+                       for i, b in enumerate(blobs)])
+    return blobs, p
+
+
+@pytest.fixture(scope="module")
+def native_pools():
+    from strom.config import StromConfig
+    from strom.delivery.core import StromContext
+
+    ctx = StromContext(StromConfig(engine="python", queue_depth=8,
+                                   num_buffers=8))
+    with DecodePool(2) as threads, \
+            DecodePool(2, transform=NATIVE_TF) as procs:
+        yield ctx, threads, procs
+    ctx.close()
+
+
+@needs_native
+@pytest.mark.parametrize("path", ["map_into", "overlapped", "streamed"])
+def test_native_roi_threads_and_processes_byte_identical(path, native_batch,
+                                                         native_pools):
+    """Native + ROI + reduced decode, progressive and truncated members:
+    worker processes deliver the thread path's bytes and feed the same
+    counters (their tallies travel back with each task)."""
+    from strom.formats.jpeg_core import COUNTERS
+    from tests.test_decode import _decode_batch
+
+    blobs, tar = native_batch
+    ctx, threads, procs = native_pools
+    assert procs.offloads(NATIVE_TF) and not threads.offloads(NATIVE_TF)
+    got, tallies = {}, {}
+    for name, pool in (("threads", threads), ("processes", procs)):
+        names = COUNTERS + ("decode_errors",)
+        c0 = {k: global_stats.counter(k).value for k in names}
+        got[name] = _decode_batch(path, pool, ctx, blobs, tar, NATIVE_TF, 64)
+        tallies[name] = {k: global_stats.counter(k).value - c0[k]
+                         for k in names}
+    np.testing.assert_array_equal(got["threads"], got["processes"])
+    assert tallies["threads"] == tallies["processes"]
+    assert tallies["processes"]["decode_roi_hits"] > 0
+    assert tallies["processes"]["decode_errors"] == 1
+    assert not got["processes"][5].any()

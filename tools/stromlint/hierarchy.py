@@ -53,6 +53,9 @@ LOCK_RANKS = {
                                # leaf in practice — metrics_fn and
                                # knob.set both run OUTSIDE it (metrics
                                # walks the context's stats locks)
+    "app.jpeg_procs": 16,      # DecodeProcs workers/regions/pending tasks:
+                               # never held across a socket send, a spawn
+                               # or a fence wait
     # -- band: scheduler -----------------------------------------------------
     "sched.arbiter": 20,       # IoScheduler._cond (the fair-drain core)
     "sched.admission": 21,     # AdmissionGate._cond
