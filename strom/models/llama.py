@@ -108,10 +108,14 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float) -> jax.Array:
     return (xf * scale * w).astype(x.dtype)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S] (absolute)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: jax.Array | None = None) -> jax.Array:
+    """Rotary embedding. x: [B, S, H, Dh]; positions: [B, S] (absolute).
+    Pairs are (i, i + Dh/2). *inv_freq* [Dh/2] replaces theta's frequencies
+    (YaRN's blend, :mod:`strom.models.deepseek_v2`)."""
     hd = x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, hd // 2, dtype=jnp.float32) / (hd // 2))
+    freqs = (theta ** (-jnp.arange(0, hd // 2, dtype=jnp.float32) / (hd // 2))
+             if inv_freq is None else inv_freq)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B,S,hd/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]

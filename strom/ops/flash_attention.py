@@ -21,6 +21,12 @@ per-row logsumexp, and two kernels rebuild P tile-by-tile from (q, k, lse):
 so no [S, S] tensor ever materializes — O(S) memory in both passes, which is
 what makes long-context training (ring/sp composition) viable.
 
+Queries and keys share one head width Dqk; values may have another, Dv
+(multi-head latent attention: 192 and 128). A block's last dim is the whole
+head width, so 192 needs no padding: Mosaic takes a block dim equal to the
+array's. The softmax scale defaults to 1/√Dqk; a caller passes its own (YaRN
+scales it).
+
 On the CPU backend the kernels run in interpreter mode so tests exercise the
 same code path the TPU compiles; on a TPU they compile to Mosaic; on any other
 backend :func:`interpret_mode` refuses rather than interpret in silence.
@@ -71,9 +77,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0]                       # [blk_q, Dh]
-        k = k_ref[0, 0]                       # [blk_k, Dh]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]                       # [blk_q, Dqk]
+        k = k_ref[0, 0]                       # [blk_k, Dqk]
+        v = v_ref[0, 0]                       # [blk_k, Dv]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -106,21 +112,23 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
 
 
 def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
-               block_q: int, block_k: int, interpret: bool
-               ) -> tuple[jax.Array, jax.Array]:
-    """q [B,S,H,Dh]; k,v [B,S,KV,Dh] → (out [B,S,H,Dh], lse [B,H,Sq/blk,blk]).
-    Layout transposed to head-major [B,H,S,Dh] for MXU-friendly [S,Dh] tiles."""
-    B, S, H, Dh = q.shape
+               block_q: int, block_k: int, interpret: bool,
+               scale: float | None = None) -> tuple[jax.Array, jax.Array]:
+    """q [B,S,H,Dqk]; k [B,S,KV,Dqk]; v [B,S,KV,Dv] → (out [B,S,H,Dv],
+    lse [B,H,S,1]). Layout transposed to head-major [B,H,S,D] for
+    MXU-friendly [S,D] tiles."""
+    B, S, H, Dqk = q.shape
     KV = k.shape[2]
+    Dv = v.shape[3]
     G = H // KV
     blk_q = min(block_q, S)
     blk_k = min(block_k, S)
     if S % blk_q or S % blk_k:
         raise ValueError(f"seq len {S} must divide by blocks ({blk_q},{blk_k})")
-    qt = q.transpose(0, 2, 1, 3)  # [B,H,S,Dh]
-    kt = k.transpose(0, 2, 1, 3)  # [B,KV,S,Dh]
+    qt = q.transpose(0, 2, 1, 3)  # [B,H,S,Dqk]
+    kt = k.transpose(0, 2, 1, 3)  # [B,KV,S,Dqk]
     vt = v.transpose(0, 2, 1, 3)
-    scale = 1.0 / math.sqrt(Dh)
+    scale = _scale(scale, Dqk)
 
     kernel = functools.partial(_fa_kernel, causal=causal, scale=scale,
                                blk_q=blk_q, blk_k=blk_k)
@@ -129,27 +137,28 @@ def _flash_fwd(q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool,
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, blk_q, Dh),
+            pl.BlockSpec((1, 1, blk_q, Dqk),
                          lambda b, h, i, j: (b, h, i, 0)),
-            pl.BlockSpec((1, 1, blk_k, Dh),
+            pl.BlockSpec((1, 1, blk_k, Dqk),
                          lambda b, h, i, j, G=G: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, blk_k, Dh),
+            pl.BlockSpec((1, 1, blk_k, Dv),
                          lambda b, h, i, j, G=G: (b, h // G, j, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, blk_q, Dh), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, blk_q, Dv), lambda b, h, i, j: (b, h, i, 0)),
             pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, i, j: (b, h, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B, H, S, Dh), q.dtype),
+            jax.ShapeDtypeStruct((B, H, S, Dv), q.dtype),
             jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((blk_q, _LANES), jnp.float32),  # m
             pltpu.VMEM((blk_q, _LANES), jnp.float32),  # l
-            pltpu.VMEM((blk_q, Dh), jnp.float32),      # acc
+            pltpu.VMEM((blk_q, Dv), jnp.float32),      # acc
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse
 
@@ -191,10 +200,10 @@ def _fa_bwd_dkv_kernel(q_ref, do_ref, k_ref, v_ref, lse_ref, delta_ref,
 
     @pl.when(run)
     def _step():
-        q = q_ref[0, 0]            # [blk_q, Dh]
-        do = do_ref[0, 0]          # [blk_q, Dh]
-        k = k_ref[0, 0]            # [blk_k, Dh]
-        v = v_ref[0, 0]
+        q = q_ref[0, 0]            # [blk_q, Dqk]
+        do = do_ref[0, 0]          # [blk_q, Dv]
+        k = k_ref[0, 0]            # [blk_k, Dqk]
+        v = v_ref[0, 0]            # [blk_k, Dv]
         _, p = _causal_p(q, k, lse_ref[0, 0], scale=scale, causal=causal,
                          iq=iq, jk=jk, blk_q=blk_q, blk_k=blk_k)
         pb = p.astype(v.dtype)
@@ -260,121 +269,141 @@ def _delta(out: jax.Array, g: jax.Array) -> jax.Array:
 
 
 def _flash_bwd(q, k, v, out, lse, g, *, causal: bool, block_q: int,
-               block_k: int, interpret: bool, delta=None):
+               block_k: int, interpret: bool, delta=None,
+               scale: float | None = None):
     """Blockwise backward. With the default delta=None this is the vjp of
     the single-device forward. Passing an explicit (lse, delta) pair makes
     it a BLOCK-PAIR primitive for ring attention: fed the GLOBAL logsumexp
     and Δ of the q rows, the kernels rebuild the globally-normalized tile
     P = exp(q·kᵀ·scale − lse_global) directly, so each (q block, kv block)
     call yields that pair's exact contribution to the global gradients."""
-    B, S, H, Dh = q.shape
+    B, S, H, Dqk = q.shape
     KV = k.shape[2]
+    Dv = v.shape[3]
     G = H // KV
     blk_q = min(block_q, S)
     blk_k = min(block_k, S)
     nq = S // blk_q
     nk = S // blk_k
-    scale = 1.0 / math.sqrt(Dh)
+    scale = _scale(scale, Dqk)
 
-    qt = q.transpose(0, 2, 1, 3)   # [B,H,S,Dh]
-    kt = k.transpose(0, 2, 1, 3)   # [B,KV,S,Dh]
-    vt = v.transpose(0, 2, 1, 3)
-    dot = g.transpose(0, 2, 1, 3)  # [B,H,S,Dh]
+    qt = q.transpose(0, 2, 1, 3)   # [B,H,S,Dqk]
+    kt = k.transpose(0, 2, 1, 3)   # [B,KV,S,Dqk]
+    vt = v.transpose(0, 2, 1, 3)   # [B,KV,S,Dv]
+    dot = g.transpose(0, 2, 1, 3)  # [B,H,S,Dv]
     if delta is None:
         delta = _delta(out, g)
 
-    q_spec = pl.BlockSpec((1, 1, blk_q, Dh),
-                          lambda b, kv, jk, gg, iq, G=G: (b, kv * G + gg, iq, 0))
-    row_spec = pl.BlockSpec((1, 1, blk_q, 1),
-                            lambda b, kv, jk, gg, iq, G=G: (b, kv * G + gg, iq, 0))
-    kv_spec = pl.BlockSpec((1, 1, blk_k, Dh),
-                           lambda b, kv, jk, gg, iq: (b, kv, jk, 0))
+    def q_rows(d):
+        return pl.BlockSpec((1, 1, blk_q, d), lambda b, kv, jk, gg, iq, G=G:
+                            (b, kv * G + gg, iq, 0))
+
+    def kv_rows(d):
+        return pl.BlockSpec((1, 1, blk_k, d),
+                            lambda b, kv, jk, gg, iq: (b, kv, jk, 0))
+
     dkt, dvt = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, causal=causal, scale=scale,
                           blk_q=blk_q, blk_k=blk_k),
         grid=(B, KV, nk, G, nq),
-        in_specs=[q_spec, q_spec, kv_spec, kv_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((B, KV, S, Dh), k.dtype),
-                   jax.ShapeDtypeStruct((B, KV, S, Dh), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk_k, Dh), jnp.float32),
-                        pltpu.VMEM((blk_k, Dh), jnp.float32)],
+        in_specs=[q_rows(Dqk), q_rows(Dv), kv_rows(Dqk), kv_rows(Dv),
+                  q_rows(1), q_rows(1)],
+        out_specs=[kv_rows(Dqk), kv_rows(Dv)],
+        out_shape=[jax.ShapeDtypeStruct((B, KV, S, Dqk), k.dtype),
+                   jax.ShapeDtypeStruct((B, KV, S, Dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((blk_k, Dqk), jnp.float32),
+                        pltpu.VMEM((blk_k, Dv), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qt, dot, kt, vt, lse, delta)
 
-    q_spec2 = pl.BlockSpec((1, 1, blk_q, Dh), lambda b, h, iq, jk: (b, h, iq, 0))
-    row_spec2 = pl.BlockSpec((1, 1, blk_q, 1), lambda b, h, iq, jk: (b, h, iq, 0))
-    kv_spec2 = pl.BlockSpec((1, 1, blk_k, Dh),
+    def q_rows2(d):
+        return pl.BlockSpec((1, 1, blk_q, d),
+                            lambda b, h, iq, jk: (b, h, iq, 0))
+
+    def kv_rows2(d):
+        return pl.BlockSpec((1, 1, blk_k, d),
                             lambda b, h, iq, jk, G=G: (b, h // G, jk, 0))
+
     dqt = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, causal=causal, scale=scale,
                           blk_q=blk_q, blk_k=blk_k),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec2, q_spec2, kv_spec2, kv_spec2, row_spec2, row_spec2],
-        out_specs=q_spec2,
-        out_shape=jax.ShapeDtypeStruct((B, H, S, Dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((blk_q, Dh), jnp.float32)],
+        in_specs=[q_rows2(Dqk), q_rows2(Dv), kv_rows2(Dqk), kv_rows2(Dv),
+                  q_rows2(1), q_rows2(1)],
+        out_specs=q_rows2(Dqk),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, Dqk), q.dtype),
+        scratch_shapes=[pltpu.VMEM((blk_q, Dqk), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qt, dot, kt, vt, lse, delta)
 
     return (dqt.transpose(0, 2, 1, 3), dkt.transpose(0, 2, 1, 3),
             dvt.transpose(0, 2, 1, 3))
 
 
-def _dense_ref(q, k, v, causal):
+def _scale(scale: float | None, dqk: int) -> float:
+    return 1.0 / math.sqrt(dqk) if scale is None else float(scale)
+
+
+def _dense_ref(q, k, v, causal, scale=None):
     """f32 dense attention — the parity oracle for tests."""
-    B, S, H, Dh = q.shape
+    B, S, H, Dqk = q.shape
     KV = k.shape[2]
     G = H // KV
-    qg = q.reshape(B, S, KV, G, Dh)
+    qg = q.reshape(B, S, KV, G, Dqk)
     s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-    s = s / math.sqrt(Dh)
+    s = s * _scale(scale, Dqk)
     if causal:
         pos = jnp.arange(S)
         s = jnp.where((pos[:, None] >= pos[None, :])[None, None, None],
                       s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgqs,bskd->bqkgd", p.astype(v.dtype), v)
-    return out.reshape(B, S, H, Dh)
+    return out.reshape(B, S, H, v.shape[3])
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = True, block_q: int = 128,
                     block_k: int = 128,
-                    interpret: bool | None = None) -> jax.Array:
-    """Flash attention. q [B,S,H,Dh]; k,v [B,S,KV,Dh] (GQA) → [B,S,H,Dh].
+                    interpret: bool | None = None,
+                    scale: float | None = None) -> jax.Array:
+    """Flash attention. q [B,S,H,Dqk]; k [B,S,KV,Dqk]; v [B,S,KV,Dv]
+    (GQA) → [B,S,H,Dv].
 
-    interpret=None → :func:`interpret_mode` of the default backend.
+    interpret=None → :func:`interpret_mode` of the default backend;
+    scale=None → 1/√Dqk.
     """
     if interpret is None:
         interpret = interpret_mode()
     out, _ = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
-                        block_k=block_k, interpret=interpret)
+                        block_k=block_k, interpret=interpret, scale=scale)
     return out
 
 
-def _vjp_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _vjp_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
     if interpret is None:
         interpret = interpret_mode()
     out, lse = _flash_fwd(q, k, v, causal=causal, block_q=block_q,
-                          block_k=block_k, interpret=interpret)
+                          block_k=block_k, interpret=interpret, scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _vjp_bwd(causal, block_q, block_k, interpret, res, g):
+def _vjp_bwd(causal, block_q, block_k, interpret, scale, res, g):
     if interpret is None:
         interpret = interpret_mode()
     q, k, v, out, lse = res
     return _flash_bwd(q, k, v, out, lse, g, causal=causal, block_q=block_q,
-                      block_k=block_k, interpret=interpret)
+                      block_k=block_k, interpret=interpret, scale=scale)
 
 
 flash_attention.defvjp(_vjp_fwd, _vjp_bwd)
 
 
 def make_flash_attention(*, block_q: int = 128, block_k: int = 128,
-                         causal: bool = True, mesh=None):
+                         causal: bool = True, mesh=None,
+                         scale: float | None = None):
     """An `attn_fn` for strom.models.llama.forward(..., attn_fn=...).
 
     With *mesh* (a jitted step over a device mesh), the kernel runs per
@@ -385,7 +414,7 @@ def make_flash_attention(*, block_q: int = 128, block_k: int = 128,
     device (inside a shard_map) or on one device."""
 
     def attn(q, k, v):
-        return flash_attention(q, k, v, causal, block_q, block_k)
+        return flash_attention(q, k, v, causal, block_q, block_k, None, scale)
 
     if mesh is None:
         return attn

@@ -46,7 +46,7 @@ def param_specs(params: dict) -> dict:
         lambda path, _: _LLAMA_RULES.get(_path_key(path), P()), params)
 
 
-def param_shardings(params: dict, mesh: Mesh) -> dict:
+def _restricted(specs: dict, mesh: Mesh) -> dict:
     """Specs restricted to the mesh's axes: a rule axis the mesh doesn't have
     (e.g. tp on a dp×sp mesh) degrades to replicated on that dim."""
 
@@ -54,8 +54,11 @@ def param_shardings(params: dict, mesh: Mesh) -> dict:
         return P(*(ax if ax in mesh.axis_names else None for ax in spec))
 
     return jax.tree.map(lambda spec: NamedSharding(mesh, restrict(spec)),
-                        param_specs(params),
-                        is_leaf=lambda x: isinstance(x, P))
+                        specs, is_leaf=lambda x: isinstance(x, P))
+
+
+def param_shardings(params: dict, mesh: Mesh) -> dict:
+    return _restricted(param_specs(params), mesh)
 
 
 # MoE variant: attention rules shared with llama; expert FFN stacks carry the
@@ -76,12 +79,36 @@ def moe_param_specs(params: dict) -> dict:
 
 
 def moe_param_shardings(params: dict, mesh: Mesh) -> dict:
-    def restrict(spec: P) -> P:
-        return P(*(ax if ax in mesh.axis_names else None for ax in spec))
+    return _restricted(moe_param_specs(params), mesh)
 
-    return jax.tree.map(lambda spec: NamedSharding(mesh, restrict(spec)),
-                        moe_param_specs(params),
-                        is_leaf=lambda x: isinstance(x, P))
+
+# DeepSeek-V2 (strom.models.deepseek_v2): the same column/row pairs on tp;
+# stacked expert layers carry "pp" on their leading axis. The routed experts
+# are this rank's held share already (expert parallelism is the choice of
+# held experts, not a sharded axis), so their expert dim stays whole.
+_DSV2_COLUMN = {"wq", "wkv_b", "w_gate", "w_up", "shared_gate", "shared_up"}
+_DSV2_ROW = {"wo", "w_down", "shared_down"}
+
+
+def deepseek_v2_param_specs(params: dict) -> dict:
+    def spec(path, leaf) -> P:
+        key = _path_key(path)
+        if key[0] in ("embed", "lm_head"):
+            return P(None, "tp")
+        name = key[-1]
+        lead = ["pp"] if key[0] == "moe" else []
+        body = [None] * (leaf.ndim - len(lead))
+        if name in _DSV2_COLUMN:
+            body[-1] = "tp"
+        elif name in _DSV2_ROW:
+            body[-2] = "tp"
+        return P(*lead, *body)
+
+    return jax.tree_util.tree_map_with_path(spec, params)
+
+
+def deepseek_v2_param_shardings(params: dict, mesh: Mesh) -> dict:
+    return _restricted(deepseek_v2_param_specs(params), mesh)
 
 
 def batch_spec(*, sp: bool = False) -> P:

@@ -1,4 +1,6 @@
-"""Sharded training step for the flagship model (pjit over a dp×tp mesh).
+"""Sharded training steps (pjit over a dp×tp mesh): one function,
+:func:`make_step`, makes them for the Llama, Switch-MoE and DeepSeek-V2
+models.
 
 This is the consumer the data path feeds: strom loaders deliver token batches
 already sharded over ("dp", ...) and the step runs under jit with explicit
@@ -68,13 +70,9 @@ def init_train_state(key: jax.Array, cfg: LlamaConfig, mesh: Mesh,
                        mesh, optimizer)
 
 
-def make_train_step(cfg: LlamaConfig, mesh: Mesh,
-                    optimizer: optax.GradientTransformation, *,
-                    attn: str = "dense", sp: bool = False, donate: bool = True):
-    """Compile a (state, tokens) -> (state, metrics) step.
-
-    tokens arrive sharded P("dp"[, "sp"]) — exactly the sharding
-    strom.pipelines loaders deliver — so no resharding happens on entry.
+def _attn_fn(attn: str, sp: bool, mesh: Mesh):
+    """The attention a step's model runs: None (the model's dense op),
+    the Pallas flash kernel, or with sp=True the ring over the "sp" axis.
 
     attn="flash": the Pallas flash-attention kernel (blockwise forward AND
     backward, O(S) memory — strom.ops.flash_attention) replaces the dense op
@@ -84,45 +82,72 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh,
     sp=True: activations stay sequence-sharded and attention runs the ring
     algorithm (kv blocks rotate over ICI neighbor hops) instead of letting
     XLA all-gather the whole sequence — peak memory O(S/n_sp) per device.
-    """
+    With attn="flash" the Pallas kernels run INSIDE the ring — each ring step
+    is a real flash block (fwd + blockwise bwd), merged by logsumexp."""
     if attn not in ("dense", "flash", "zigzag"):
         raise ValueError(
             f"attn must be 'dense', 'flash' or 'zigzag', got {attn!r}")
     if attn == "zigzag" and not sp:
         raise ValueError("attn='zigzag' is the load-balanced causal RING; "
                          "it needs sp=True")
-    batch_sharding = NamedSharding(mesh, P("dp", "sp") if sp else P("dp", None))
-    attn_fn = None
     if sp:
         from strom.parallel.ring import make_ring_attention
 
-        # attn="flash": the Pallas kernels run INSIDE the ring — each ring
-        # step is a real flash block (fwd + blockwise bwd), merged by
-        # logsumexp. The flagship long-context combination: O(S/n_sp)
-        # activations AND no [S_loc, S_loc] materialization per step.
-        attn_fn = make_ring_attention(mesh, axis="sp", impl=attn)
-    elif attn == "flash":
+        return make_ring_attention(mesh, axis="sp", impl=attn)
+    if attn == "flash":
         from strom.ops.flash_attention import make_flash_attention
 
-        attn_fn = make_flash_attention(mesh=mesh)
+        return make_flash_attention(mesh=mesh)
+    return None
 
-    def loss_fn(params, tokens):
-        return next_token_loss(params, tokens, cfg, attn_fn=attn_fn, remat=True)
+
+def make_step(loss_fn, init_fn, shardings_fn, mesh: Mesh,
+              optimizer: optax.GradientTransformation, *,
+              batch_sharding: NamedSharding, donate: bool = True):
+    """Compile a (state, tokens) -> (state, metrics) step for any model.
+
+    ``loss_fn(params, tokens) -> (loss, extra)``: *extra* is a dict of
+    further metrics the step returns beside ``loss`` and ``grad_norm``.
+    ``init_fn(key) -> params`` and ``shardings_fn(params, mesh)`` fix the
+    state's shardings, which the step gives back, so its first call compiles
+    the only signature it will see. tokens arrive with *batch_sharding* —
+    exactly the sharding strom.pipelines loaders deliver — so no resharding
+    happens on entry."""
 
     def step(state: TrainState, tokens: jax.Array):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
+        (loss, extra), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            state.params, tokens)
         updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
         new_params = optax.apply_updates(state.params, updates)
-        metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
+        metrics = {"loss": loss, "grad_norm": optax.global_norm(grads), **extra}
         return TrainState(new_params, new_opt, state.step + 1), metrics
 
-    state_shardings = _state_shardings(jax.random.key(0),
-                                       partial(init_params, cfg=cfg),
-                                       param_shardings, mesh, optimizer)
+    state_shardings = _state_shardings(jax.random.key(0), init_fn,
+                                       shardings_fn, mesh, optimizer)
     donate_argnums = (0,) if donate else ()
     return jax.jit(step, in_shardings=(None, batch_sharding),
                    out_shardings=(state_shardings, NamedSharding(mesh, P())),
                    donate_argnums=donate_argnums)
+
+
+def _token_sharding(mesh: Mesh, sp: bool) -> NamedSharding:
+    return NamedSharding(mesh, P("dp", "sp") if sp else P("dp", None))
+
+
+def make_train_step(cfg: LlamaConfig, mesh: Mesh,
+                    optimizer: optax.GradientTransformation, *,
+                    attn: str = "dense", sp: bool = False, donate: bool = True):
+    """The Llama step (:func:`make_step`); tokens P("dp"[, "sp"]), attention
+    as :func:`_attn_fn` picks it."""
+    attn_fn = _attn_fn(attn, sp, mesh)
+
+    def loss_fn(params, tokens):
+        return next_token_loss(params, tokens, cfg, attn_fn=attn_fn,
+                               remat=True), {}
+
+    return make_step(loss_fn, partial(init_params, cfg=cfg), param_shardings,
+                     mesh, optimizer, batch_sharding=_token_sharding(mesh, sp),
+                     donate=donate)
 
 
 def init_moe_train_state(key: jax.Array, cfg, mesh: Mesh,
@@ -145,45 +170,49 @@ def make_moe_train_step(cfg, mesh: Mesh,
     from strom.models import moe
     from strom.parallel.sharding import moe_param_shardings
 
-    if attn not in ("dense", "flash", "zigzag"):
-        raise ValueError(
-            f"attn must be 'dense', 'flash' or 'zigzag', got {attn!r}")
-    if attn == "zigzag" and not sp:
-        raise ValueError("attn='zigzag' is the load-balanced causal RING; "
-                         "it needs sp=True")
-    batch_sharding = NamedSharding(mesh, P("dp", "sp") if sp else P("dp", None))
-    attn_fn = None
-    if sp:
-        from strom.parallel.ring import make_ring_attention
-
-        # attn="flash": the Pallas kernels run INSIDE the ring — each ring
-        # step is a real flash block (fwd + blockwise bwd), merged by
-        # logsumexp. The flagship long-context combination: O(S/n_sp)
-        # activations AND no [S_loc, S_loc] materialization per step.
-        attn_fn = make_ring_attention(mesh, axis="sp", impl=attn)
-    elif attn == "flash":
-        from strom.ops.flash_attention import make_flash_attention
-
-        attn_fn = make_flash_attention(mesh=mesh)
+    attn_fn = _attn_fn(attn, sp, mesh)
 
     def loss_fn(params, tokens):
         return moe.next_token_loss(params, tokens, cfg, attn_fn=attn_fn,
-                                   remat=True)
+                                   remat=True), {}
 
-    def step(state: TrainState, tokens: jax.Array):
-        loss, grads = jax.value_and_grad(loss_fn)(state.params, tokens)
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
-        metrics = {"loss": loss, "grad_norm": optax.global_norm(grads)}
-        return TrainState(new_params, new_opt, state.step + 1), metrics
+    return make_step(loss_fn, partial(moe.init_params, cfg=cfg),
+                     moe_param_shardings, mesh, optimizer,
+                     batch_sharding=_token_sharding(mesh, sp), donate=donate)
 
-    state_shardings = _state_shardings(jax.random.key(0),
-                                       partial(moe.init_params, cfg=cfg),
-                                       moe_param_shardings, mesh, optimizer)
-    donate_argnums = (0,) if donate else ()
-    return jax.jit(step, in_shardings=(None, batch_sharding),
-                   out_shardings=(state_shardings, NamedSharding(mesh, P())),
-                   donate_argnums=donate_argnums)
+
+def init_deepseek_v2_train_state(key: jax.Array, cfg, mesh: Mesh,
+                                 optimizer: optax.GradientTransformation
+                                 ) -> TrainState:
+    from strom.models import deepseek_v2
+    from strom.parallel.sharding import deepseek_v2_param_shardings
+
+    return _init_state(key, partial(deepseek_v2.init_params, cfg=cfg),
+                       deepseek_v2_param_shardings, mesh, optimizer)
+
+
+def make_deepseek_v2_train_step(cfg, mesh: Mesh,
+                                optimizer: optax.GradientTransformation, *,
+                                donate: bool = True):
+    """The DeepSeek-V2 step: tokens [B, S+1] P("dp") from
+    ``make_llama_pipeline``, float32 masters, bfloat16 compute, per-layer
+    remat, MLA through the flash kernel at the model's softmax scale.
+    Metrics: ``loss``, ``grad_norm`` and ``expert_assignments`` ([expert
+    layers, held experts] int32: the (token, held expert) pairs computed)."""
+    from strom.models import deepseek_v2
+    from strom.ops.flash_attention import make_flash_attention
+    from strom.parallel.sharding import deepseek_v2_param_shardings
+
+    attn_fn = make_flash_attention(mesh=mesh, scale=cfg.softmax_scale)
+
+    def loss_fn(params, tokens):
+        return deepseek_v2.next_token_loss(params, tokens, cfg,
+                                           attn_fn=attn_fn, remat=True)
+
+    return make_step(loss_fn, partial(deepseek_v2.init_params, cfg=cfg),
+                     deepseek_v2_param_shardings, mesh, optimizer,
+                     batch_sharding=_token_sharding(mesh, False),
+                     donate=donate)
 
 
 jax.tree_util.register_dataclass(TrainState,

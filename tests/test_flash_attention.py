@@ -142,6 +142,50 @@ class TestFlashAttention:
         assert np.argmax(d[0, -1]) == np.argmax(f[0, -1])  # same prediction
 
 
+# (Dqk, Dv, scale): the square head at the default 1/√Dqk, and multi-head
+# latent attention's 192-wide queries and keys, 128-wide values, and YaRN's
+# scale (DeepSeek-V2: 192^-½ × (0.1·0.707·ln 40 + 1)²).
+WIDTHS = [(64, 64, None),
+          (192, 128, 192 ** -0.5 * (0.1 * 0.707 * np.log(40) + 1) ** 2)]
+
+
+def _qkv_split(rng, B, S, H, Dqk, Dv):
+    q = jnp.array(rng.normal(size=(B, S, H, Dqk)), jnp.float32)
+    k = jnp.array(rng.normal(size=(B, S, H, Dqk)), jnp.float32)
+    v = jnp.array(rng.normal(size=(B, S, H, Dv)), jnp.float32)
+    return q, k, v
+
+
+@pytest.mark.parametrize("Dqk,Dv,scale", WIDTHS)
+def test_forward_with_value_width_and_scale(Dqk, Dv, scale):
+    q, k, v = _qkv_split(np.random.default_rng(10), 1, 256, 2, Dqk, Dv)
+    out = flash_attention(q, k, v, True, 128, 128, None, scale)
+    assert out.shape == (1, 256, 2, Dv)
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense_ref(q, k, v, True, scale)),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("Dqk,Dv,scale", WIDTHS)
+def test_backward_with_value_width_and_scale(Dqk, Dv, scale):
+    """dq and dk keep the query/key width, dv the value width."""
+    q, k, v = _qkv_split(np.random.default_rng(11), 1, 256, 2, Dqk, Dv)
+
+    def f_flash(q_, k_, v_):
+        return jnp.sum(flash_attention(q_, k_, v_, True, 128, 128, None,
+                                       scale) ** 2)
+
+    def f_dense(q_, k_, v_):
+        return jnp.sum(_dense_ref(q_, k_, v_, True, scale) ** 2)
+
+    got = jax.grad(f_flash, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(f_dense, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4, err_msg=name)
+
+
 @pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
 def test_interpret_mode_per_backend(backend, interpret):
     """Interpreted on the CPU (tests), compiled Mosaic on a TPU."""

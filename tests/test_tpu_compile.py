@@ -72,6 +72,23 @@ def test_flash_compiles_for_v5e(one_chip, shape, fn):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
+def test_mla_flash_compiles_for_v5e(one_chip, grad):
+    """DeepSeek-V2-Lite's attention at the benchmark cell's shape: 192-wide
+    queries and keys, 128-wide values, YaRN's scale, batch 8 at 4,096."""
+    q = jax.ShapeDtypeStruct((8, 4096, 16, 192), jnp.bfloat16,
+                             sharding=one_chip)
+    v = jax.ShapeDtypeStruct((8, 4096, 16, 128), jnp.bfloat16,
+                             sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, True, 128, 128, False, 0.1)
+
+    fn = (lambda *a: jax.grad(lambda *b: fwd(*b).astype(jnp.float32).sum(),
+                              argnums=(0, 1, 2))(*a)) if grad else fwd
+    assert "tpu_custom_call" in jax.jit(fn).lower(q, q, v).compile().as_text()
+
+
 def test_ring_flash_compiles_on_four_chips(topo):
     """ring x flash under shard_map, sp=4 over the described 2x2 mesh,
     forward and backward."""
