@@ -9,6 +9,7 @@ off around these compiles, since entries for a described chip cannot be read
 back without one.
 """
 
+import functools
 import os
 
 import numpy as np
@@ -56,37 +57,62 @@ def _qkv(shape, sharding):
     return q, kv, kv
 
 
-def _fwd(q, k, v):
-    return flash_attention(q, k, v, True, 128, 128, False)
+def _fwd(q, k, v, block=128):
+    return flash_attention(q, k, v, True, block, block, False)
 
 
-def _bwd(q, k, v):
-    return jax.grad(lambda *a: _fwd(*a).astype(jnp.float32).sum(),
+def _bwd(q, k, v, block=128):
+    return jax.grad(lambda *a: _fwd(*a, block).astype(jnp.float32).sum(),
                     argnums=(0, 1, 2))(q, k, v)
 
 
 @pytest.mark.parametrize("shape", list(SHAPES), ids=list(SHAPES))
 @pytest.mark.parametrize("fn", [_fwd, _bwd], ids=["fwd", "bwd"])
 def test_flash_compiles_for_v5e(one_chip, shape, fn):
-    compiled = jax.jit(fn).lower(*_qkv(SHAPES[shape], one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    """At 128×128 tiles and at the plan's (blocks None)."""
+    for block in (128, None):
+        compiled = jax.jit(functools.partial(fn, block=block)).lower(
+            *_qkv(SHAPES[shape], one_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text(), block
 
 
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
 def test_mla_flash_compiles_for_v5e(one_chip, grad):
     """DeepSeek-V2-Lite's attention at the benchmark cell's shape: 192-wide
-    queries and keys, 128-wide values, YaRN's scale, batch 8 at 4,096."""
+    queries and keys, 128-wide values, YaRN's scale, batch 8 at 4,096; at
+    128×128 tiles and at the plan's (blocks None), which must fit the
+    chip's VMEM."""
     q = jax.ShapeDtypeStruct((8, 4096, 16, 192), jnp.bfloat16,
                              sharding=one_chip)
     v = jax.ShapeDtypeStruct((8, 4096, 16, 128), jnp.bfloat16,
                              sharding=one_chip)
 
-    def fwd(q, k, v):
-        return flash_attention(q, k, v, True, 128, 128, False, 0.1)
+    for block in (128, None):
+        def fwd(q, k, v, block=block):
+            return flash_attention(q, k, v, True, block, block, False, 0.1)
 
-    fn = (lambda *a: jax.grad(lambda *b: fwd(*b).astype(jnp.float32).sum(),
-                              argnums=(0, 1, 2))(*a)) if grad else fwd
-    assert "tpu_custom_call" in jax.jit(fn).lower(q, q, v).compile().as_text()
+        fn = (lambda *a, fwd=fwd: jax.grad(
+            lambda *b: fwd(*b).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(*a)) if grad else fwd
+        text = jax.jit(fn).lower(q, q, v).compile().as_text()
+        assert "tpu_custom_call" in text, block
+
+
+@pytest.mark.parametrize("dqk,dv,dtype", [
+    pytest.param(256, 256, jnp.bfloat16, id="256-256"),
+    pytest.param(576, 512, jnp.bfloat16, id="576-512"),
+    pytest.param(192, 128, jnp.float32, id="192-128-float32"),
+    pytest.param(256, 256, jnp.float32, id="256-256-float32")])
+def test_plan_tiles_fit_vmem_at_wider_heads(one_chip, dqk, dv, dtype):
+    """Wider heads than the benchmark's take smaller tiles: the plan's pair
+    must still fit the chip's VMEM in all three kernels (the forward runs
+    inside the gradient), in float32 as in bf16."""
+    q = jax.ShapeDtypeStruct((1, 4096, 2, dqk), dtype, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 4096, 2, dv), dtype, sharding=one_chip)
+    grad = jax.grad(lambda *a: flash_attention(*a, True, None, None, False)
+                    .astype(jnp.float32).sum(), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(q, q, v).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
 
 
 def test_ring_flash_compiles_on_four_chips(topo):
