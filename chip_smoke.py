@@ -2,11 +2,10 @@
 """Run strom-tpu's main path once on a TPU and check what comes out.
 
 NVMe -> io_uring/O_DIRECT engine -> host slabs -> device_put -> jax.Array in
-HBM -> the training or scan step that consumes it, through the library and
-the ``strom.cli`` bench functions, in this one process (a child that touched
-JAX would fight it for the chip). Every fixture is generated from a seed
-under ``.chip_smoke_data/`` in the checkout (O_DIRECT needs a real
-filesystem, not tmpfs). Every phase raises on failure; nothing is retried.
+HBM -> the training or scan step that consumes it, through the library, in
+this one process (a child that touched JAX would fight it for the chip).
+Every fixture is generated from a seed under ``.chip_smoke_data/`` in the
+checkout (O_DIRECT needs a real filesystem, not tmpfs). Every phase raises on failure; nothing is retried.
 
     python chip_smoke.py               # one chip: device, bulk delivery,
                                        # ResNet-50, llama+flash, parquet scan
@@ -21,7 +20,6 @@ it is printed only when every phase passed on a TPU.
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import math
 import os
@@ -73,6 +71,15 @@ def assert_matches_file(arr, path: str, offset: int = 0,
                                  f"byte {offset + bad}")
 
 
+def drop_cache_hint(path: str) -> None:
+    """fsync + posix_fadvise(DONTNEED), so a read reaches the media and not
+    the page cache (freshly written pages are dirty and stay cached until
+    synced)."""
+    from strom.probe.residency import drop_cache
+
+    drop_cache(path)
+
+
 # -- phases -------------------------------------------------------------------
 def device_phase(count: int) -> dict:
     """The devices must be *count* TPUs; no CPU fallback."""
@@ -108,7 +115,6 @@ def bulk_phase(path: str, size: int, probe_bytes: int) -> None:
     """BASELINE config 1 into HBM: sync, async, then the slab-recycling
     probe (two streamed transfers back to back with the pool on)."""
     import strom
-    from strom.cli import _drop_cache_hint
     from strom.config import StromConfig
     from strom.delivery.core import StromContext
 
@@ -116,7 +122,7 @@ def bulk_phase(path: str, size: int, probe_bytes: int) -> None:
     if size < ctx.config.overlap_min_bytes:
         raise ValueError(f"bulk size {size} is below overlap_min_bytes: the "
                          "streamed slab path would not run")
-    _drop_cache_hint(path)
+    drop_cache_hint(path)
     t0 = time.perf_counter()
     arr = strom.memcpy_ssd2tpu(path, length=size)
     arr.block_until_ready()
@@ -131,7 +137,7 @@ def bulk_phase(path: str, size: int, probe_bytes: int) -> None:
         f"streamed assembly")
     del arr
 
-    _drop_cache_hint(path)
+    drop_cache_hint(path)
     t0 = time.perf_counter()
     handle = strom.memcpy_ssd2tpu(path, length=size, async_=True)
     arr = strom.memcpy_wait(handle)
@@ -148,7 +154,7 @@ def bulk_phase(path: str, size: int, probe_bytes: int) -> None:
     cfg = StromConfig(overlap_chunk_bytes=chunk, overlap_min_bytes=2 * chunk)
     probe = StromContext(cfg)
     try:
-        _drop_cache_hint(path)
+        drop_cache_hint(path)
         first = probe.memcpy_ssd2tpu(path, offset=0, length=probe_bytes)
         second = probe.memcpy_ssd2tpu(path, offset=probe_bytes,
                                       length=probe_bytes)
@@ -163,93 +169,158 @@ def bulk_phase(path: str, size: int, probe_bytes: int) -> None:
         f"byte-exact; pool hits={pool['hits']} misses={pool['misses']}")
 
 
+def make_wds_tar(path: str, n_samples: int, image_size: int) -> None:
+    """A WebDataset tar of *n_samples* seeded random q90 JPEGs, each twice
+    *image_size* a side, with a ``.cls`` label beside each."""
+    import io
+    import tarfile
+
+    import cv2
+    import numpy as np
+
+    rng = np.random.default_rng(SEED)
+    with tarfile.open(path, "w") as tf:
+        for i in range(n_samples):
+            img = rng.integers(0, 256, (image_size * 2, image_size * 2, 3),
+                               dtype=np.uint8)
+            ok, buf = cv2.imencode(".jpg", img, [cv2.IMWRITE_JPEG_QUALITY, 90])
+            assert ok
+            for name, data in ((f"s{i:06d}.jpg", buf.tobytes()),
+                               (f"s{i:06d}.cls", str(i % 1000).encode())):
+                info = tarfile.TarInfo(name)
+                info.size = len(data)
+                tf.addfile(info, io.BytesIO(data))
+    drop_cache_hint(path)
+
+
+def train_loop(pipe, step, steps: int) -> tuple[float, float, int]:
+    """One warm-up step (its compile included), then *steps* more on the
+    clock, ended by a host fetch of the loss. Returns (loss, steps per
+    second, data-stall steps)."""
+    float(step(next(pipe)))
+    base = pipe.data_stall_steps
+    t0 = time.perf_counter()
+    for _ in range(steps):
+        loss = step(next(pipe))
+    loss = float(loss)
+    rate = steps / (time.perf_counter() - t0)
+    return loss, rate, pipe.data_stall_steps - base
+
+
 def resnet_phase(batch: int, image_size: int, steps: int) -> None:
-    """BASELINE config 2: ``strom-bench resnet --predecoded --train-step``,
-    then the first delivered batch against a numpy read of the records."""
+    """BASELINE config 2 from predecoded records: a JPEG tar staged once as
+    a ``.pdec`` shard, its first delivered batch against a numpy read of
+    the records, then SGD train steps on what the pipeline delivers."""
+    import functools
+
     import jax
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from strom.cli import build_parser
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
+    import strom
+    from strom.formats.predecoded import LABELS_SUFFIX, predecode_wds
+    from strom.models.resnet import (ResNetConfig, init_params, loss_fn,
+                                     normalize_images)
     from strom.parallel.mesh import make_mesh
     from strom.pipelines import make_predecoded_vision_pipeline
 
-    args = build_parser().parse_args([
-        "resnet", "--predecoded", "--train-step", "--model", RESNET_MODEL,
-        "--batch", str(batch), "--image-size", str(image_size),
-        "--steps", str(steps), "--prefetch", str(steps + 2),
-        "--tmpdir", DATA, "--json"])
-    out = args.fn(args)
-    loss = out["train_loss"]
-    if not math.isfinite(loss):
-        raise AssertionError(f"resnet train loss is not finite: {loss}")
-    log(f"resnet: {RESNET_MODEL} {batch}x{image_size}^2 {steps} steps, loss={loss} "
-        f"finite; train {out['train_images_per_s']} img/s, "
-        f"{out['train_data_stalls']} stalls (smoke figures)")
-
-    (pdec,) = glob.glob(os.path.join(DATA, f"*.{image_size}.pdec"))
+    ctx = strom.context()
+    tar = os.path.join(DATA, "images.tar")
+    make_wds_tar(tar, max(batch * 4, 256), image_size)
+    pdec = predecode_wds(ctx, [tar], os.path.join(DATA, "images.pdec"),
+                         image_size=image_size)
+    drop_cache_hint(pdec)
     record = (image_size, image_size, 3)
     records = np.memmap(pdec, dtype=np.uint8, mode="r").reshape(-1, *record)
-    labels = np.load(pdec + ".labels.npy")
+    labels = np.load(pdec + LABELS_SUFFIX)
     # the pipeline's epoch-0 order: Philox(seed 0, epoch 0) permutation
     idx = np.random.Generator(np.random.Philox(key=[0, 0])).permutation(
         records.shape[0])[:batch]
+
+    cfg = getattr(ResNetConfig, RESNET_MODEL)()
     mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
-    sharding = NamedSharding(mesh, P("dp", None, None, None))
-    ctx = StromContext(StromConfig())
-    try:
-        with make_predecoded_vision_pipeline(
-                ctx, [pdec], batch=batch, image_size=image_size,
-                sharding=sharding) as pipe:
-            imgs, lbls = next(pipe)
-            imgs, lbls = np.asarray(imgs), np.asarray(lbls)
-    finally:
-        ctx.close()
-    if not (np.array_equal(imgs, records[idx])
-            and np.array_equal(lbls, labels[idx])):
-        raise AssertionError("first predecoded batch differs from the "
-                             "numpy read of its records")
-    log(f"resnet: first batch ({batch} records) equals the numpy read")
+    # replicated, as the step gives them back: one compile
+    replicated = NamedSharding(mesh, P())
+    params, bn_state = jax.device_put(init_params(jax.random.key(0), cfg),
+                                      replicated)
+
+    @functools.partial(jax.jit, donate_argnums=(0,), out_shardings=replicated)
+    def sgd_step(p, s, images, labels):
+        (loss, new_s), grads = jax.value_and_grad(loss_fn, has_aux=True)(
+            p, s, normalize_images(images), labels, cfg)
+        return jax.tree.map(lambda w, g: w - 1e-3 * g, p, grads), new_s, loss
+
+    def step(b):
+        nonlocal params, bn_state
+        params, bn_state, loss = sgd_step(params, bn_state, b[0],
+                                          b[1] % cfg.num_classes)
+        return loss
+
+    with make_predecoded_vision_pipeline(
+            ctx, [pdec], batch=batch, image_size=image_size,
+            sharding=NamedSharding(mesh, P("dp", None, None, None)),
+            prefetch_depth=steps + 2) as pipe:
+        imgs, lbls = next(pipe)
+        if not (np.array_equal(np.asarray(imgs), records[idx])
+                and np.array_equal(np.asarray(lbls), labels[idx])):
+            raise AssertionError("first predecoded batch differs from the "
+                                 "numpy read of its records")
+        log(f"resnet: first batch ({batch} records) equals the numpy read")
+        loss, rate, stalls = train_loop(pipe, step, steps)
+    if not math.isfinite(loss):
+        raise AssertionError(f"resnet train loss is not finite: {loss}")
+    log(f"resnet: {RESNET_MODEL} {batch}x{image_size}^2 {steps} steps, "
+        f"loss={loss} finite; train {rate * batch:.1f} img/s, {stalls} "
+        f"stalls (smoke figures)")
 
 
 def llama_phase(batch: int, seq_len: int, steps: int) -> None:
-    """``strom-bench llama --train-step --attn flash``; the lowered step must
-    hold the Mosaic kernel, and flash must match dense on the device."""
+    """The llama pipeline feeding the flash train step; the lowered step
+    must hold the Mosaic kernel, and flash must match dense on the
+    device."""
     import jax
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from strom.cli import build_parser
+    import strom
     from strom.models.llama import LlamaConfig
     from strom.ops.flash_attention import _dense_ref, flash_attention
     from strom.parallel.mesh import make_mesh
     from strom.parallel.train import (init_train_state, make_optimizer,
                                       make_train_step)
-
-    args = build_parser().parse_args([
-        "llama", "--train-step", "--model", LLAMA_MODEL, "--attn", "flash",
-        "--seq-len", str(seq_len), "--batch", str(batch),
-        "--steps", str(steps), "--prefetch", str(steps + 2),
-        "--tmpdir", DATA, "--json"])
-    out = args.fn(args)
-    loss = out["train_loss"]
-    if not math.isfinite(loss):
-        raise AssertionError(f"llama train loss is not finite: {loss}")
-    log(f"llama: {LLAMA_MODEL} flash {batch}x{seq_len + 1} {steps} steps, "
-        f"loss={loss} finite; train {out['train_tokens_per_s']} tok/s, "
-        f"{out['train_data_stalls']} stalls (smoke figures)")
+    from strom.pipelines import make_llama_pipeline
 
     cfg = getattr(LlamaConfig, LLAMA_MODEL)()
+    tok_path = os.path.join(DATA, "tokens.bin")
+    rng = np.random.default_rng(SEED)
+    rng.integers(0, cfg.vocab, ((steps + 2) * batch * 2, seq_len + 1),
+                 dtype=np.int32).tofile(tok_path)
+    drop_cache_hint(tok_path)
     mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
     opt = make_optimizer()
     state = init_train_state(jax.random.key(0), cfg, mesh, opt)
-    step = make_train_step(cfg, mesh, opt, attn="flash")
+    step_fn = make_train_step(cfg, mesh, opt, attn="flash")
+
+    def step(toks):
+        nonlocal state
+        state, m = step_fn(state, toks)
+        return m["loss"]
+
+    sharding = NamedSharding(mesh, P("dp", None))
+    with make_llama_pipeline(strom.context(), [tok_path], batch=batch,
+                             seq_len=seq_len, sharding=sharding,
+                             prefetch_depth=steps + 2) as pipe:
+        loss, rate, stalls = train_loop(pipe, step, steps)
+    if not math.isfinite(loss):
+        raise AssertionError(f"llama train loss is not finite: {loss}")
+    log(f"llama: {LLAMA_MODEL} flash {batch}x{seq_len + 1} {steps} steps, "
+        f"loss={loss} finite; train {rate * batch * (seq_len + 1):.1f} "
+        f"tok/s, {stalls} stalls (smoke figures)")
+
     toks = jax.ShapeDtypeStruct((batch, seq_len + 1), jnp.int32,
-                                sharding=NamedSharding(mesh, P("dp", None)))
-    text = step.lower(state, toks).as_text()
+                                sharding=sharding)
+    text = step_fn.lower(state, toks).as_text()
     if "tpu_custom_call" not in text:
         raise AssertionError("lowered flash train step holds no "
                              "tpu_custom_call: the kernel was interpreted")

@@ -28,9 +28,10 @@ nobody is watching. One in-flight save at a time: a second ``save`` first
 waits out the current commit (back-pressure, counted in the stall timer),
 so the checkpointer can never queue unbounded snapshots.
 
-``CKPT_ASYNC_FIELDS`` single-sources the bench columns the ``resume`` arm
-emits (cli.py bench_resume → bench.py copy loop → compare_rounds "resume"
-section → bench_sentinel gate on ``ckpt_async_stall_p99_us``).
+``CKPT_ASYNC_FIELDS`` names the columns of the checked-in pre-chip
+benchmark records that ``tools/compare_rounds.py`` reads for its "resume"
+section (and ``tools/bench_sentinel.py`` gates on
+``ckpt_async_stall_p99_us``).
 """
 
 from __future__ import annotations
@@ -46,10 +47,8 @@ from strom.ckpt.checkpoint import (CkptError, _build_manifest,
                                    load_manifest)
 from strom.utils.locks import make_lock
 
-# bench-JSON columns the resume arm's async-save phase emits (cli.py
-# bench_resume), single-sourced so the driver's copy loop (bench.py) and
-# the compare_rounds "resume" section cannot drift from the producer —
-# the same contract CKPT_FIELDS / SPILL_FIELDS enforce.
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "resume" section (async save).
 CKPT_ASYNC_FIELDS = (
     "ckpt_async_saves",
     "ckpt_async_stall_p99_us",

@@ -46,10 +46,8 @@ ALIGN = 4096          # leaf-span alignment: O_DIRECT offset granularity
 FORMAT = "strom-ckpt-v1"
 _STAGE_BYTES = 32 << 20   # staging slab per write flush
 
-# bench-JSON columns the checkpoint bench phase emits (cli.py
-# bench_checkpoint), single-sourced so the driver's copy loop (bench.py)
-# and the compare_rounds "write path" section cannot drift from the
-# producer — the same contract CACHE_BENCH_FIELDS / SPILL_FIELDS enforce.
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "write path" section.
 CKPT_FIELDS = (
     "ckpt_bytes",
     "ckpt_leaves",

@@ -1,1956 +1,20 @@
-"""strom-bench — benchmark CLI (``python -m strom.cli`` or the ``strom-bench``
-script).
+"""strom-bench — the ``strom-bench`` script (``python -m strom.cli``).
 
-Reproduces the reference's two benchmark utilities (SURVEY.md §2.1/§3.4;
-reference cite UNVERIFIED — empty mount, SURVEY.md §0):
+- ``strom-bench daemon`` runs one long-lived :class:`StromContext` with its
+  scheduler, serving external tenants over the live HTTP surface.
+- ``strom-bench check <path>`` (≙ the reference's ``CHECK_FILE``) reports a
+  file's data-path tier.
 
-- ``strom-bench nvme``    ≙ ``utils/nvme_test``: CPU-only O_DIRECT sequential
-  read, 128KiB blocks → host RAM. This is BASELINE config #1 (BASELINE.json:7)
-  and defines "raw NVMe read bandwidth", the ≥90% target's denominator.
-- ``strom-bench ssd2tpu`` ≙ ``utils/ssd2gpu_test``: async copy loop at queue
-  depth into device memory, reporting GB/s.
+The benchmark is ``python3 benchmark/run.py`` (see ``BENCHMARK.json``).
 """
 
 from __future__ import annotations
 
 import argparse
-import contextlib
-import dataclasses
 import json
 import os
-import shutil
 import sys
 import threading
-import time
-
-import numpy as np
-
-
-def _mk_testfile(path: str, size: int) -> None:
-    """Create a benchmark file of *size* bytes (incompressible-ish pattern)."""
-    rng = np.random.default_rng(0)
-    block = 8 * 1024 * 1024
-    with open(path, "wb") as f:
-        remaining = size
-        while remaining > 0:
-            take = min(block, remaining)
-            f.write(rng.integers(0, 256, size=take, dtype=np.uint8).tobytes())
-            remaining -= take
-    os.sync()
-
-
-def _drop_cache_hint(path: str) -> None:
-    """fsync + posix_fadvise(DONTNEED) so repeat runs measure media, not page
-    cache. The fsync matters: freshly-written fixture pages are DIRTY and
-    unevictable, so without it the first run after generation would ride the
-    residency hybrid's cache path while every later run hits media — the
-    bench must be deterministic-cold."""
-    from strom.probe.residency import drop_cache
-
-    drop_cache(path)
-
-
-def bench_nvme(args: argparse.Namespace) -> dict:
-    """Config #1: O_DIRECT sequential read, block-size chunks → host RAM."""
-    from strom.config import StromConfig
-    from strom.delivery.buffers import alloc_aligned
-    from strom.engine import make_engine
-
-    path = args.file
-    created = False
-    if path is None:
-        path = os.path.join(args.tmpdir, "strom_bench_nvme.bin")
-        if not os.path.exists(path) or os.path.getsize(path) < args.size:
-            _mk_testfile(path, args.size)
-        created = True
-    size = min(os.path.getsize(path), args.size) // args.block * args.block
-    # from_env: STROM_* overrides stay honored (STROM_RESIDENCY_HYBRID=0 for
-    # the --warm A/B; STROM_ENGINE_RINGS for multi-ring runs — note the ring
-    # fan-out only engages on multi-file gathers, i.e. striped sources)
-    cfg = StromConfig.from_env(engine=args.engine, block_size=args.block,
-                               queue_depth=args.depth,
-                               num_buffers=max(args.depth * 2, 8),
-                               sqpoll=getattr(args, "sqpoll", False))
-    numa_node = getattr(args, "numa_node", -1)
-    na = None
-    if numa_node >= 0:
-        from strom.utils.numa import NumaAffinity
-
-        na = NumaAffinity(node=numa_node)
-        na.ensure_thread(path)
-    warm = bool(getattr(args, "warm", False))
-    results = []
-    for it in range(args.iters):
-        if warm:
-            # A/B arm for the residency hybrid: pre-warm the page cache so
-            # the engine serves the file as memcpys (counters prove it);
-            # compare against --warm with STROM_RESIDENCY_HYBRID=0
-            with open(path, "rb", buffering=0) as f:
-                while f.read(64 * 1024 * 1024):
-                    pass
-        else:
-            _drop_cache_hint(path)
-        eng = make_engine(cfg)
-        fi = eng.register_file(path, o_direct=not args.buffered)
-        dest = alloc_aligned(size, huge=getattr(args, "huge", False))
-        if na is not None:
-            na.bind(dest)
-        eng.register_dest(dest)  # READ_FIXED where supported; -1 = plain READ
-        t0 = time.perf_counter()
-        if getattr(args, "per_op", False):
-            # legacy shape: one submit+wait ctypes round trip per block
-            n = eng.read_into_direct(fi, 0, size, dest)
-        else:
-            # native vectored gather: batched SQE fills, one io_uring_enter
-            # per batch — the honest "raw bandwidth" this hardware can do
-            n = eng.read_vectored([(fi, 0, 0, size)], dest)
-        dt = time.perf_counter() - t0
-        stats = eng.stats()
-        eng.close()
-        assert n == size
-        results.append(size / dt / 1e9)
-        if not args.json:
-            print(f"  iter {it}: {size / dt / 1e9:.3f} GB/s "
-                  f"({size >> 20} MiB in {dt:.3f}s, o_direct={stats.get('unaligned_fallback_reads', 0) == 0})",
-                  file=sys.stderr)
-    gbps = max(results)
-    out = {
-        "bench": "nvme", "gbps": round(gbps, 4), "block": args.block,
-        "depth": args.depth, "bytes": size, "engine": cfg.engine,
-        "o_direct": not args.buffered, "iters": args.iters,
-        "per_op": bool(getattr(args, "per_op", False)),
-        "numa_node": numa_node,
-        "huge": bool(getattr(args, "huge", False)),
-        # ACTIVE state from the engine, not the request: SQPOLL falls back
-        # silently when the kernel refuses it
-        "sqpoll": bool(stats.get("sqpoll", False)),
-        # which path the last iter's bytes took (residency hybrid A/B proof)
-        "warm": warm,
-        "cached_bytes": int(stats.get("cached_bytes", 0)),
-        "media_bytes": int(stats.get("media_bytes", 0)),
-        "file_created": created,
-    }
-    out.update(_sqpoll_ab(cfg, path, size, args))
-    return out
-
-
-def _sqpoll_ab(cfg, path: str, size: int, args: argparse.Namespace) -> dict:
-    """The SQPOLL submission-syscall A/B (ISSUE 16): one bounded gather
-    each on a plain ring and an SQPOLL ring, reporting submit-side
-    ``io_uring_enter`` calls per GB from the engine's own counters
-    (wait-side excluded on both arms — the A/B isolates SUBMISSION cost,
-    which is what SQPOLL eliminates). Emitted whenever the uring engine is
-    in play; ``sqpoll_active=0`` marks a kernel that refused SQPOLL (the
-    probe fallback), in which case both arms measure the plain path and
-    the sentinel's down-gate sees no false win."""
-    import dataclasses as _dc
-
-    from strom.delivery.buffers import alloc_aligned
-    from strom.engine import make_engine
-
-    if cfg.engine not in ("uring", "auto") or getattr(args, "buffered",
-                                                      False):
-        return {}
-    n = min(size, 256 * 1024 * 1024) // cfg.block_size * cfg.block_size
-    if n <= 0:
-        return {}
-    out: dict = {}
-    try:
-        for key, sqpoll in (("plain_submit_syscalls_per_gb", False),
-                            ("sqpoll_submit_syscalls_per_gb", True)):
-            _drop_cache_hint(path)
-            eng = make_engine(_dc.replace(cfg, sqpoll=sqpoll))
-            try:
-                fi = eng.register_file(path, o_direct=True)
-                dest = alloc_aligned(n)
-                eng.register_dest(dest)
-                got = eng.read_vectored([(fi, 0, 0, n)], dest)
-                s = eng.stats()
-            finally:
-                eng.close()
-            if got != n:
-                return {}
-            calls = int(s.get("enter_submit_calls", 0))
-            if not sqpoll and calls == 0:
-                # auto resolved to the python fallback engine: no syscall
-                # counters to compare, no A/B to report
-                return {}
-            out[key] = round(calls * 1e9 / n, 2)
-            if sqpoll:
-                out["sqpoll_active"] = int(bool(s.get("sqpoll", False)))
-    except Exception as e:  # stromlint: ignore[swallowed-exceptions] -- the A/B is an OPTIONAL measurement riding a bench that already produced its headline number; a box that can't run it (no uring, no O_DIRECT) reports the miss on stderr and emits no fields rather than sinking the arm
-        print(f"  sqpoll A/B skipped: {e}", file=sys.stderr)
-        return {}
-    return out
-
-
-def bench_ssd2host(args: argparse.Namespace) -> dict:
-    """Framework host-delivered ratio (the box-feasible form of the ≥0.90
-    target, BASELINE.json:5): raw engine read vs the delivered path stopped
-    at the device_put boundary (``StromContext.memcpy_ssd2host`` — plan,
-    striped-alias resolution, extent-aware chunking, residency routing,
-    engine gather, zero-copy assembly). Both arms read the SAME bytes into
-    the SAME registered dest (READ_FIXED on both sides); arms alternate
-    which goes first across --iters passes with best-of-N each, because
-    cold-read rates on shared storage drift within a run and a fixed order
-    hands that drift to one arm (measured: 1.81 back-to-back, 1.03 with a
-    fixed order, 0.96-0.99 debiased — BASELINE.md §C).
-
-    --raid N measures the ratio on the reference's flagship deployment
-    shape instead (4xNVMe md-raid0, BASELINE.json:9; VERDICT.md r4 next
-    #2): the file is striped over N members, the framework arm reads
-    through the striped alias (stripe decode + interleaved assembly into
-    logical order), and the raw arm reads every member's bytes
-    contiguously through a bare engine — the same bytes off the same
-    media with none of the stripe math, so the ratio prices exactly the
-    striped path's software."""
-    from strom.config import StromConfig
-    from strom.delivery.buffers import alloc_aligned
-    from strom.delivery.core import StromContext
-    from strom.engine import make_engine
-
-    path = args.file
-    if path is None:
-        path = os.path.join(args.tmpdir, "strom_bench_nvme.bin")
-        if not os.path.exists(path) or os.path.getsize(path) < args.size:
-            _mk_testfile(path, args.size)
-    raid = int(getattr(args, "raid", 0) or 0)
-    raid_chunk = int(getattr(args, "raid_chunk", 512 * 1024) or 512 * 1024)
-    if raid:
-        members, _ = _ensure_striped(path, raid, raid_chunk)
-        stripe_w = raid * raid_chunk
-        size = min(os.path.getsize(path), args.size) // stripe_w * stripe_w
-        per_member = size // raid
-        drop_paths = members
-    else:
-        members = []
-        size = min(os.path.getsize(path), args.size) // args.block * args.block
-        drop_paths = [path]
-    cfg = StromConfig.from_env(engine=args.engine, block_size=args.block,
-                               queue_depth=args.depth,
-                               num_buffers=max(args.depth * 2, 8),
-                               **_obs_config_kw(args))
-    raw_passes: list[float] = []
-    host_passes: list[float] = []
-    dest = alloc_aligned(size)
-    ctx = StromContext(cfg)
-    from strom.utils.stats import global_stats as _gs
-
-    # delta-snapshot the process-global window counter (same reasoning as
-    # bench_parquet: other phases share the singleton in one process); the
-    # *_last gauges need no snapshot — the raw arm's bare engine never
-    # touches them, so they hold exactly the last HOST transfer's values
-    _win0 = _gs.counter("stripe_windows").value
-    try:
-        ctx.engine.register_dest(dest)
-        source: str | object = path
-        if raid:
-            source = path + ".raid0"  # alias only: never on disk
-            ctx.register_striped(source, members, raid_chunk, size=size)
-
-        def run_raw() -> None:
-            eng = make_engine(cfg)
-            try:
-                if raid:
-                    # every member read contiguously into its own dest
-                    # region: the same bytes as the striped logical file,
-                    # zero stripe math — the most favorable bare-engine
-                    # form, so the ratio can only undercount the framework
-                    ops = [(eng.register_file(m, o_direct=True), 0,
-                            i * per_member, per_member)
-                           for i, m in enumerate(members)]
-                else:
-                    ops = [(eng.register_file(path, o_direct=True), 0, 0,
-                            size)]
-                eng.register_dest(dest)
-                t0 = time.perf_counter()
-                n = eng.read_vectored(ops, dest)
-                dt = time.perf_counter() - t0
-            finally:
-                eng.close()
-            assert n == size
-            raw_passes.append(size / dt / 1e9)
-
-        def run_host() -> None:
-            t0 = time.perf_counter()
-            arr = ctx.memcpy_ssd2host(source, length=size, out=dest)
-            dt = time.perf_counter() - t0
-            assert arr.nbytes == size
-            host_passes.append(size / dt / 1e9)
-
-        # even pass count only: an odd count gives one arm more first-
-        # position runs, reintroducing the very order bias the alternation
-        # exists to remove
-        passes = max(args.iters, 1)
-        if passes % 2:
-            passes += 1
-            print(f"ssd2host: rounding --iters up to {passes} "
-                  f"(alternating arm order needs an even pass count)",
-                  file=sys.stderr)
-        for i in range(passes):
-            for run in ((run_raw, run_host) if i % 2 == 0
-                        else (run_host, run_raw)):
-                for p in drop_paths:
-                    _drop_cache_hint(p)
-                run()
-            if not args.json:
-                print(f"  pass {i}: raw {max(raw_passes):.3f} / host "
-                      f"{max(host_passes):.3f} GB/s (best so far)",
-                      file=sys.stderr)
-        # delivery-scheduler observability (coalescing + striped overlap
-        # window), read before close() so engine stats are still live
-        sched = ctx.stats()["context"]
-    finally:
-        ctx.close()
-    raw_gbps = max(raw_passes, default=0.0)
-    host_gbps = max(host_passes, default=0.0)
-    return {
-        "bench": "ssd2host",
-        "raw_gbps": round(raw_gbps, 4),
-        "host_gbps": round(host_gbps, 4),
-        "vs_raw": round(host_gbps / raw_gbps, 4) if raw_gbps else 0.0,
-        # per-pass audit trail (VERDICT.md r4 next #3): best-of selection
-        # must not hide its discards
-        "raw_gbps_passes": [round(g, 4) for g in raw_passes],
-        "host_gbps_passes": [round(g, 4) for g in host_passes],
-        "bytes": size, "block": args.block, "depth": args.depth,
-        "passes": passes, "engine": cfg.engine,
-        "raid_members": raid,
-        # ops before/after coalescing (last host transfer) and the striped
-        # overlap window the host arm submitted under (windows summed over
-        # THIS call's host passes only — delta vs the _win0 snapshot)
-        "coalesce_ops_in": sched["coalesce_ops_in_last"],
-        "coalesce_ops_out": sched["coalesce_ops_out_last"],
-        "stripe_overlap_window_bytes": sched["stripe_overlap_window_bytes"],
-        "stripe_windows": sched["stripe_windows"] - _win0,
-    }
-
-
-def bench_ssd2tpu(args: argparse.Namespace) -> dict:
-    """≙ ssd2gpu_test: keep async ssd2tpu copies in flight; report delivered GB/s."""
-    import jax
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-
-    path = args.file
-    if path is None:
-        path = os.path.join(args.tmpdir, "strom_bench_nvme.bin")
-        if not os.path.exists(path) or os.path.getsize(path) < args.size:
-            _mk_testfile(path, args.size)
-    size = min(os.path.getsize(path), args.size)
-    chunk = args.chunk
-    n_chunks = size // chunk
-    size = n_chunks * chunk
-
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth, num_buffers=max(args.depth * 2, 8),
-                      prefetch_depth=args.prefetch, delivery_workers=args.prefetch,
-                      **_obs_config_kw(args))
-    results = []
-    for it in range(args.iters):
-        _drop_cache_hint(path)
-        ctx = StromContext(cfg)
-        dev = jax.devices()[0]
-        # warm up one transfer (compile/runtime init out of the timed region,
-        # including the one-element fetch executable used below)
-        warm = ctx.memcpy_ssd2tpu(path, offset=0, length=chunk, device=dev)
-        warm.block_until_ready()
-        np.asarray(warm[:1])
-        del warm
-        _drop_cache_hint(path)
-        t0 = time.perf_counter()
-        inflight = []
-        delivered = []
-        for i in range(n_chunks):
-            h = ctx.memcpy_ssd2tpu(path, offset=i * chunk, length=chunk,
-                                   device=dev, async_=True)
-            inflight.append(h)
-            if len(inflight) > args.prefetch:
-                delivered.append(inflight.pop(0).result())
-        for h in inflight:
-            delivered.append(h.result())
-        for a in delivered:
-            a.block_until_ready()
-        # host fetch of the LAST chunk: the clock stops on bytes that
-        # provably arrived
-        if delivered:  # n_chunks can be 0 when the file is < one chunk
-            np.asarray(delivered[-1][:1])
-        dt = time.perf_counter() - t0
-        ctx.close()
-        results.append(size / dt / 1e9)
-        if not args.json:
-            print(f"  iter {it}: {size / dt / 1e9:.3f} GB/s into {dev.platform}",
-                  file=sys.stderr)
-    gbps = max(results)
-    return {
-        "bench": "ssd2tpu", "gbps": round(gbps, 4), "chunk": chunk,
-        "block": args.block, "depth": args.depth, "prefetch": args.prefetch,
-        "bytes": size, "engine": cfg.engine, "iters": args.iters,
-        "device": str(jax.devices()[0]),
-    }
-
-
-def _ensure_striped(plain: str, raid: int, chunk: int) -> tuple[list[str], int]:
-    """(member files, true size) of *plain* striped RAID0-style (fixture
-    helper shared by the vit and parquet benches). Member names are keyed by
-    both raid knobs — reusing members striped with a different chunk would
-    decode interleaved-wrong bytes — and a fingerprint sidecar (source
-    size + mtime_ns, written last) revalidates against a changed source:
-    mtime ordering alone misses a same-size rewrite within mtime granularity
-    and would silently benchmark stale bytes."""
-    from strom.engine.raid0 import stripe_file
-
-    members = [f"{plain}.r{i}of{raid}.c{chunk}" for i in range(raid)]
-    st = os.stat(plain)
-    fingerprint = f"{st.st_size}:{st.st_mtime_ns}"
-    fp_path = members[0] + ".stromfp"
-    try:
-        with open(fp_path) as f:
-            fresh = f.read() == fingerprint \
-                and all(os.path.exists(m) for m in members)
-    except OSError:
-        fresh = False
-    if not fresh:
-        stripe_file(plain, members, chunk)
-        with open(fp_path, "w") as f:
-            f.write(fingerprint)
-    return members, st.st_size
-
-
-def _fetch_one(arr) -> None:
-    """One-element host fetch: fetching a value forces the batch to
-    provably exist before the clock stops — the bandwidth phase's house
-    pattern (kept for ROADMAP A1's first chip run to judge against plain
-    ``block_until_ready``). Call once on the warmup batch too, so the
-    slice/fetch executable compiles outside the timed region."""
-    idx = (slice(0, 1),) + (0,) * (arr.ndim - 1)
-    np.asarray(arr[idx])
-
-
-def _fit_dp_devices(batch: int) -> int:
-    """Largest local device count that divides *batch* (benches shard the
-    batch dim over a dp mesh of this size)."""
-    import jax
-
-    return max(d for d in range(len(jax.devices()), 0, -1) if batch % d == 0)
-
-
-def _timed_train_phase(pipe_factory, step, steps: int,
-                       items_per_step: int
-                       ) -> tuple[float, int, float, dict]:
-    """Shared harness for the --train-step north-star phases (llama, resnet,
-    vit): one warmup step (compile + drain) outside the timed region, a
-    stall-counter baseline, *steps* timed steps, then a HOST FETCH of the
-    loss, which drains the full step chain inside the timed region.
-
-    *step(batch) -> loss* threads model state via closure. Returns
-    (items_per_s, data_stall_steps, final_loss, depth_info) — depth_info
-    carries the prefetch controller's final depth, its (step, depth) trace,
-    and the per-step stall attribution (goodput_pct + ingest-wait/decode/
-    put/read/compute bucket p50/p99 from the event ring, strom/obs/stall)
-    so auto-tuned arms AND where each step's wall time went are auditable
-    in the artifact."""
-    from strom.obs import stall
-    from strom.obs.events import ring
-
-    with pipe_factory() as pipe:
-        loss = step(next(pipe))  # warmup; also the reported loss at steps=0
-        float(loss)
-        base_stalls = pipe.data_stall_steps
-        ring_lo = ring.now_us()  # attribute only THIS phase's steps
-        t0 = time.perf_counter()
-        for i in range(steps):
-            # the attribution window: one consumer step = one next() + the
-            # compute consuming it (strom/obs/stall splits it into buckets)
-            with ring.span("train.step", cat="step", args={"step": i}):
-                loss = step(next(pipe))
-        train_loss = float(loss)
-        dt = time.perf_counter() - t0
-        depth_info = {
-            "prefetch_depth_final": pipe.prefetch_depth,
-            "prefetch_depth_trace": pipe.prefetch_depth_trace,
-        }
-        depth_info.update(stall.flatten_summary(stall.steps_summary(
-            ring.snapshot(), lo_us=ring_lo, hi_us=ring.now_us())))
-        return (round(steps * items_per_step / dt, 1),
-                pipe.data_stall_steps - base_stalls, round(train_loss, 4),
-                depth_info)
-
-
-def _bounded_train_phase(pipe_factory_at_depth, step, rate: float,
-                         items_per_step: int, bsteps: int, bdepth: int
-                         ) -> tuple[float, int, float]:
-    """The NON-degenerate 0-stall arm (VERDICT.md r3 next #2), shared by the
-    llama and predecoded-vision benches: a headline phase run with
-    prefetch > steps cannot distinguish "overlap works" from "everything
-    was staged before consumption started". This arm paces the consumer
-    at EXECUTION rate: a fixed host-side delay of ~the measured per-step
-    wall time after each step's dispatch. Depth <= 4, steps >= 40: 0 stalls
-    here is the SURVEY.md §3.5 double-buffer contract shown non-degenerately.
-    Counter and warmup exclusion untouched (_timed_train_phase).
-
-    *pipe_factory_at_depth(depth)* builds the pipeline at a given prefetch
-    depth; *rate* is the measured headline items/s the pace derives from.
-    Returns (items_per_s, data_stall_steps, delay_s)."""
-    delay = items_per_step / rate if rate else 0.05
-    delay = min(max(delay, 0.01), 1.0)
-
-    def paced(batch):
-        loss = step(batch)
-        time.sleep(delay)
-        return loss
-
-    r, stalls, _, _ = _timed_train_phase(lambda: pipe_factory_at_depth(bdepth),
-                                         paced, bsteps, items_per_step)
-    return r, stalls, round(delay, 4)
-
-
-def _run_bounded_arm(args: argparse.Namespace, out: dict, pipe_factory, step,
-                     rate: float, items_per_step: int, rate_key: str,
-                     drop_paths) -> None:
-    """Run the bounded arm when --bounded-steps asks for it and record the
-    shared key schema — single-sourced so the llama/resnet/vit benches
-    cannot drift apart on the protocol."""
-    bsteps = int(getattr(args, "bounded_steps", 0) or 0)
-    if not bsteps:
-        return
-    bdepth = int(getattr(args, "bounded_prefetch", 4) or 4)
-    for p in drop_paths:
-        _drop_cache_hint(p)
-    brate, bstalls, delay = _bounded_train_phase(
-        pipe_factory, step, rate, items_per_step, bsteps, bdepth)
-    out["bounded_train_data_stalls"] = bstalls
-    out["bounded_steps"] = bsteps
-    out["bounded_prefetch"] = bdepth
-    out["bounded_step_delay_s"] = delay
-    out[rate_key] = brate
-
-
-def bench_llama(args: argparse.Namespace) -> dict:
-    """Config #4 loader shape: packed-token pipeline throughput (tokens/s)
-    + the 0-data-stall counter, feeding a dp mesh on the local device(s).
-
-    Two phases:
-    1. loader flat-out — no compute, every next() is consumed instantly, so
-       the stall counter here measures nothing but raw loader rate;
-    2. (--train-step) a REAL jitted llama train step consumes the batches —
-       this is the north-star measurement (BASELINE.json:5 "0 data-stall
-       steps"): with prefetch >= 2, the loader must fully hide I/O behind
-       the step's device time."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.parallel.mesh import make_mesh
-    from strom.pipelines import make_llama_pipeline
-
-    record = (args.seq_len + 1) * 4
-    path = args.file
-    if path is None:
-        path = os.path.join(args.tmpdir, "strom_bench_tokens.bin")
-        want = args.steps * args.batch * record * 2
-        if not os.path.exists(path) or os.path.getsize(path) < want:
-            _mk_testfile(path, want)
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth, num_buffers=max(args.depth * 2, 8),
-                      **_obs_config_kw(args))
-    ctx = StromContext(cfg)
-    try:
-        n_dev = _fit_dp_devices(args.batch)
-        mesh = make_mesh({"dp": n_dev}, devices=jax.devices()[:n_dev])
-        sharding = NamedSharding(mesh, P("dp", None))
-        _drop_cache_hint(path)
-        with make_llama_pipeline(ctx, [path], batch=args.batch, seq_len=args.seq_len,
-                                 sharding=sharding, prefetch_depth=args.prefetch) as pipe:
-            toks = next(pipe)  # warmup outside the timed region
-            toks.block_until_ready()
-            _fetch_one(toks)  # compile the arrival-forcing fetch here too
-            t0 = time.perf_counter()
-            for _ in range(args.steps):
-                toks = next(pipe)
-                toks.block_until_ready()
-            if args.steps:
-                _fetch_one(toks)  # arrival-forced, not dispatch-rate-bound
-            dt = time.perf_counter() - t0
-            stalls = pipe.data_stall_steps
-        tokens = args.steps * args.batch * (args.seq_len + 1)
-        out = {
-            "bench": "llama_loader", "tokens_per_s": round(tokens / dt, 1),
-            "gbps": round(tokens * 4 / dt / 1e9, 4), "batch": args.batch,
-            "seq_len": args.seq_len, "steps": args.steps, "devices": n_dev,
-            "data_stall_steps": stalls, "engine": cfg.engine,
-        }
-
-        if getattr(args, "train_step", False):
-            from strom.models.llama import LlamaConfig
-            from strom.parallel.train import (init_train_state, make_optimizer,
-                                              make_train_step)
-
-            mcfg = getattr(LlamaConfig, args.model)()
-            opt = make_optimizer()
-            with mesh:
-                state = init_train_state(jax.random.key(0), mcfg, mesh, opt)
-                step_fn = make_train_step(mcfg, mesh, opt, attn=args.attn)
-
-                def step(toks):
-                    nonlocal state
-                    # bench tokens are random bytes; clamp into vocab on device
-                    state, m = step_fn(state, toks % mcfg.vocab)
-                    return m["loss"]
-
-                auto = bool(getattr(args, "auto_prefetch", False))
-                rate, stalls, loss, dinfo = _timed_train_phase(
-                    lambda: make_llama_pipeline(ctx, [path], batch=args.batch,
-                                                seq_len=args.seq_len,
-                                                sharding=sharding,
-                                                prefetch_depth=args.prefetch,
-                                                auto_prefetch=auto),
-                    step, args.steps, args.batch * (args.seq_len + 1))
-                out["train_tokens_per_s"] = rate
-                out["train_data_stalls"] = stalls
-                out["train_model"] = args.model
-                out["train_attn"] = args.attn
-                out["train_loss"] = loss
-                out["prefetch_auto"] = auto
-                out.update(dinfo)
-
-                # the non-degenerate 0-stall arm — see _bounded_train_phase
-                _run_bounded_arm(
-                    args, out,
-                    lambda depth: make_llama_pipeline(
-                        ctx, [path], batch=args.batch, seq_len=args.seq_len,
-                        sharding=sharding, prefetch_depth=depth),
-                    step, rate, args.batch * (args.seq_len + 1),
-                    "bounded_train_tokens_per_s", [path])
-    finally:
-        ctx.close()
-    return out
-
-
-def _mk_wds_fixture(tmpdir: str, batch: int, image_size: int) -> str:
-    """WebDataset .tar fixture of random JPEGs (keyed by both knobs so a
-    bigger --batch regenerates it). Shared by the resnet and vit benches."""
-    import io
-    import tarfile
-
-    n_samples = max(batch * 4, 256)
-    path = os.path.join(tmpdir, f"strom_bench_wds_{image_size}_{n_samples}.tar")
-    if not os.path.exists(path):
-        import cv2
-
-        rng = np.random.default_rng(0)
-        with tarfile.open(path, "w") as tf:
-            for i in range(n_samples):
-                img = rng.integers(0, 256, (image_size * 2, image_size * 2, 3),
-                                   dtype=np.uint8)
-                ok, buf = cv2.imencode(".jpg", img,
-                                       [cv2.IMWRITE_JPEG_QUALITY, 90])
-                assert ok
-                for name, data in ((f"s{i:06d}.jpg", buf.tobytes()),
-                                   (f"s{i:06d}.cls", str(i % 1000).encode())):
-                    info = tarfile.TarInfo(name)
-                    info.size = len(data)
-                    tf.addfile(info, io.BytesIO(data))
-        os.sync()
-    return path
-
-
-def _ensure_predecoded(ctx, tar_path: str, image_size: int, tmpdir: str) -> str:
-    """Decode-once fixture for the --predecoded arm: the WDS tar staged as a
-    packed uint8 shard (strom.formats.predecoded), revalidated by source
-    fingerprint like _ensure_striped."""
-    from strom.formats.predecoded import predecode_wds
-
-    st = os.stat(tar_path)
-    out = os.path.join(
-        tmpdir, f"{os.path.basename(tar_path)}.{image_size}.pdec")
-    fingerprint = f"{st.st_size}:{st.st_mtime_ns}:{image_size}"
-    fp_path = out + ".srcfp"
-    from strom.formats.predecoded import LABELS_SUFFIX
-
-    try:
-        with open(fp_path) as f:
-            if f.read() == fingerprint and os.path.exists(out) \
-                    and os.path.exists(out + LABELS_SUFFIX):
-                return out
-    except OSError:
-        pass
-    predecode_wds(ctx, [tar_path], out, image_size=image_size)
-    with open(fp_path, "w") as f:
-        f.write(fingerprint)
-    return out
-
-
-# decode-path counters reported by the JPEG vision arms (ISSUE 2):
-# reduced-scale decode hits per denominator, bytes decoded straight into
-# batch slots, zero-image substitutions, decode/put overlap window
-_DECODE_COUNTERS = ("decode_reduced_hits_2", "decode_reduced_hits_4",
-                    "decode_reduced_hits_8", "decode_slot_bytes",
-                    "decode_errors", "decode_put_overlap_ms")
-
-
-def _hist_delta(snap0: dict, snap1: dict, stem: str) -> tuple[float, float]:
-    """(p50_us, mean_us) of histogram *stem* over the snapshot delta —
-    shared by the decode and stream bench columns so the bucket math (and
-    the mean*count fallback for snapshots predating exact ``*_total_us``
-    sums) can never diverge between them."""
-    from strom.utils.stats import percentile_from_buckets
-
-    b0 = snap0.get(stem + "_hist") or []
-    b1 = snap1.get(stem + "_hist") or []
-    db = [a - b for a, b in zip(b1, b0)] if b0 else list(b1)
-    n = sum(db)
-
-    def _tot(snap: dict) -> float:
-        t = snap.get(stem + "_total_us")
-        if t is None:
-            t = snap.get(stem + "_mean_us", 0.0) \
-                * snap.get(stem + "_count", 0)
-        return t
-
-    tot = _tot(snap1) - _tot(snap0)
-    return (percentile_from_buckets(db, 0.50),
-            round(tot / n, 1) if n else 0.0)
-
-
-def _decode_stats_delta(snap0: dict) -> dict:
-    """Decode-path counter AND histogram deltas since *snap0* (the process
-    -global registry is shared across bench phases in one process — same
-    delta discipline as bench_parquet's scheduler counters; a cumulative
-    p50 would bill the resnet arm's batches to the vit arm's column)."""
-    from strom.utils.stats import global_stats
-
-    snap1 = global_stats.snapshot()
-    out = {k: int(snap1.get(k, 0) - snap0.get(k, 0))
-           for k in _DECODE_COUNTERS}
-    p50, mean = _hist_delta(snap0, snap1, "decode_batch")
-    out["decode_batch_p50_us"] = p50
-    out["decode_batch_mean_us"] = mean
-    return out
-
-
-def _decode_config_kw(args: argparse.Namespace) -> dict:
-    """StromConfig decode-knob overrides from the A/B flags (absent in
-    driver-built Namespaces → config defaults, i.e. all on)."""
-    return {
-        "decode_reduced_scale": not getattr(args, "full_decode", False),
-        "decode_to_slot": not getattr(args, "no_slot_decode", False),
-        "decode_overlap_put": not getattr(args, "no_overlap_put", False),
-        # intra-batch streaming (ISSUE 5): --no-stream is the A/B flag that
-        # restores the gather-ALL → decode-ALL → put-ALL barrier path
-        # (bit-identical batches, so the stall columns are the only diff);
-        # an explicit --stream wins over --no-stream
-        "stream_intra_batch": bool(getattr(args, "stream", False))
-        or not getattr(args, "no_stream", False),
-        # decode path v2 (ISSUE 12): native turbo binding, fused-run
-        # dispatch, ROI decode (default on — each --no-* flag restores the
-        # pre-v2 path bit-identically); the decoded-output cache is
-        # opt-in (--decode-cache), same capacity reasoning as --hot-cache
-        "decode_native": not getattr(args, "no_native_decode", False),
-        "decode_fuse_runs": not getattr(args, "no_fuse_decode", False),
-        "decode_roi": not getattr(args, "no_roi_decode", False),
-        "decode_cache": bool(getattr(args, "decode_cache", False)),
-    }
-
-
-def _stream_stats_begin() -> None:
-    """Arm-scope the stream peak gauge: a max-gauge cannot be
-    delta'd, so each bench arm zeroes it where it snapshots its counter
-    baseline — otherwise the --no-stream A/B arm (and every later arm)
-    would report the PREVIOUS arm's peak as its own."""
-    from strom.utils.stats import global_stats
-
-    global_stats.set_gauge("stream_inflight_peak", 0)
-
-
-def _stream_stats_delta(snap0: dict) -> dict:
-    """Streaming-path counter/histogram deltas since *snap0* — the bench
-    columns proving the completion-driven dataflow engaged (single-sourced
-    key list: strom.delivery.stream.STREAM_FIELDS; same delta discipline as
-    ``_decode_stats_delta``). ``stream_inflight_peak`` is a max-gauge
-    zeroed at arm start (``_stream_stats_begin``), so the value IS this
-    arm's peak."""
-    from strom.utils.stats import global_stats
-
-    snap1 = global_stats.snapshot()
-    out = {k: int(snap1.get(k, 0) - snap0.get(k, 0))
-           for k in ("stream_batches", "stream_instant_bytes",
-                     "stream_samples_early")}
-    out["stream_inflight_peak"] = int(snap1.get("stream_inflight_peak", 0))
-    for stem in ("stream_first_decode_lat", "stream_tail_extent"):
-        p50, mean = _hist_delta(snap0, snap1, stem)
-        out[stem + "_p50_us"] = p50
-        out[stem + "_mean_us"] = mean
-    return out
-
-
-def _req_slo_delta(ctx, snap0: dict) -> dict:
-    """Per-arm request-latency / SLO columns (ISSUE 8 satellite): p50/p99
-    of the ``req_lat`` histogram over the arm's snapshot delta plus the
-    SLO verdict (1 = no tenant burning at arm end). Keys single-sourced in
-    ``strom.obs.slo.SLO_BENCH_FIELDS`` — the driver's copy loop and the
-    compare_rounds "request latency / SLO" section read the same tuple."""
-    from strom.utils.stats import global_stats, percentile_from_buckets
-
-    snap1 = global_stats.snapshot()
-    b0 = snap0.get("req_lat_hist") or []
-    b1 = snap1.get("req_lat_hist") or []
-    db = [a - b for a, b in zip(b1, b0)] if b0 else list(b1)
-    return {
-        "req_lat_p50_us": percentile_from_buckets(db, 0.50),
-        "req_lat_p99_us": percentile_from_buckets(db, 0.99),
-        "slo_ok": int(ctx.slo.ok()),
-    }
-
-
-def _obs_config_kw(args: argparse.Namespace) -> dict:
-    """StromConfig observability overrides: --metrics-port starts the live
-    /metrics, /stats, /trace, /flight endpoint for the bench context's
-    lifetime; --flight-dir arms the flight recorder so a killed bench
-    (the driver's `timeout`, an OOM-adjacent wedge) leaves an atomic
-    crash bundle instead of an undiagnosable rc (absent in driver-built
-    Namespaces → both off)."""
-    return {"metrics_port": int(getattr(args, "metrics_port", 0) or 0),
-            "flight_dir": getattr(args, "flight_dir", "") or "",
-            "flight_stall_s":
-                float(getattr(args, "flight_stall_s", 30.0) or 0.0),
-            # fault injection (ISSUE 9): --fault-plan wraps the engine in
-            # the FaultyEngine proxy — any bench arm runs under the plan's
-            # deterministic chaos (absent in driver-built Namespaces → off)
-            "fault_plan": getattr(args, "fault_plan", "") or "",
-            # lock-order witness (ISSUE 11): --debug-locks turns every
-            # make_lock site into a WitnessLock for this run — inversions
-            # raise LockOrderError + dump a flight bundle instead of
-            # deadlocking in production later (absent → off; the chaos
-            # arm forces it on regardless)
-            "debug_locks": bool(getattr(args, "debug_locks", False))}
-
-
-def _resil_delta(snap0: dict) -> dict:
-    """Resilience counter deltas since *snap0* (ISSUE 9 satellite): the
-    retry/hedge/breaker/failover columns, single-sourced in
-    ``strom.engine.resilience.RESILIENCE_FIELDS`` so the chaos arm, the
-    driver's copy loop and the compare_rounds "resilience" section read
-    one tuple. ``breaker_state`` is a live gauge (not delta'd)."""
-    from strom.engine.resilience import RESILIENCE_FIELDS
-    from strom.utils.stats import global_stats
-
-    snap1 = global_stats.snapshot()
-    out = {}
-    for k in RESILIENCE_FIELDS:
-        if k == "breaker_state":
-            out[k] = int(snap1.get(k, 0))
-        else:
-            out[k] = int(snap1.get(k, 0) - snap0.get(k, 0))
-    return out
-
-
-def _cache_config_kw(args: argparse.Namespace) -> dict:
-    """StromConfig hot-cache overrides from the --hot-cache flags (absent
-    in hand-built Namespaces → config defaults, i.e. cache off)."""
-    hc = 0 if getattr(args, "no_hot_cache", False) \
-        else int(getattr(args, "hot_cache_bytes", 0) or 0)
-    return {
-        "hot_cache_bytes": hc,
-        "hot_cache_admit": getattr(args, "hot_cache_admit", None)
-        or "second_touch",
-        "readahead_window_batches":
-            int(getattr(args, "readahead_window", 0) or 0) if hc else 0,
-    }
-
-
-def _flat_epoch(pipe_factory, batch: int, drop_paths, *,
-                steady: bool = False, **pkw) -> tuple[float, int]:
-    """ONE flat-out epoch's (img/s, steps) — the shared measurement
-    protocol of the cache and decode-v2 phase pairs (drop page cache,
-    iterate batches_per_epoch, block, arrival-force the last batch): a
-    timing fix here applies to every epoch-pair column at once. *steady*
-    runs one unmeasured epoch first so the timed one excludes pipeline
-    construction + compile warmup (the flat-out phases' warmup-batch
-    exclusion, epoch-shaped); *pkw* are per-pipeline knob overrides."""
-    for p in drop_paths:
-        _drop_cache_hint(p)
-    with pipe_factory(**pkw) as pipe:
-        spe = pipe.sampler.batches_per_epoch
-        imgs = None
-        if steady:
-            for _ in range(spe):
-                imgs, _ = next(pipe)
-                imgs.block_until_ready()
-            if imgs is not None:
-                _fetch_one(imgs)
-        t0 = time.perf_counter()
-        for _ in range(spe):
-            imgs, _ = next(pipe)
-            imgs.block_until_ready()
-        if imgs is not None:
-            _fetch_one(imgs)  # arrival-forced, not dispatch-rate-bound
-        dt = time.perf_counter() - t0
-    return (spe * batch / dt if dt else 0.0), spe
-
-
-def _bench_cache_scope(ctx) -> None:
-    """Scope a bench context's hot cache to the cold/warm epoch pair: the
-    flat-out, train-step and bounded phases predate the cache and their
-    columns (img/s, stall counts, stall attribution) are compared
-    round-over-round — a cache serving those phases from RAM would silently
-    change what every earlier round's numbers meant. The pair itself
-    re-enables (and re-disables) around its two epochs."""
-    if ctx.hot_cache is not None:
-        ctx.hot_cache.enabled = False
-
-
-def _cache_epoch_phases(ctx, pipe_factory, batch: int, drop_paths) -> dict:
-    """Cold-epoch/warm-epoch phase pair (ISSUE 4 satellite): run exactly one
-    epoch flat-out twice over the same records. The cold pass pays the full
-    NVMe gather (and, under force-admit, the admission memcpys); the warm
-    pass serves the repeat traffic from the hot cache — ``warm_vs_cold`` is
-    the delivered speedup, ``cache_hit_bytes``/``cache_miss_bytes`` (warm-
-    phase deltas) prove WHERE the bytes came from (a collapsed miss delta =
-    the ``read`` stall bucket collapsing: the engine saw ~nothing).
-
-    Page cache is dropped before BOTH passes so the warm win is the hot
-    cache's, not the kernel's — and the HOT cache is scoped to exactly this
-    pair: the bench arms construct it DISABLED (``_bench_cache_scope``), it
-    is cleared (entries + touch ledger) and enabled here, and disabled
-    again on exit. Otherwise the preceding flat-out phase's admissions
-    would serve the "cold" epoch from RAM (flattening the very ratio this
-    pair measures) and the train/stall-attribution phases that FOLLOW
-    would measure RAM-served traffic, silently changing what every
-    pre-cache round's columns meant. Counter deltas ride the
-    process-global registry, same delta discipline as
-    ``_decode_stats_delta``. Keys are single-sourced in
-    ``strom.delivery.hotcache.CACHE_BENCH_FIELDS``."""
-    from strom.utils.stats import global_stats as _gs
-
-    if ctx.hot_cache is not None:
-        ctx.hot_cache.clear()
-        ctx.hot_cache.enabled = True
-
-    def one_epoch() -> tuple[float, int]:
-        return _flat_epoch(pipe_factory, batch, drop_paths)
-
-    try:
-        snap0 = _gs.snapshot()
-        cold, spe = one_epoch()
-        snap1 = _gs.snapshot()
-        warm, _ = one_epoch()
-        snap2 = _gs.snapshot()
-    finally:
-        if ctx.hot_cache is not None:
-            # disable AND drop the entries: the following train/bounded
-            # phases can never hit a disabled cache, so leaving 256MiB of
-            # slab-backed entries resident would only shrink the pool
-            # available to the phases being measured
-            ctx.hot_cache.enabled = False
-            ctx.hot_cache.clear()
-
-    def delta(key: str, a: dict, b: dict) -> int:
-        return int(b.get(key, 0) - a.get(key, 0))
-
-    return {
-        "cold_images_per_s": round(cold, 1),
-        "warm_images_per_s": round(warm, 1),
-        "warm_vs_cold": round(warm / cold, 3) if cold else None,
-        "cache_hit_bytes": delta("cache_hit_bytes", snap1, snap2),
-        "cache_miss_bytes": delta("cache_miss_bytes", snap1, snap2),
-        "cache_admitted_bytes": delta("cache_admitted_bytes", snap0, snap1),
-        "cache_readahead_bytes": delta("cache_readahead_bytes", snap0, snap2),
-        "cache_epoch_steps": spe,
-    }
-
-
-def _decode2_phases(ctx, pipe_factory, batch: int, drop_paths) -> dict:
-    """Decode-path v2 phase set (ISSUE 12 tentpole). Two measurements on
-    the SAME fixture and epoch protocol as the cache pair:
-
-    (1) **native-vs-cv2 A/B**: one flat-out epoch with the native turbo
-    binding + fused runs + ROI decode forced ON, one with all three forced
-    OFF (the pre-v2 cv2 path). ``decode_native_vs_cv2`` is a same-run
-    ratio — weather-independent, like ``warm_vs_cold`` — and the counter
-    deltas (native decodes, fused runs, ROI scanlines skipped) prove which
-    mechanism did the work. Both epochs run with the hot cache disabled
-    (the arm's ``_bench_cache_scope`` state), so the A/B prices decode
-    alone.
-
-    (2) **decoded-cache cold/warm pair** (hot cache present only): two
-    epochs with ``decode_cache`` on and the hot cache scoped to the pair
-    (cleared+enabled before, disabled+cleared after — the
-    ``_cache_epoch_phases`` contract). The cold epoch decodes full frames
-    and admits them; the warm epoch serves post-decode pixels from RAM and
-    pays only crop+resize — ``decode_cache_warm_img_per_s`` is the
-    predecoded-on-the-fly headline, read against the predecoded arm's
-    flat-out column. Decoded entries bill the shared cache budget: a
-    working set larger than ``--hot-cache`` evicts and the warm ratio
-    honestly shows it.
-
-    Keys single-sourced in ``strom.formats.jpeg.DECODE2_FIELDS`` (driver
-    copy loop, compare_rounds "decode v2" section and the bench_sentinel
-    gates all read that tuple)."""
-    from strom.formats.jpeg import native_available
-    from strom.utils.stats import global_stats as _gs
-
-    def one_epoch(steady: bool = False, **pkw) -> tuple[float, int]:
-        return _flat_epoch(pipe_factory, batch, drop_paths, steady=steady,
-                           **pkw)
-
-    def delta(key: str, a: dict, b: dict) -> int:
-        return int(b.get(key, 0) - a.get(key, 0))
-
-    out: dict = {}
-    if native_available():
-        # the A/B only prices something when the binding exists — on a
-        # host without libjpeg-turbo headers the "native" epoch would be
-        # a second cv2 epoch and decode_native_img_per_s would hand
-        # bench_sentinel a gated number that never exercised the native
-        # path; omitted keys render "-" and gate nothing
-        snap0 = _gs.snapshot()
-        native_rate, _ = one_epoch(decode_native=True,
-                                   decode_fuse_runs=True,
-                                   decode_roi=True, decode_cache=False)
-        snap1 = _gs.snapshot()
-        cv2_rate, _ = one_epoch(decode_native=False, decode_fuse_runs=False,
-                                decode_roi=False, decode_cache=False)
-        out["decode_native_img_per_s"] = round(native_rate, 1)
-        out["decode_cv2_img_per_s"] = round(cv2_rate, 1)
-        out["decode_native_vs_cv2"] = round(native_rate / cv2_rate, 3) \
-            if cv2_rate else None
-        for k in ("decode_native_imgs", "decode_native_fallbacks",
-                  "decode_fused_runs", "decode_fused_samples",
-                  "decode_roi_hits", "decode_roi_rows_skipped"):
-            out[k] = delta(k, snap0, snap1)
-
-    if ctx.hot_cache is not None:
-        ctx.hot_cache.clear()
-        ctx.hot_cache.enabled = True
-        try:
-            s0 = _gs.snapshot()
-            cold, _ = one_epoch(decode_cache=True)
-            s1 = _gs.snapshot()
-            # steady=True: the warm number is the acceptance ratio's
-            # numerator (read against the predecoded arm's flat-out
-            # column), so it must exclude construction/compile warmup
-            # like that column does — the COLD epoch can't have a warmup
-            # pass (it would stop being cold) and keeps the construction-
-            # included _cache_epoch_phases protocol. The hit counters
-            # below span both warm epochs (the unmeasured pass serves
-            # from cache too).
-            warm, _ = one_epoch(steady=True, decode_cache=True)
-            s2 = _gs.snapshot()
-        finally:
-            # same scoping rule as _cache_epoch_phases: later phases must
-            # not measure RAM-served traffic, and 100s of MiB of decoded
-            # frames must not shrink the slab pool under them
-            ctx.hot_cache.enabled = False
-            ctx.hot_cache.clear()
-        out["decode_cache_cold_img_per_s"] = round(cold, 1)
-        out["decode_cache_warm_img_per_s"] = round(warm, 1)
-        out["decode_cache_warm_vs_cold"] = round(warm / cold, 3) \
-            if cold else None
-        out["decode_cache_hits"] = delta("decode_cache_hits", s1, s2)
-        out["decode_cache_hit_bytes"] = delta("decode_cache_hit_bytes",
-                                              s1, s2)
-        # s0 -> s2: under second_touch the first epoch only OBSERVES and
-        # the admissions land during the warm pass — a cold-window-only
-        # delta would report 0 next to nonzero hits
-        out["decode_cache_admitted_bytes"] = \
-            delta("decode_cache_admitted_bytes", s0, s2)
-    return out
-
-
-def bench_resnet(args: argparse.Namespace) -> dict:
-    """Config #2 shape: JPEG WebDataset -> decode -> device, images/s
-    (IO-bound: a throttled fake 'train step' just blocks on delivery).
-    --predecoded swaps in the decode-free staged-shard loader: decode
-    happens ONCE offline and the training loader is a pure engine gather,
-    so the 0-stall overlap machinery is demonstrable even on hosts where
-    decode and the consumer share one core (BASELINE.md §C)."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.parallel.mesh import make_mesh
-    from strom.pipelines import (make_imagenet_resnet_pipeline,
-                                 make_predecoded_vision_pipeline)
-
-    path = args.file
-    if path is None:
-        path = _mk_wds_fixture(args.tmpdir, args.batch, args.image_size)
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth, num_buffers=max(args.depth * 2, 8),
-                      **_decode_config_kw(args), **_obs_config_kw(args),
-                      **_cache_config_kw(args))
-    ctx = StromContext(cfg)
-    _bench_cache_scope(ctx)
-    from strom.utils.stats import global_stats as _gs
-
-    _stream_stats_begin()  # arm-scope the stream peak gauge
-    _dec0 = _gs.snapshot()
-    try:
-        n_dev = _fit_dp_devices(args.batch)
-        mesh = make_mesh({"dp": n_dev}, devices=jax.devices()[:n_dev])
-        sharding = NamedSharding(mesh, P("dp", None, None, None))
-        predecoded = bool(getattr(args, "predecoded", False))
-        # auto depth applies to the headline train phase only: the bounded
-        # arm is BY PROTOCOL a fixed shallow depth, and the flat-out phase
-        # has no compute to overlap with (its stalls measure loader rate)
-        auto_pf = bool(getattr(args, "auto_prefetch", False))
-        if predecoded:
-            pdec = _ensure_predecoded(ctx, path, args.image_size, args.tmpdir)
-            data_paths = [pdec]
-
-            def pipe_factory(depth=args.prefetch, auto=False):
-                return make_predecoded_vision_pipeline(
-                    ctx, [pdec], batch=args.batch,
-                    image_size=args.image_size, sharding=sharding,
-                    prefetch_depth=depth, auto_prefetch=auto)
-        else:
-            data_paths = [path]
-
-            def pipe_factory(depth=args.prefetch, auto=False, **pkw):
-                return make_imagenet_resnet_pipeline(
-                    ctx, [path], batch=args.batch,
-                    image_size=args.image_size, sharding=sharding,
-                    prefetch_depth=depth, auto_prefetch=auto,
-                    decode_workers=args.decode_workers, **pkw)
-        for p in data_paths:
-            _drop_cache_hint(p)
-        with pipe_factory() as pipe:
-            imgs = next(pipe)[0]  # warmup outside the timed region
-            imgs.block_until_ready()
-            _fetch_one(imgs)  # compile the arrival-forcing fetch here too
-            t0 = time.perf_counter()
-            for _ in range(args.steps):
-                imgs, _ = next(pipe)
-                imgs.block_until_ready()
-            if args.steps:
-                _fetch_one(imgs)  # arrival-forced, not dispatch-rate-bound
-            dt = time.perf_counter() - t0
-            stalls = pipe.data_stall_steps
-        out = {
-            "bench": "resnet_loader",
-            "images_per_s": round(args.steps * args.batch / dt, 1),
-            "batch": args.batch, "image_size": args.image_size,
-            "steps": args.steps, "devices": n_dev, "data_stall_steps": stalls,
-            # the decode-free arm runs no decode pool: reporting the flag's
-            # value there would imply workers that never existed
-            "decode_workers": 0 if predecoded else args.decode_workers,
-            "engine": cfg.engine,
-            "predecoded": predecoded,
-        }
-        if not predecoded:
-            out.update({"decode_reduced_scale": cfg.decode_reduced_scale,
-                        "decode_to_slot": cfg.decode_to_slot,
-                        "decode_overlap_put": cfg.decode_overlap_put,
-                        "stream_intra_batch": cfg.stream_intra_batch,
-                        "decode_native": cfg.decode_native,
-                        "decode_fuse_runs": cfg.decode_fuse_runs,
-                        "decode_roi": cfg.decode_roi})
-        if cfg.hot_cache_bytes:
-            # ISSUE 4 satellite: cold/warm epoch pair — repeat traffic must
-            # serve from the hot cache, not NVMe (see _cache_epoch_phases)
-            out["hot_cache_bytes"] = cfg.hot_cache_bytes
-            out["hot_cache_admit"] = cfg.hot_cache_admit
-            out.update(_cache_epoch_phases(ctx, pipe_factory, args.batch,
-                                           data_paths))
-        if not predecoded and not getattr(args, "no_decode2", False):
-            # ISSUE 12: native-vs-cv2 decode A/B + decoded-cache cold/warm
-            # pair on the same fixture (see _decode2_phases)
-            out.update(_decode2_phases(ctx, pipe_factory, args.batch,
-                                       data_paths))
-
-        if getattr(args, "train_step", False):
-            # north-star phase (BASELINE.json:5 "ResNet-50 input pipeline fully
-            # IO-overlapped, 0 data-stall steps"): a REAL jitted ResNet train
-            # step (fwd+bwd+SGD) consumes the batches; decode+delivery must hide
-            # behind its device time. Flat-out above stalls by construction —
-            # there is no compute to overlap with.
-            import functools
-
-            from strom.models.resnet import (ResNetConfig, init_params, loss_fn,
-                                             normalize_images)
-
-            mcfg = getattr(ResNetConfig, args.model)()
-            # replicated over the dp mesh, as the step gives them back: its
-            # first call compiles the only signature it will see
-            replicated = NamedSharding(mesh, P())
-            params, bn_state = jax.device_put(
-                init_params(jax.random.key(0), mcfg), replicated)
-
-            @functools.partial(jax.jit, donate_argnums=(0,),
-                               out_shardings=replicated)
-            def sgd_step(p, s, images, labels):
-                (loss, new_s), grads = jax.value_and_grad(
-                    loss_fn, has_aux=True)(p, s, normalize_images(images),
-                                           labels, mcfg)
-                new_p = jax.tree.map(lambda w, g: w - 1e-3 * g, p, grads)
-                return new_p, new_s, loss
-
-            def step(batch):
-                nonlocal params, bn_state
-                imgs, lbls = batch
-                params, bn_state, loss = sgd_step(params, bn_state, imgs,
-                                                  lbls % mcfg.num_classes)
-                return loss
-
-            for p in data_paths:
-                _drop_cache_hint(p)
-            rate, stalls, loss, dinfo = _timed_train_phase(
-                lambda: pipe_factory(args.prefetch, auto_pf), step,
-                args.steps, args.batch)
-            out["train_images_per_s"] = rate
-            out["train_data_stalls"] = stalls
-            out["train_model"] = args.model
-            out["train_loss"] = loss
-            out["prefetch_auto"] = auto_pf
-            out.update(dinfo)
-
-            # the non-degenerate 0-stall arm — see _bounded_train_phase
-            # (fixed depth by protocol: pipe_factory's auto default is False)
-            _run_bounded_arm(args, out, pipe_factory, step, rate, args.batch,
-                             "bounded_train_images_per_s", data_paths)
-        if not predecoded:
-            out.update(_decode_stats_delta(_dec0))
-            out.update(_stream_stats_delta(_dec0))
-        out.update(_req_slo_delta(ctx, _dec0))
-    finally:
-        ctx.close()
-    return out
-
-
-def bench_vit(args: argparse.Namespace) -> dict:
-    """Config #3 shape: WebDataset .tar shards -> ViT training loader on a
-    RAID0 striped set. The tar is striped over --raid member files
-    (``stripe_file``) and registered as a path alias, so every member gather
-    stripe-decodes across the set — the userspace twin of the tar living on
-    a 4xNVMe md-raid0 mount (BASELINE.json:9). --predecoded stages the tar
-    decode-once (strom.formats.predecoded) and stripes the PACKED shard
-    instead: the loader is a pure stripe-decoded engine gather, no per-step
-    JPEG decode."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.parallel.mesh import make_mesh
-    from strom.pipelines import (make_predecoded_vision_pipeline,
-                                 make_vit_wds_pipeline)
-
-    plain = args.file or _mk_wds_fixture(args.tmpdir, args.batch,
-                                         args.image_size)
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth, num_buffers=max(args.depth * 2, 8),
-                      **_decode_config_kw(args), **_obs_config_kw(args),
-                      **_cache_config_kw(args))
-    ctx = StromContext(cfg)
-    _bench_cache_scope(ctx)
-    from strom.utils.stats import global_stats as _gs
-
-    _stream_stats_begin()  # arm-scope the stream peak gauge
-    _dec0 = _gs.snapshot()
-    try:
-        predecoded = bool(getattr(args, "predecoded", False))
-        if predecoded:
-            from strom.formats.predecoded import stage_striped_predecoded
-
-            pdec = _ensure_predecoded(ctx, plain, args.image_size,
-                                      args.tmpdir)
-            members, _ = _ensure_striped(pdec, args.raid, args.raid_chunk)
-            virt = stage_striped_predecoded(ctx, pdec, members,
-                                            args.raid_chunk, stripe=False)
-        else:
-            members, _ = _ensure_striped(plain, args.raid, args.raid_chunk)
-            virt = plain + ".raid0"  # never on disk: reads resolve via alias
-            ctx.register_striped(virt, members, args.raid_chunk)
-        n_dev = _fit_dp_devices(args.batch)
-        mesh = make_mesh({"dp": n_dev}, devices=jax.devices()[:n_dev])
-        sharding = NamedSharding(mesh, P("dp", None, None, None))
-
-        auto_pf = bool(getattr(args, "auto_prefetch", False))
-        if predecoded:
-            def pipe_factory(depth=args.prefetch, auto=False):
-                return make_predecoded_vision_pipeline(
-                    ctx, [virt], batch=args.batch,
-                    image_size=args.image_size, sharding=sharding,
-                    prefetch_depth=depth, auto_prefetch=auto)
-        else:
-            def pipe_factory(depth=args.prefetch, auto=False, **pkw):
-                return make_vit_wds_pipeline(
-                    ctx, [virt], batch=args.batch,
-                    image_size=args.image_size, sharding=sharding,
-                    prefetch_depth=depth, auto_prefetch=auto,
-                    decode_workers=args.decode_workers, **pkw)
-        for m in members:
-            _drop_cache_hint(m)
-        with pipe_factory() as pipe:
-            imgs = next(pipe)[0]  # warmup outside the timed region
-            imgs.block_until_ready()
-            _fetch_one(imgs)  # compile the arrival-forcing fetch here too
-            t0 = time.perf_counter()
-            for _ in range(args.steps):
-                imgs, _ = next(pipe)
-                imgs.block_until_ready()
-            if args.steps:
-                _fetch_one(imgs)  # arrival-forced, not dispatch-rate-bound
-            dt = time.perf_counter() - t0
-            stalls = pipe.data_stall_steps
-        out = {
-            "bench": "vit_loader", "images_per_s": round(args.steps * args.batch / dt, 1),
-            "batch": args.batch, "image_size": args.image_size,
-            "steps": args.steps, "devices": n_dev, "raid_members": args.raid,
-            "data_stall_steps": stalls, "engine": cfg.engine,
-            "predecoded": predecoded,
-        }
-        if not predecoded:
-            out.update({"decode_reduced_scale": cfg.decode_reduced_scale,
-                        "decode_to_slot": cfg.decode_to_slot,
-                        "decode_overlap_put": cfg.decode_overlap_put,
-                        "stream_intra_batch": cfg.stream_intra_batch,
-                        "decode_native": cfg.decode_native,
-                        "decode_fuse_runs": cfg.decode_fuse_runs,
-                        "decode_roi": cfg.decode_roi})
-        if cfg.hot_cache_bytes:
-            # ISSUE 4 satellite: cold/warm epoch pair over the striped set —
-            # the warm epoch's stripe gathers collapse into RAM memcpys
-            out["hot_cache_bytes"] = cfg.hot_cache_bytes
-            out["hot_cache_admit"] = cfg.hot_cache_admit
-            out.update(_cache_epoch_phases(ctx, pipe_factory, args.batch,
-                                           members))
-        if not predecoded and not getattr(args, "no_decode2", False):
-            # ISSUE 12: native-vs-cv2 A/B + decoded-cache pair, striped
-            out.update(_decode2_phases(ctx, pipe_factory, args.batch,
-                                       members))
-
-        if getattr(args, "train_step", False):
-            # north-star phase: a REAL jitted ViT train step consumes the batches
-            # (decode+stripe-gather must hide behind its device time)
-            import functools
-
-            from strom.models.resnet import normalize_images
-            from strom.models.vit import ViTConfig, init_params, loss_fn
-
-            mcfg = getattr(ViTConfig, args.model)()
-            if mcfg.image_size != args.image_size:
-                mcfg = dataclasses.replace(mcfg, image_size=args.image_size)
-            # replicated over the dp mesh, as the step gives them back
-            replicated = NamedSharding(mesh, P())
-            params = jax.device_put(init_params(jax.random.key(0), mcfg),
-                                    replicated)
-
-            @functools.partial(jax.jit, donate_argnums=(0,),
-                               out_shardings=replicated)
-            def sgd_step(p, images, labels):
-                loss, grads = jax.value_and_grad(loss_fn)(
-                    p, normalize_images(images), labels, mcfg)
-                new_p = jax.tree.map(lambda w, g: w - 1e-3 * g, p, grads)
-                return new_p, loss
-
-            def step(batch):
-                nonlocal params
-                imgs, lbls = batch
-                params, loss = sgd_step(params, imgs, lbls % mcfg.num_classes)
-                return loss
-
-            for m in members:
-                _drop_cache_hint(m)
-            rate, stalls, loss, dinfo = _timed_train_phase(
-                lambda: pipe_factory(args.prefetch, auto_pf), step,
-                args.steps, args.batch)
-            out["train_images_per_s"] = rate
-            out["train_data_stalls"] = stalls
-            out["train_model"] = args.model
-            out["train_loss"] = loss
-            out["prefetch_auto"] = auto_pf
-            out.update(dinfo)
-
-            # the non-degenerate 0-stall arm — see _bounded_train_phase
-            # (fixed depth by protocol: pipe_factory's auto default is False)
-            _run_bounded_arm(args, out, pipe_factory, step, rate, args.batch,
-                             "bounded_train_images_per_s", members)
-        if not predecoded:
-            out.update(_decode_stats_delta(_dec0))
-            out.update(_stream_stats_delta(_dec0))
-        out.update(_req_slo_delta(ctx, _dec0))
-    finally:
-        ctx.close()
-    return out
-
-
-def _pushdown_ab(ctx, args: argparse.Namespace) -> dict:
-    """ISSUE 19 tentpole proof: the SAME logical scan twice — once with the
-    predicate pushed to extent-plan time (stats-refuted row groups never
-    enter an ExtentList), once as a post-hoc row filter over the full read.
-    Both arms must produce the identical aggregate; the pushed arm must
-    submit strictly fewer bytes. Selectivity is an INPUT, not an accident
-    of the data: the fixture's ``seq`` column is monotone, so per-group
-    min/max stats are disjoint and ``seq < cutoff`` refutes exactly the
-    groups past the cutoff."""
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from strom.ops.pushdown import PUSHDOWN_FIELDS, col
-    from strom.pipelines.parquet_scan import parquet_scan_aggregate
-    from strom.utils.stats import global_stats
-
-    rows = min(int(args.rows), 1 << 20)
-    groups = max(int(args.row_groups), 8)
-    sel = float(getattr(args, "pushdown_selectivity", 0.25) or 0.25)
-    sel = min(max(sel, 0.05), 1.0)
-    path = os.path.join(args.tmpdir,
-                        f"strom_bench_pushdown_{rows}_{groups}.parquet")
-    if not os.path.exists(path):
-        rng = np.random.default_rng(1)
-        pq.write_table(pa.table({
-            "seq": np.arange(rows, dtype=np.int64),
-            "value": rng.standard_normal(rows),
-            # dead weight neither arm selects: projection pruning must
-            # leave it on disk in both, so the A/B isolates the predicate
-            "payload": rng.integers(0, 256, rows, dtype=np.int64),
-        }), path, row_group_size=max(rows // groups, 1))
-        os.sync()
-    cutoff = int(rows * sel)
-    pred = col("seq") < cutoff
-    devs = None
-    if getattr(args, "cpu_device", False):
-        import jax
-
-        devs = jax.devices("cpu")
-
-    def map_pushed(d: dict):
-        import jax.numpy as jnp
-
-        return {"hits": jnp.sum((d["value"] > 0).astype(jnp.int32))}
-
-    def map_post(d: dict):
-        import jax.numpy as jnp
-
-        keep = d["seq"] < cutoff
-        return {"hits": jnp.sum(((d["value"] > 0) & keep).astype(jnp.int32))}
-
-    def pushed() -> int:
-        r = parquet_scan_aggregate(ctx, [path], ["value"], map_pushed,
-                                   predicate=pred, prefetch_depth=args.prefetch,
-                                   unit_batch=1, devices=devs)
-        return int(r["hits"])
-
-    def post() -> int:
-        r = parquet_scan_aggregate(ctx, [path], ["value", "seq"], map_post,
-                                   prefetch_depth=args.prefetch,
-                                   unit_batch=1, devices=devs)
-        return int(r["hits"])
-
-    # warmup: XLA compiles both bodies (full groups + the masked cutoff
-    # group's shape) outside the timed region — house pattern
-    pushed()
-    post()
-    snap0 = global_stats.snapshot()
-    _drop_cache_hint(path)
-    t0 = time.perf_counter()
-    h_push = pushed()
-    dt_push = time.perf_counter() - t0
-    snap1 = global_stats.snapshot()
-    _drop_cache_hint(path)
-    t0 = time.perf_counter()
-    h_post = post()
-    dt_post = time.perf_counter() - t0
-    d = {k: int(snap1.get(k, 0)) - int(snap0.get(k, 0))
-         for k in PUSHDOWN_FIELDS}
-    # skipped + submitted = what the unpushed plan would have submitted for
-    # the same read set — the strictly-fewer-bytes check needs no second
-    # metadata walk
-    unpushed_bytes = d["parquet_pushdown_skipped_bytes"] \
-        + d["parquet_pushdown_submitted_bytes"]
-    ok = int(h_push == h_post and d["parquet_pushdown_skipped_bytes"] > 0
-             and d["parquet_pushdown_submitted_bytes"] < unpushed_bytes)
-    return {
-        "pushdown_ok": ok,
-        "pushdown_hits": h_push, "unpushed_hits": h_post,
-        "pushdown_rows": rows, "pushdown_selectivity": sel,
-        "parquet_pushdown_rows_per_s": round(rows / dt_push, 1),
-        "parquet_unpushed_rows_per_s": round(rows / dt_post, 1),
-        # same-run ratio: the plan-time refutation's rows/s over the
-        # post-hoc filter's on identical logical work
-        "parquet_pushdown_vs_unpushed": round(dt_post / dt_push, 4),
-        "parquet_pushdown_skipped_bytes":
-            d["parquet_pushdown_skipped_bytes"],
-        "parquet_pushdown_submitted_bytes":
-            d["parquet_pushdown_submitted_bytes"],
-        "parquet_pushdown_groups_skipped":
-            d["parquet_pushdown_groups_skipped"],
-        "parquet_pushdown_groups_total": d["parquet_pushdown_groups_total"],
-    }
-
-
-def bench_parquet(args: argparse.Namespace) -> dict:
-    """Config #5 shape (PG-Strom-style SSD2TPU columnar scan): only the
-    selected columns' compressed chunks are engine-read, filter/aggregate
-    runs jitted on device, row groups are LPT-assigned by byte size across
-    processes. Reports scanned rows/s and selected-column GB/s."""
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.pipelines.parquet_scan import parquet_count_where
-
-    n_cols = max(int(getattr(args, "columns", 1) or 1), 1)
-    compression = str(getattr(args, "compression", "snappy") or "snappy")
-    val_dtype = np.dtype(getattr(args, "dtype", "float64") or "float64")
-    path = args.file
-    if path is None:
-        rows = args.rows
-        # keyed by EVERY generation knob so a changed flag regenerates it
-        key = f"{rows}_{args.row_groups}" + (f"_c{n_cols}" if n_cols > 1 else "") \
-            + (f"_{compression}" if compression != "snappy" else "") \
-            + (f"_{val_dtype.name}" if val_dtype != np.float64 else "")
-        path = os.path.join(args.tmpdir, f"strom_bench_scan_{key}.parquet")
-        if not os.path.exists(path):
-            rng = np.random.default_rng(0)
-            # several columns so column pruning is actually exercised: the
-            # narrow scan touches `value` only, the rest is dead weight on
-            # disk. --columns N adds f0..f{N-2} feature columns for the
-            # WIDE-projection arm (the PG-Strom shape that projects a
-            # feature vector per row), where selected bytes/row is large
-            # enough for selected_gbps to mean scan bandwidth. --dtype
-            # float32 matches both the real feature-vector shape and jax's
-            # x64-disabled default, so device dispatch is an alias, not a
-            # downcast copy.
-            cols = {
-                "value": rng.standard_normal(rows).astype(val_dtype),
-                "key": rng.integers(0, 1 << 30, rows, dtype=np.int64),
-                "payload": rng.integers(0, 256, rows, dtype=np.int64),
-            }
-            for i in range(n_cols - 1):
-                cols[f"f{i}"] = rng.standard_normal(rows).astype(val_dtype)
-            # --compression none writes PLAIN-encoded uncompressed chunks
-            # (dictionary off: a dict page would defeat the direct decoder):
-            # decode degenerates to buffer reinterpretation, so the scan's
-            # selected-GB/s measures the I/O path rather than a single-core
-            # snappy codec (VERDICT.md r4 next #1 — config #5's essence is
-            # scanning at disk bandwidth, SURVEY.md §0.5)
-            # plain fixture: dictionary off (a dict page would force the
-            # pyarrow fallback). parquet-cpp caps data pages at 20k rows
-            # regardless of data_page_size, so chunks decode as a handful
-            # of frombuffer page views plus ONE join copy per chunk —
-            # "direct decode", not literally zero-copy (the page-level
-            # zero-copy variant measured 25x slower: dispatch cost on ~80KB
-            # operands dwarfs the saved memcpy).
-            extra = {"use_dictionary": False} if compression == "none" else {}
-            pq.write_table(pa.table(cols), path,
-                           row_group_size=max(rows // args.row_groups, 1),
-                           compression="NONE" if compression == "none"
-                           else compression, **extra)
-            os.sync()
-    raid = args.raid
-    members: list[str] = []
-    if raid:
-        # the reference's flagship deployment scans from md-raid0-of-NVMe
-        # (BASELINE.json:11 is the PG-Strom-style config): stripe the file
-        # and scan through the path alias so every column-chunk gather
-        # stripe-decodes across the set (the size sidecar keeps the footer
-        # at the true EOF). Striped BEFORE the context exists so a failed
-        # stripe can't leak the engine.
-        members, logical_bytes = _ensure_striped(path, raid, args.raid_chunk)
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth, num_buffers=max(args.depth * 2, 8),
-                      **_obs_config_kw(args))
-    ctx = StromContext(cfg)
-    from strom.utils.stats import global_stats as _gs
-
-    # snapshot the process-global scheduler counters NOW: other bench
-    # phases (ssd2host, vision arms) share the singleton in one process,
-    # and reporting their ops as this scan's would corrupt the artifact
-    _sched0 = {k: _gs.counter(k).value
-               for k in ("coalesce_ops_in", "coalesce_ops_out",
-                         "stripe_windows")}
-    try:
-        from strom.formats.parquet import ParquetShard
-
-        if raid:
-            virt = path + ".raid0"
-            ctx.register_striped(virt, members, args.raid_chunk)
-            path = virt
-            for m in members:
-                _drop_cache_hint(m)
-        else:
-            _drop_cache_hint(path)
-        # ParquetShard owns the plain-vs-striped metadata dispatch — the
-        # bench reads through the same path the library scan does (the
-        # instance is reused for the --disk-rate extent walk)
-        shard = ParquetShard(path, ctx=ctx)
-        meta = shard.metadata
-        n_rows = meta.num_rows
-        sel_cols = ["value"] + [f"f{i}" for i in range(n_cols - 1)]
-        # probe the SCHEMA, not row_group(0): a valid file with zero row
-        # groups must still reach the scan's clear "no row groups" error
-        present = set(meta.schema.names)
-        missing = [c for c in sel_cols if c not in present]
-        if missing:
-            # fail up front with the real cause: --columns names the
-            # generated fixture's schema (value, f0..fN-2) — a user --file
-            # without those columns would otherwise die mid-scan on an
-            # opaque pyarrow missing-column error after sel_bytes silently
-            # undercounted
-            raise SystemExit(
-                f"strom-bench parquet: --columns {n_cols} selects {sel_cols} "
-                f"but {path} lacks {missing}; --columns > 1 expects the "
-                f"generated fixture schema (omit --file or regenerate)")
-        sel_bytes = sum(
-            meta.row_group(g).column(i).total_compressed_size
-            for g in range(meta.num_row_groups)
-            for i in range(meta.num_columns)
-            if meta.row_group(g).column(i).path_in_schema in sel_cols)
-
-        # --cpu-device: run the jitted aggregate on the host backend (the
-        # default is the default device, where config #5 runs it)
-        devs = None
-        if getattr(args, "cpu_device", False):
-            import jax
-
-            devs = jax.devices("cpu")
-        if n_cols == 1:
-            def scan() -> int:
-                return parquet_count_where(ctx, [path], "value",
-                                           lambda v: v > 0,
-                                           prefetch_depth=args.prefetch,
-                                           unit_batch=args.unit_batch,
-                                           devices=devs)
-        else:
-            # wide projection: every selected column moves disk -> device;
-            # the aggregate consumes them all so nothing is dead weight
-            from strom.pipelines.parquet_scan import parquet_scan_aggregate
-
-            def map_fn(d: dict):
-                import jax.numpy as jnp
-
-                return {"hits": jnp.sum((d["value"] > 0).astype(jnp.int32)),
-                        "fsum": sum(jnp.sum(d[c]) for c in sel_cols[1:])}
-
-            def scan() -> int:
-                res = parquet_scan_aggregate(ctx, [path], sel_cols, map_fn,
-                                             prefetch_depth=args.prefetch,
-                                             unit_batch=args.unit_batch,
-                                             devices=devs)
-                return int(res["hits"])
-        # warmup pass: XLA compiles (body + tail shapes) outside the timed
-        # region — house pattern of every bench here; matters doubly for the
-        # --unit-batch A/B, which would otherwise partly measure compile count
-        scan()
-        from strom.utils.stats import global_stats
-
-        disk_rate = bool(getattr(args, "disk_rate", False))
-        drop_paths = members if raid else [path]
-        scan_dts: list[float] = []
-        raw_gbps_list: list[float] = []
-        hits = 0
-        plain_bytes = pyarrow_bytes = 0
-
-        def scan_arm() -> None:
-            nonlocal hits, plain_bytes, pyarrow_bytes
-            snap0 = global_stats.snapshot()
-            t0 = time.perf_counter()
-            hits = scan()
-            scan_dts.append(time.perf_counter() - t0)
-            snap1 = global_stats.snapshot()
-            # which decode path the timed bytes took (the artifact must
-            # prove the plain arm rode the direct frombuffer decoder)
-            plain_bytes += snap1.get("parquet_plain_bytes", 0) \
-                - snap0.get("parquet_plain_bytes", 0)
-            pyarrow_bytes += snap1.get("parquet_decode_bytes", 0) \
-                - snap0.get("parquet_decode_bytes", 0)
-
-        if disk_rate:
-            # --disk-rate: a BARE-engine vectored gather of EXACTLY the
-            # selected chunks' extents — the same bytes, the same access
-            # pattern, none of the framework (no planner, no decode, no
-            # device dispatch). Column chunks start at unaligned offsets
-            # (data_page_offset 4 for the first), so these ops ride the
-            # engine's buffered-fd fallback — the SAME per-op routing the
-            # scan's own gathers get, which is the point: like-for-like
-            # I/O, cache dropped before every pass. Arms alternate across
-            # 2 passes with best-of-N per arm — the ssd2host debiasing
-            # methodology (cold-read rates on this virtio disk drift
-            # within a run; a fixed order hands the drift to one arm).
-            # The ratio selected_gbps / disk_read_gbps is then the scan
-            # machinery's true cost over raw I/O (VERDICT.md r4 next #1).
-            # With --raid the logical extents are expanded to member ops
-            # HERE (plan_stripe_reads — the stripe math is the bench's,
-            # the bare engine just reads member ranges), so the striped
-            # scan gets the same yardstick.
-            from strom.delivery.buffers import alloc_aligned
-            from strom.engine import make_engine
-
-            raw_extents = [e for g in range(meta.num_row_groups)
-                           for e in shard.column_chunk_extents(
-                               g, sel_cols).extents]
-            raw_total = sum(e.length for e in raw_extents)
-            raw_dest = alloc_aligned(raw_total)
-
-            def raw_arm() -> None:
-                from strom.engine.raid0 import plan_stripe_reads
-
-                eng = make_engine(cfg)
-                try:
-                    ops = []
-                    off = 0
-                    if raid:
-                        member_fi = [eng.register_file(m, o_direct=True)
-                                     for m in members]
-                        for e in raw_extents:
-                            for s in plan_stripe_reads(
-                                    e.offset, e.length, raid,
-                                    args.raid_chunk):
-                                ops.append((member_fi[s.member],
-                                            s.member_offset,
-                                            off + (s.logical_offset
-                                                   - e.offset),
-                                            s.length))
-                            off += e.length
-                    else:
-                        fi = eng.register_file(path, o_direct=True)
-                        for e in raw_extents:
-                            ops.append((fi, e.offset, off, e.length))
-                            off += e.length
-                    eng.register_dest(raw_dest)
-                    t0 = time.perf_counter()
-                    n_read = eng.read_vectored(ops, raw_dest)
-                    d = time.perf_counter() - t0
-                finally:
-                    eng.close()
-                assert n_read == raw_total
-                raw_gbps_list.append(raw_total / d / 1e9)
-
-            for i in range(2):
-                for arm in ((scan_arm, raw_arm) if i % 2 == 0
-                            else (raw_arm, scan_arm)):
-                    for p in drop_paths:
-                        _drop_cache_hint(p)
-                    arm()
-        else:
-            for p in drop_paths:
-                _drop_cache_hint(p)
-            scan_arm()
-        dt = min(scan_dts)
-        plain_bytes //= len(scan_dts)
-        pyarrow_bytes //= len(scan_dts)
-        disk_gbps = round(max(raw_gbps_list), 4) if raw_gbps_list else None
-        pd_res = _pushdown_ab(ctx, args) \
-            if getattr(args, "pushdown", False) else {}
-        sched = {k: _gs.counter(k).value - v0 for k, v0 in _sched0.items()}
-    finally:
-        ctx.close()
-    return {
-        **pd_res,
-        "bench": "parquet_scan",
-        "rows_per_s": round(n_rows / dt, 1),
-        "selected_gbps": round(sel_bytes / dt / 1e9, 4),
-        "rows": n_rows, "row_groups": meta.num_row_groups,
-        "selected_bytes": sel_bytes, "hits": int(hits),
-        "selected_columns": len(sel_cols),
-        # logical bytes either way, so raid and plain runs of the same
-        # file agree
-        "total_bytes": logical_bytes if raid else os.path.getsize(path),
-        "engine": cfg.engine,
-        "unit_batch": args.unit_batch, "raid_members": raid,
-        "compression": compression,
-        "disk_read_gbps": disk_gbps,
-        # same-run interleaved ratio: the scan machinery's cost over a bare
-        # engine gather of the identical extents (weather-independent; the
-        # absolute GB/s on either side is disk weather)
-        "vs_disk": round(sel_bytes / dt / 1e9 / disk_gbps, 4)
-        if disk_gbps else None,
-        # per-pass audit trail (VERDICT.md r4 next #3: best-of selection
-        # must not hide its discards)
-        "selected_gbps_passes": [round(sel_bytes / d / 1e9, 4)
-                                 for d in scan_dts],
-        "disk_gbps_passes": [round(g, 4) for g in raw_gbps_list],
-        "plain_decoded_bytes": int(plain_bytes),
-        "pyarrow_decoded_bytes": int(pyarrow_bytes),
-        # delivery-scheduler observability: per-column-chunk extents that
-        # landed adjacent merged into fewer engine ops (cumulative over the
-        # scan passes); the stripe window engages with --raid
-        "coalesce_ops_in": sched["coalesce_ops_in"],
-        "coalesce_ops_out": sched["coalesce_ops_out"],
-        "stripe_windows": sched["stripe_windows"],
-    }
-
-
-def _scoped_sched_delta(tenant: str, snap0: dict) -> dict:
-    """Per-tenant scheduler/engine column deltas since *snap0* (a snapshot
-    of ``global_stats.scoped(tenant=...)``): the SCHED_FIELDS counters plus
-    queue-wait and per-op-latency percentiles over the bucket deltas — the
-    per-tenant half of the multitenant bench columns (single-sourced key
-    list: strom.sched.scheduler.SCHED_FIELDS)."""
-    from strom.utils.stats import global_stats, percentile_from_buckets
-
-    snap1 = global_stats.scoped(tenant=tenant).snapshot()
-    out = {k: int(snap1.get(k, 0) - snap0.get(k, 0))
-           for k in ("sched_granted_ops", "sched_granted_bytes",
-                     "sched_throttle_waits")}
-
-    def delta_buckets(stem: str) -> list:
-        b0 = snap0.get(stem + "_hist") or []
-        b1 = snap1.get(stem + "_hist") or []
-        return [a - b for a, b in zip(b1, b0)] if b0 else list(b1)
-
-    qw = delta_buckets("sched_queue_wait")
-    out["sched_queue_wait_p50_us"] = percentile_from_buckets(qw, 0.50)
-    out["sched_queue_wait_p99_us"] = percentile_from_buckets(qw, 0.99)
-    out["engine_op_lat_p99_us"] = percentile_from_buckets(
-        delta_buckets("engine_op_lat"), 0.99)
-    return out
-
-
-def bench_multitenant(args: argparse.Namespace) -> dict:
-    """ISSUE 7 acceptance arm: N concurrent pipelines (2 vision JPEG
-    tenants + 1 parquet scan tenant) on ONE StromContext through the
-    multi-tenant scheduler. Each tenant runs solo first (its baseline),
-    then all three run concurrently; per-tenant columns (items/s, vs_solo,
-    queue-wait p50/p99, granted bytes, per-op engine latency p99 — keys
-    single-sourced in strom.sched.scheduler.SCHED_FIELDS) land prefixed
-    ``mt_<tenant>_``, and ``mt_vs_solo_mean`` is the aggregate efficiency
-    (mean of per-tenant concurrent/solo ratios — 1.0 = multiplexing was
-    free, the within-10% acceptance bound). The parquet tenant registers
-    INTERACTIVE, so its p99 queue wait is the no-starvation evidence: it
-    must stay bounded while the two training tenants flood the engine."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.parallel.mesh import make_mesh
-    from strom.pipelines import make_wds_vision_pipeline
-    from strom.pipelines.parquet_scan import parquet_count_where
-    from strom.sched.scheduler import SCHED_FIELDS  # noqa: F401 (contract)
-    from strom.utils.stats import global_stats as _gs
-
-    steps = int(getattr(args, "steps", 6) or 6)
-    batch = int(getattr(args, "batch", 8) or 8)
-    image_size = int(getattr(args, "image_size", 64) or 64)
-    pq_iters = int(getattr(args, "pq_iters", 2) or 2)
-    tar = args.file or _mk_wds_fixture(args.tmpdir, batch, image_size)
-    # parquet fixture: the narrow-scan shape, small enough for the budget
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    rows = int(getattr(args, "rows", 200_000) or 200_000)
-    pq_path = os.path.join(args.tmpdir, f"strom_bench_mt_{rows}.parquet")
-    if not os.path.exists(pq_path):
-        rng = np.random.default_rng(0)
-        pq.write_table(pa.table({
-            "value": rng.standard_normal(rows),
-            "key": rng.integers(0, 1 << 30, rows, dtype=np.int64)}),
-            pq_path, row_group_size=max(rows // 8, 1))
-        os.sync()
-
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth,
-                      num_buffers=max(args.depth * 2, 8),
-                      **_obs_config_kw(args))
-    ctx = StromContext(cfg)
-    out: dict = {"bench": "multitenant", "steps": steps, "batch": batch,
-                 "image_size": image_size, "engine": cfg.engine,
-                 "sched_enabled": cfg.sched_enabled}
-    try:
-        # tenant registry: two training-class vision tenants (the heavy
-        # traffic) + one interactive parquet tenant (the light one whose
-        # p99 the no-starvation acceptance bounds)
-        ctx.register_tenant("vis0", priority="training")
-        ctx.register_tenant("vis1", priority="training")
-        ctx.register_tenant("pq", priority="interactive")
-        n_dev = _fit_dp_devices(batch)
-        mesh = make_mesh({"dp": n_dev}, devices=jax.devices()[:n_dev])
-        sharding = NamedSharding(mesh, P("dp", None, None, None))
-        cpu = jax.devices("cpu")
-
-        def vision_run(tenant: str) -> float:
-            """steps batches through a tenant-labeled vision pipeline;
-            returns images/s (warmup batch excluded)."""
-            pipe = make_wds_vision_pipeline(
-                ctx, [tar], batch=batch, image_size=image_size,
-                sharding=sharding, decode_workers=2,
-                scope={"pipeline": "resnet", "tenant": tenant})
-            try:
-                next(pipe)[0].block_until_ready()  # warmup/compile
-                t0 = time.perf_counter()
-                imgs = None
-                for _ in range(steps):
-                    imgs, _ = next(pipe)
-                    imgs.block_until_ready()
-                if imgs is not None:
-                    _fetch_one(imgs)
-                dt = time.perf_counter() - t0
-            finally:
-                pipe.close()
-            return steps * batch / dt if dt else 0.0
-
-        def pq_run(tenant: str) -> float:
-            """pq_iters full count-where scans; returns rows/s."""
-            t0 = time.perf_counter()
-            for _ in range(pq_iters):
-                parquet_count_where(ctx, [pq_path], "value",
-                                    lambda v: v > 0, devices=cpu,
-                                    scope={"pipeline": "parquet",
-                                           "tenant": tenant})
-            dt = time.perf_counter() - t0
-            return pq_iters * rows / dt if dt else 0.0
-
-        workloads = (("vis0", vision_run), ("vis1", vision_run),
-                     ("pq", pq_run))
-        solo = {name: fn(name) for name, fn in workloads}
-
-        # concurrent phase: all three tenants flood one engine at once;
-        # per-tenant deltas come from the tenant-labeled scoped registry
-        snaps = {name: dict(_gs.scoped(tenant=name).snapshot())
-                 for name, _ in workloads}
-        conc: dict[str, float] = {}
-        errs: list = []
-
-        def run(name, fn):
-            try:
-                conc[name] = fn(name)
-            except BaseException as e:  # surfaced after join
-                errs.append((name, e))
-
-        threads = [threading.Thread(target=run, args=w, daemon=True,
-                                    name=f"strom-mt-{w[0]}")
-                   for w in workloads]
-        t0 = time.perf_counter()
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        out["concurrent_wall_s"] = round(time.perf_counter() - t0, 2)
-        if errs:
-            raise errs[0][1]
-
-        ratios = []
-        for name, _ in workloads:
-            d = _scoped_sched_delta(name, snaps[name])
-            vs = round(conc[name] / solo[name], 3) if solo[name] else None
-            d["items_per_s"] = round(conc[name], 1)
-            d["vs_solo"] = vs
-            if vs is not None:
-                ratios.append(vs)
-            for k, v in d.items():
-                out[f"mt_{name}_{k}"] = v
-            out[f"mt_{name}_solo_items_per_s"] = round(solo[name], 1)
-        # aggregate multiplexing efficiency: MEAN of per-tenant
-        # concurrent/solo ratios (units differ per tenant — img/s vs
-        # rows/s — so a raw sum would be meaningless). ~1.0 here means
-        # multiplexing added no loss, which holds when tenants bottleneck
-        # on their own decode/compute; tenants genuinely contending for
-        # one saturated engine necessarily drive the mean toward 1/N —
-        # read it alongside the per-tenant queue-wait columns, not alone.
-        out["mt_vs_solo_mean"] = round(sum(ratios) / len(ratios), 3) \
-            if ratios else None
-        out["mt_tenants"] = [name for name, _ in workloads]
-    finally:
-        ctx.close()
-    return out
 
 
 def cmd_daemon(args: argparse.Namespace) -> dict:
@@ -1968,23 +32,19 @@ def cmd_daemon(args: argparse.Namespace) -> dict:
     from strom.config import StromConfig
     from strom.delivery.core import StromContext
 
-    cfg = StromConfig.from_env(engine=args.engine,
-                               flight_dir=getattr(args, "flight_dir", "")
-                               or "",
-                               flight_stall_s=float(
-                                   getattr(args, "flight_stall_s", 30.0)
-                                   or 0.0),
-                               # closed-loop autotuner (ISSUE 16): the
-                               # daemon is the long-lived process the
-                               # controller was built for — /tune exposes
-                               # its state, --profile persists the search
-                               tune=bool(getattr(args, "tune", False)),
-                               tune_profile=getattr(args, "profile", "")
-                               or "",
-                               **_cache_config_kw(args))
+    hot = args.hot_cache_bytes
+    cfg = StromConfig.from_env(
+        engine=args.engine, flight_dir=args.flight_dir,
+        flight_stall_s=args.flight_stall_s,
+        # closed-loop autotuner: the daemon is the long-lived
+        # process the controller was built for — /tune exposes its state,
+        # --profile persists the search
+        tune=args.tune, tune_profile=args.profile,
+        hot_cache_bytes=hot, hot_cache_admit=args.hot_cache_admit,
+        readahead_window_batches=args.readahead_window if hot else 0)
     # explicit port (0 = OS-assigned ephemeral): the daemon ALWAYS serves
     # — a daemon without its /tenants surface would be unreachable
-    ctx = StromContext(cfg, metrics_port=int(args.metrics_port or 0))
+    ctx = StromContext(cfg, metrics_port=args.metrics_port)
     srv = ctx.metrics_server
     stop = threading.Event()
     got: dict = {"sig": None}
@@ -2006,18 +66,16 @@ def cmd_daemon(args: argparse.Namespace) -> dict:
     stuck: list = []
     n_tenants = 0
     if ctx.scheduler is not None:
-        stuck = ctx.scheduler.drain_all(
-            timeout_s=float(getattr(args, "drain_timeout", 10.0)))
+        stuck = ctx.scheduler.drain_all(timeout_s=args.drain_timeout)
         n_tenants = len(ctx.scheduler.tenants_info()["tenants"])
     print(f"strom daemon drained tenants={n_tenants} stuck={stuck}",
           flush=True)
     # persist the converged knobs BEFORE the signal re-raise below ends
     # the process — the next daemon run warm-starts from them
-    profile_path = getattr(args, "profile", "") or ""
-    if ctx.tuner is not None and profile_path:
+    if ctx.tuner is not None and args.profile:
         try:
             ctx.tuner.settle()  # don't persist an unevaluated trial value
-            ctx.tuner.profile().save(profile_path)
+            ctx.tuner.profile().save(args.profile)
         except OSError as e:
             print(f"tune profile save failed: {e}", file=sys.stderr)
     sig = got["sig"]
@@ -2043,1158 +101,10 @@ def cmd_daemon(args: argparse.Namespace) -> dict:
             "tenants": n_tenants, "stuck": stuck, "signal": sig}
 
 
-def bench_tune(args: argparse.Namespace) -> dict:
-    """Closed-loop autotuner arm (ISSUE 16 policy half): the SAME shuffled
-    block-read workload measured twice — once on the hand-configured knobs,
-    once after the coordinate-descent tuner has searched the live surfaces
-    (scheduler slice bytes, cache budget) against measured items/s. The
-    headline is ``tuned_vs_hand`` (the sentinel's >= 1.0 gate: guarded
-    revert during the search plus a final interleaved A/B validation —
-    a tuned profile that loses the A/B is discarded for the hand knobs —
-    mean losing to the hand config is a controller bug, not weather).
-    ``--profile`` warm-starts from a saved profile and saves the converged
-    knobs back. Keys: strom.tune.TUNE_BENCH_FIELDS."""
-    import random
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.tune import TUNE_BENCH_FIELDS  # noqa: F401 (contract)
-    from strom.tune import Autotuner, Profile, standard_knobs
-
-    path = args.file
-    created = False
-    if path is None:
-        path = os.path.join(args.tmpdir, "strom_bench_tune.bin")
-        if not os.path.exists(path) or os.path.getsize(path) < args.size:
-            _mk_testfile(path, args.size)
-        created = True
-    size = min(os.path.getsize(path), args.size) // args.block * args.block
-    cfg = StromConfig.from_env(engine=args.engine, block_size=args.block,
-                               queue_depth=args.depth,
-                               num_buffers=max(args.depth * 2, 8),
-                               hot_cache_bytes=args.cache_bytes,
-                               hot_cache_admit="always",
-                               **_obs_config_kw(args))
-    ctx = StromContext(cfg, metrics_port=args.metrics_port or None)
-    try:
-        offs = list(range(0, size, args.block))
-        rng = random.Random(0)
-
-        def epoch() -> float:
-            order = offs[:]
-            rng.shuffle(order)
-            t0 = time.perf_counter()
-            for off in order:
-                ctx.pread(path, off, min(args.block, size - off))
-            return len(order) / (time.perf_counter() - t0)
-
-        epoch()  # warm the cache once: both phases measure steady state
-        hand = max(epoch() for _ in range(args.iters))
-        last = {"rate": hand}
-        knobs = standard_knobs(ctx)
-        hand_knobs = {k.name: float(k.get()) for k in knobs}
-        tuner = Autotuner(knobs,
-                          lambda: {"objective": last["rate"]},
-                          guard_frac=cfg.tune_guard_frac,
-                          scope=ctx.scope,
-                          profile_name=os.path.splitext(os.path.basename(
-                              args.profile))[0] if args.profile else "tune")
-        if args.profile and os.path.exists(args.profile):
-            tuner.apply_profile(Profile.load(args.profile))
-        # beat the controller manually: one measured epoch per beat (the
-        # two-beat propose/evaluate state machine settles on real rates)
-        for _ in range(args.trials):
-            tuner.step()
-            last["rate"] = epoch()
-        # judge the final in-flight trial WITHOUT proposing another: the
-        # tuned phase must measure the converged knobs, not a live trial
-        tuner.settle()
-        tuned_knobs = {k.name: float(k.get()) for k in knobs}
-
-        def apply(vals: dict) -> None:
-            for k in knobs:
-                k.set(k.clamp(vals[k.name]))
-
-        if tuned_knobs == hand_knobs:
-            # every trial reverted: the tuned state IS the hand state, so
-            # the ratio is 1.0 by identity — re-measuring two identical
-            # configs would only report noise as a (dis)improvement
-            tuned = hand = max(hand, epoch())
-        else:
-            # INTERLEAVED final A/B: alternate tuned/hand epochs so slow
-            # drift (page-cache weather, thermal) cancels out of the ratio
-            # instead of landing on whichever phase ran second
-            tuned = hand = 0.0
-            for _ in range(args.iters):
-                apply(tuned_knobs)
-                tuned = max(tuned, epoch())
-                apply(hand_knobs)
-                hand = max(hand, epoch())
-            if tuned >= hand:
-                apply(tuned_knobs)  # ship the validated win
-            else:
-                # validation gate: a search "win" that loses the honest
-                # interleaved A/B was accepted on noise — ship the hand
-                # knobs instead (the contract is "never worse than hand",
-                # so what ships is hand and the ratio is 1.0 by identity)
-                tuned_knobs = hand_knobs
-                tuned = hand
-        ts = tuner.stats()
-        es = ctx.engine.stats()
-        if args.profile:
-            tuner.profile().save(args.profile)
-        out = {
-            "bench": "tune", "bytes": size, "block": args.block,
-            "engine": cfg.engine, "trials": args.trials,
-            "hand_items_per_s": round(hand, 2),
-            "tuned_items_per_s": round(tuned, 2),
-            "tuned_vs_hand": round(tuned / hand, 4) if hand else 0.0,
-            "tune_moves": ts["tune_moves"],
-            "tune_reverts": ts["tune_reverts"],
-            "tune_holds": ts["tune_holds"],
-            "tune_knobs": ts["tune_knobs"],
-            "tune_profile": args.profile or "",
-            "engine_fixed_buf_ratio":
-                round(float(es.get("engine_fixed_buf_ratio", 0.0)), 4),
-            "engine_unregistered_reads":
-                int(es.get("engine_unregistered_reads", 0)),
-            "file_created": created,
-        }
-        # the SQPOLL submit-syscall A/B rides this arm too (bench.py's
-        # driver copies TUNE_BENCH_FIELDS from here alone — the nvme cli
-        # arm emits the same fields for interactive runs)
-        out.update(_sqpoll_ab(cfg, path, size, args))
-        if not args.json:
-            print(f"  hand {hand:.1f} it/s -> tuned {tuned:.1f} it/s "
-                  f"(x{out['tuned_vs_hand']}) after {args.trials} trials: "
-                  f"{ts['tune_moves']} moves, {ts['tune_reverts']} reverts; "
-                  f"knobs {ts['tune_knobs']}", file=sys.stderr)
-        return out
-    finally:
-        ctx.close()
-
-
-def bench_chaos(args: argparse.Namespace) -> dict:
-    """Chaos arm (ISSUE 9 satellite): the resnet JPEG loader run twice over
-    one fixture — clean, then under a seeded fault plan (EIO + short reads
-    + latency spikes on the engine op stream). Every batch is hashed;
-    ``chaos_ok=1`` means the faulted run COMPLETED with batches
-    bit-identical to the clean pass (retries/failover/hedges absorbed the
-    injected chaos), ``chaos_slowdown`` is the bounded price paid, and the
-    resilience counter deltas say which mechanism did the absorbing. Keys
-    single-sourced in ``strom.engine.resilience.CHAOS_BENCH_FIELDS``."""
-    import hashlib
-
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.engine.resilience import CHAOS_BENCH_FIELDS  # noqa: F401 (contract)
-    from strom.parallel.mesh import make_mesh
-    from strom.pipelines import make_imagenet_resnet_pipeline
-    from strom.utils.stats import global_stats as _gs
-
-    path = args.file
-    if path is None:
-        path = _mk_wds_fixture(args.tmpdir, args.batch, args.image_size)
-    plan_spec = getattr(args, "fault_plan", "") or \
-        f"chaos:{int(getattr(args, 'seed', 0))}"
-    n_dev = _fit_dp_devices(args.batch)
-    mesh = make_mesh({"dp": n_dev}, devices=jax.devices()[:n_dev])
-    sharding = NamedSharding(mesh, P("dp", None, None, None))
-
-    def one_pass(fault_plan: str) -> tuple[float, list[str], int]:
-        # residency_hybrid off: the chaos pass must exercise the MEDIA op
-        # stream the plan's matchers see, not a page-cache memcpy
-        cfg = StromConfig(engine=args.engine, block_size=args.block,
-                          queue_depth=args.depth,
-                          num_buffers=max(args.depth * 2, 8),
-                          residency_hybrid=False, fault_plan=fault_plan,
-                          # the chaos arm runs with the lock-order witness
-                          # on (ISSUE 11): the seeded-fault op stream
-                          # exercises retry/failover/hedge lock paths the
-                          # clean arms never enter, so every round
-                          # cross-validates the static hierarchy at runtime
-                          debug_locks=True)
-        _drop_cache_hint(path)
-        ctx = StromContext(cfg)
-        try:
-            with make_imagenet_resnet_pipeline(
-                    ctx, [path], batch=args.batch,
-                    image_size=args.image_size, sharding=sharding,
-                    prefetch_depth=args.prefetch,
-                    decode_workers=args.decode_workers) as pipe:
-                hashes = []
-                t0 = time.perf_counter()
-                for _ in range(args.steps):
-                    imgs, lbls = next(pipe)
-                    h = hashlib.sha256()
-                    h.update(np.asarray(imgs).tobytes())
-                    h.update(np.asarray(lbls).tobytes())
-                    hashes.append(h.hexdigest())
-                dt = time.perf_counter() - t0
-            injected = 0
-            plan = getattr(ctx.engine, "plan", None)
-            if plan is not None:
-                injected = plan.stats()["faults_injected"]
-            return (args.steps * args.batch / dt if dt else 0.0, hashes,
-                    injected)
-        finally:
-            ctx.close()
-
-    clean_rate, clean_hashes, _ = one_pass("")
-    snap0 = _gs.snapshot()
-    faulty_rate, faulty_hashes, injected = one_pass(plan_spec)
-    resil = _resil_delta(snap0)
-    out = {
-        "bench": "chaos",
-        "batch": args.batch, "image_size": args.image_size,
-        "steps": args.steps, "engine": args.engine,
-        "fault_plan": plan_spec,
-        "chaos_ok": int(bool(clean_hashes)
-                        and clean_hashes == faulty_hashes),
-        "chaos_slowdown": round(clean_rate / faulty_rate, 3)
-        if faulty_rate else None,
-        "chaos_clean_images_per_s": round(clean_rate, 1),
-        "chaos_faulty_images_per_s": round(faulty_rate, 1),
-        "chaos_faults_injected": injected,
-        "chaos_chunk_retries": resil["chunk_retries"],
-        "chaos_failover_reads": resil["failover_reads"],
-        "chaos_breaker_trips": resil["breaker_trips"],
-        "chaos_hedges_fired": resil["hedges_fired"],
-    }
-    out.update({k: v for k, v in resil.items() if k not in out})
-    return out
-
-
-def bench_checkpoint(args: argparse.Namespace) -> dict:
-    """Write path bench (ISSUE 13): engine checkpoint save/restore of the
-    llama train state vs a pickle-to-filesystem baseline, plus a warm-spill
-    epoch pair over an engine-written rawbin fixture.
-
-    Three phases, all on the engine write path the PR added:
-    1. ckpt — ``strom.ckpt.save_checkpoint`` of a real llama train state
-       (chunked ``op="write"`` gathers through slab-pool staging, crash-safe
-       tmp+rename) rated MB/s against ``save_pickle``; restore rides
-       ``memcpy_ssd2tpu`` and the round-trip is verified bit-exact
-       (``ckpt_roundtrip_ok``). Keys: strom.ckpt.checkpoint.CKPT_FIELDS.
-    2. spill — a tiny hot cache over a rawbin fixture GENERATED through
-       ``write_token_shard`` (the engine writes what it will read): epoch 1
-       admits+evicts into the NVMe spill tier, epoch 2 re-reads the same
-       records — served RAM+spill with ZERO source-engine reads
-       (``spill_cache_miss_bytes`` = 0 is the acceptance bit). Keys:
-       strom.delivery.spill.SPILL_FIELDS."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: F401
-
-    from strom.ckpt import (CKPT_FIELDS, restore_checkpoint, save_checkpoint,
-                            save_pickle)
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.delivery.spill import SPILL_FIELDS  # noqa: F401 (contract)
-    from strom.formats.rawbin import TokenShardSet, write_token_shard
-    from strom.models.llama import LlamaConfig
-    from strom.parallel.mesh import make_mesh
-    from strom.parallel.train import init_train_state, make_optimizer
-
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth,
-                      num_buffers=max(args.depth * 2, 8),
-                      **_obs_config_kw(args))
-    out: dict = {"bench": "checkpoint", "engine": cfg.engine,
-                 "model": args.model}
-    ctx = StromContext(cfg)
-    try:
-        mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
-        mcfg = getattr(LlamaConfig, args.model)()
-        opt = make_optimizer()
-        with mesh:
-            state = init_train_state(jax.random.key(0), mcfg, mesh, opt)
-        jax.block_until_ready(state)
-        d = os.path.join(args.tmpdir, "strom_bench_ckpt")
-        t0 = time.perf_counter()
-        manifest = save_checkpoint(ctx, d, state)
-        save_s = time.perf_counter() - t0
-        payload = manifest["payload_bytes"]
-        pk = os.path.join(args.tmpdir, "strom_bench_ckpt.pkl")
-        t0 = time.perf_counter()
-        save_pickle(pk, state)
-        pickle_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        back = restore_checkpoint(ctx, d, state)
-        jax.block_until_ready(back)
-        restore_s = time.perf_counter() - t0
-        la, _ = jax.tree_util.tree_flatten(state)
-        lb, _ = jax.tree_util.tree_flatten(back)
-        ok = all(np.array_equal(np.asarray(a), np.asarray(b))
-                 for a, b in zip(la, lb))
-        mb = payload / 1e6
-        out.update({
-            "ckpt_bytes": payload,
-            "ckpt_leaves": len(manifest["leaves"]),
-            "ckpt_save_mb_per_s": round(mb / save_s, 1) if save_s else None,
-            "ckpt_restore_mb_per_s":
-                round(mb / restore_s, 1) if restore_s else None,
-            "ckpt_pickle_save_mb_per_s":
-                round(mb / pickle_s, 1) if pickle_s else None,
-            "ckpt_save_vs_pickle":
-                round(pickle_s / save_s, 3) if save_s else None,
-            "ckpt_roundtrip_ok": int(ok),
-        })
-        with contextlib.suppress(OSError):
-            os.unlink(pk)
-        shutil.rmtree(d, ignore_errors=True)
-    finally:
-        ctx.close()
-
-    # -- spill epoch pair ---------------------------------------------------
-    fixture_bytes = 16 << 20
-    record_tokens = 1024
-    scfg = StromConfig(engine=args.engine, block_size=args.block,
-                       queue_depth=args.depth,
-                       num_buffers=max(args.depth * 2, 8),
-                       hot_cache_bytes=max(fixture_bytes // 8, 1 << 20),
-                       hot_cache_admit="always",
-                       spill_bytes=fixture_bytes * 2,
-                       spill_dir=args.tmpdir,
-                       **_obs_config_kw(args))
-    sctx = StromContext(scfg)
-    try:
-        shard = os.path.join(args.tmpdir, "strom_bench_spill_tokens.bin")
-        rng = np.random.default_rng(7)
-        toks = rng.integers(0, 1 << 15,
-                            fixture_bytes // 4, dtype=np.int32)
-        # the fixture is generated through the SAME engine that reads it
-        # back (ISSUE 13 front 4: writers feed the bench they serve)
-        write_token_shard(sctx, shard, toks)
-        ss = TokenShardSet((shard,), record_tokens=record_tokens)
-        _drop_cache_hint(shard)
-        step = 32  # records per read
-
-        def one_epoch() -> float:
-            t0 = time.perf_counter()
-            for lo in range(0, ss.num_records - step + 1, step):
-                sctx.pread(ss.extents(list(range(lo, lo + step))))
-            return time.perf_counter() - t0
-
-        one_epoch()  # epoch 1: cold — admit, evict, demote to spill
-        s1 = sctx.stats(sections=["cache", "spill"])
-        miss1 = s1["cache"]["cache_miss_bytes"]
-        cold_spilled = s1["spill"]["spill_spilled_bytes"]
-        warm_s = one_epoch()  # epoch 2: RAM + spill, zero source reads
-        s2 = sctx.stats(sections=["cache", "spill"])
-        sp = s2["spill"]
-        hit = sp["spill_hit_bytes"]
-        out.update({
-            "spill_hit_bytes": hit,
-            "spill_hits": sp["spill_hits"],
-            "spill_spilled_bytes": cold_spilled,
-            "spill_entries": sp["spill_entries"],
-            "spill_bytes": sp["spill_bytes"],
-            "spill_hit_ratio": sp["spill_hit_ratio"],
-            # ISSUE 14 satellites: spill I/O route split (engine vs
-            # buffered-fd fallback) and readahead-driven promotions
-            "spill_promote_bytes": sp["spill_promote_bytes"],
-            "spill_engine_ops": sp["spill_engine_ops"],
-            "spill_fallback_ops": sp["spill_fallback_ops"],
-            # the acceptance bit: repeat traffic never misses to the
-            # source engine (RAM + spill covered everything)
-            "spill_cache_miss_bytes":
-                s2["cache"]["cache_miss_bytes"] - miss1,
-            "spill_warm_mb_per_s":
-                round(fixture_bytes / 1e6 / warm_s, 1) if warm_s else None,
-        })
-        with contextlib.suppress(OSError):
-            os.unlink(shard)
-    finally:
-        sctx.close()
-    return out
-
-
-def bench_resume(args: argparse.Namespace) -> dict:
-    """Preemption-safety arm (ISSUE 14): async-save stall overhead vs the
-    synchronous save wall, then a full kill/restart recovery cycle.
-
-    Phase 1 — **async save stall**: the llama train state is saved once
-    synchronously (the wall the old path charged the training thread),
-    then ``--saves`` times through the AsyncCheckpointer with a drained
-    writer between saves, so each measured stall is the pure
-    snapshot+handoff cost. ``ckpt_async_stall_frac`` (mean stall / sync
-    wall) is the <25% acceptance; commits run CRC-verified-restorable
-    (round-trip checked on the last one). Keys:
-    strom.ckpt.async_save.CKPT_ASYNC_FIELDS.
-
-    Phase 2 — **kill/resume**: strom.faults.resume_harness.run_kill_resume
-    — a subprocess trainer SIGKILL'd at a seeded mid-epoch step, restarted
-    from last_committed + its StepToken, the remaining batch stream
-    asserted bit-identical to an uninterrupted run (no epoch replay, no
-    orphaned tmp checkpoint). Keys: strom.ckpt.jobstate.RESUME_FIELDS."""
-    import jax
-
-    from strom.ckpt import (AsyncCheckpointer, restore_checkpoint,
-                            save_checkpoint)
-    from strom.ckpt.async_save import CKPT_ASYNC_FIELDS  # noqa: F401 (contract)
-    from strom.ckpt.jobstate import RESUME_FIELDS  # noqa: F401 (contract)
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.faults.resume_harness import run_kill_resume
-    from strom.models.llama import LlamaConfig
-    from strom.parallel.mesh import make_mesh
-    from strom.parallel.train import init_train_state, make_optimizer
-
-    cfg = StromConfig(engine=args.engine, block_size=args.block,
-                      queue_depth=args.depth,
-                      num_buffers=max(args.depth * 2, 8),
-                      **_obs_config_kw(args))
-    out: dict = {"bench": "resume", "engine": cfg.engine,
-                 "model": args.model}
-    ctx = StromContext(cfg)
-    try:
-        mesh = make_mesh({"dp": 1}, devices=jax.devices()[:1])
-        mcfg = getattr(LlamaConfig, args.model)()
-        opt = make_optimizer()
-        with mesh:
-            state = init_train_state(jax.random.key(0), mcfg, mesh, opt)
-        jax.block_until_ready(state)
-        d = os.path.join(args.tmpdir, "strom_bench_resume_ckpt")
-        t0 = time.perf_counter()
-        manifest = save_checkpoint(ctx, d, state)
-        sync_wall_us = (time.perf_counter() - t0) * 1e6
-        payload = manifest["payload_bytes"]
-        cp = AsyncCheckpointer(ctx, d)
-        commit_walls = []
-        try:
-            for _ in range(max(args.saves, 1)):
-                t0 = time.perf_counter()
-                cp.save(state)
-                cp.wait()  # drained between saves: stall = pure snapshot
-                commit_walls.append(time.perf_counter() - t0)
-        finally:
-            cp.close()
-        back = restore_checkpoint(ctx, d, state, verify=True)
-        jax.block_until_ready(back)
-        la, _ = jax.tree_util.tree_flatten(state)
-        lb, _ = jax.tree_util.tree_flatten(back)
-        ok = all(np.array_equal(np.asarray(a), np.asarray(b))
-                 for a, b in zip(la, lb))
-        st = cp.stats()
-        stall_mean = st["ckpt_async_stall_mean_us"]
-        commit_s = min(commit_walls) if commit_walls else 0.0
-        out.update({
-            "ckpt_bytes": payload,
-            "ckpt_async_saves": st["ckpt_async_saves"],
-            "ckpt_async_stall_p99_us": st["ckpt_async_stall_p99_us"],
-            "ckpt_async_stall_mean_us": stall_mean,
-            "ckpt_sync_save_wall_us": round(sync_wall_us, 1),
-            "ckpt_async_stall_frac":
-                round(stall_mean / sync_wall_us, 4) if sync_wall_us else None,
-            "ckpt_async_commit_mb_per_s":
-                round(payload / 1e6 / commit_s, 1) if commit_s else None,
-            "ckpt_async_roundtrip_ok": int(ok),
-        })
-        shutil.rmtree(d, ignore_errors=True)
-    finally:
-        ctx.close()
-
-    # -- kill/restart recovery cycle ----------------------------------------
-    wd = os.path.join(args.tmpdir, "strom_bench_resume_harness")
-    shutil.rmtree(wd, ignore_errors=True)
-    res = run_kill_resume(wd, seed=args.seed, sig=args.signal,
-                          engine=args.engine if args.engine != "auto"
-                          else "python")
-    for k in RESUME_FIELDS:
-        out[k] = res.get(k)
-    if res.get("failures"):
-        out["resume_failures"] = res["failures"][:4]
-    shutil.rmtree(wd, ignore_errors=True)
-    return out
-
-
-def bench_dist(args: argparse.Namespace) -> dict:
-    """Distributed data plane arm (ISSUE 15 tentpole): an N-process
-    CPU-mesh ingest over a shared engine-written token fixture. Each
-    worker owns a balanced file shard (``multihost.assign_balanced``),
-    warms it into its hot cache, serves it to peers over the extent
-    service (strom/dist/peers.py), and assembles its slice of every
-    global batch through the full delivery plan — rows backed by another
-    host's files arrive over the socket, not as duplicate SSD reads.
-
-    ``dist_ok=1`` folds the acceptance: every worker exited 0 AND every
-    per-host batch stream was bit-identical to the single-process
-    pipeline; ``dist_peer_hit_ratio`` is the share of assembled batch
-    bytes served peer-to-peer; ``dist_engine_ingest_bytes`` must be 0
-    when ownership warming covered the dataset (no duplicate SSD reads).
-    A single-process pass rates the same row stream for ``dist_vs_single``.
-    Keys single-sourced in ``strom.dist.peers.DIST_BENCH_FIELDS``."""
-    import shutil
-
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-    from strom.dist.launch import measure_ingest
-    from strom.dist.peers import DIST_BENCH_FIELDS  # noqa: F401 (contract)
-    from strom.formats.rawbin import write_token_shard
-
-    wd = os.path.join(args.tmpdir, "strom_bench_dist")
-    shutil.rmtree(wd, ignore_errors=True)
-    data_dir = os.path.join(wd, "data")
-    os.makedirs(data_dir, exist_ok=True)
-    # fixture through the ENGINE write path (ISSUE 13 contract: fixtures
-    # are generated by the machinery that later reads them)
-    rng = np.random.default_rng(args.seed)
-    ctx = StromContext(StromConfig(engine=args.engine, queue_depth=8,
-                                   num_buffers=16))
-    try:
-        for i in range(args.files):
-            write_token_shard(
-                ctx, os.path.join(data_dir, f"shard{i}.bin"),
-                rng.integers(0, 32000, (args.records, args.seq_len),
-                             dtype=np.int32))
-    finally:
-        ctx.close()
-
-    worker_engine = args.engine if args.engine != "auto" else "python"
-    single = measure_ingest(
-        1, os.path.join(wd, "single"), data_dir=data_dir, steps=args.steps,
-        batch=args.batch, seq_len=args.seq_len, seed=args.seed,
-        engine=worker_engine, mode=args.mode,
-        devices_per_proc=args.devices_per_proc)
-    multi = measure_ingest(
-        args.procs, os.path.join(wd, "multi"), data_dir=data_dir,
-        steps=args.steps, batch=args.batch, seq_len=args.seq_len,
-        seed=args.seed, engine=worker_engine, mode=args.mode,
-        devices_per_proc=args.devices_per_proc,
-        # chaos rides the WORKERS (peer-op rules fire on their fetch
-        # streams); the single-process baseline has no peers to chaos
-        fault_plan=getattr(args, "fault_plan", "") or "")
-    workers = multi.pop("workers")
-    single_rate = single.get("dist_items_per_s") or 0.0
-    out = {
-        "bench": "dist",
-        "procs": args.procs, "mode": args.mode, "engine": worker_engine,
-        "batch": args.batch, "seq_len": args.seq_len, "files": args.files,
-        **{k: v for k, v in multi.items()},
-        "dist_single_items_per_s": single_rate,
-        "dist_vs_single":
-            round(multi["dist_items_per_s"] / single_rate, 3)
-            if single_rate else None,
-        # single-process pass must itself be clean, or vs_single is noise
-        "dist_single_ok": single.get("dist_ok"),
-        "dist_worker_errors": sum(w.get("peer_errors", 0)
-                                  for w in workers),
-    }
-    if getattr(args, "batch_ab", False):
-        # ISSUE 20: batched-transport A/B — the SAME fleet/seed/steps
-        # rerun with the batch wire OFF (batch_extents=0 → every peer
-        # miss pays its own v1 round trip). Bit-identity must hold on
-        # both passes; dist_batch_vs_single > 1 means batching the
-        # gather's misses into one RTT bought real rate.
-        unb = measure_ingest(
-            args.procs, os.path.join(wd, "multi_unbatched"),
-            data_dir=data_dir, steps=args.steps, batch=args.batch,
-            seq_len=args.seq_len, seed=args.seed, engine=worker_engine,
-            mode=args.mode, devices_per_proc=args.devices_per_proc,
-            batch_extents=0)
-        unb.pop("workers", None)
-        unb_rate = unb.get("dist_items_per_s") or 0.0
-        out.update({
-            "dist_unbatched_ok": unb.get("dist_ok"),
-            "dist_unbatched_items_per_s": unb_rate,
-            "dist_batch_vs_single":
-                round(multi["dist_items_per_s"] / unb_rate, 3)
-                if unb_rate else None,
-        })
-    if getattr(args, "peer_compress", False):
-        # ISSUE 19: compressed-wire A/B — the SAME fleet/seed/steps rerun
-        # with peer_compress on. Bit-identity (dist_ok) must hold on both
-        # passes; the comparison is wire bytes for the identical served
-        # payloads (the raw pass's wire bytes == its served bytes)
-        comp = measure_ingest(
-            args.procs, os.path.join(wd, "multi_comp"), data_dir=data_dir,
-            steps=args.steps, batch=args.batch, seq_len=args.seq_len,
-            seed=args.seed, engine=worker_engine, mode=args.mode,
-            devices_per_proc=args.devices_per_proc, peer_compress=True)
-        comp.pop("workers", None)
-        raw_wire = multi["dist_peer_wire_bytes"]
-        comp_wire = comp.get("dist_peer_wire_bytes", 0)
-        out.update({
-            "dist_comp_ok": comp.get("dist_ok"),
-            "dist_peer_raw_wire_bytes": raw_wire,
-            "dist_peer_comp_wire_bytes": comp_wire,
-            # >1 = the compressed pass moved fewer bytes for the same rows
-            "dist_peer_comp_vs_raw":
-                round(raw_wire / comp_wire, 4) if comp_wire else None,
-            "peer_comp_ratio": comp.get("peer_comp_ratio", 0.0),
-        })
-    shutil.rmtree(wd, ignore_errors=True)
-    return out
-
-
-def bench_all(args: argparse.Namespace) -> dict:
-    """Every BASELINE config in one run (quick shapes): nvme raw baseline,
-    ssd2host framework ratio, ssd2tpu delivered, resnet/vit/llama loaders
-    with real train steps, parquet scan plain + striped + wide. One failed
-    phase never sinks the rest."""
-    size = args.size
-    # --file applies to the byte-oriented phases (any file is valid input
-    # there; llama reads it as packed tokens) and --iters to the nvme and
-    # ssd2tpu phases (ssd2host runs a fixed 2 passes per arm: alternating
-    # order needs an even count); the format-bound phases (resnet/vit/
-    # parquet) always use their generated fixtures — stated in the
-    # subcommand help
-    common = dict(file=None, size=size, block=args.block, depth=args.depth,
-                  iters=1, engine=args.engine, tmpdir=args.tmpdir, json=True)
-    byte_file = dict(file=args.file, iters=args.iters)
-    phases = [
-        ("nvme", bench_nvme, dict(buffered=False, huge=False, numa_node=-1,
-                                  per_op=False, sqpoll=False, **byte_file)),
-        ("ssd2host", bench_ssd2host, dict(file=args.file, iters=2)),
-        ("ssd2host_raid", bench_ssd2host, dict(file=args.file, iters=2,
-                                               raid=4,
-                                               raid_chunk=512 * 1024)),
-        ("ssd2tpu", bench_ssd2tpu, dict(chunk=min(32 * 1024 * 1024, size),
-                                        prefetch=2, **byte_file)),
-        ("llama", bench_llama, dict(batch=8, seq_len=2047, steps=8,
-                                    prefetch=6, train_step=True,
-                                    model="small", attn="flash",
-                                    file=args.file)),
-        ("resnet", bench_resnet, dict(batch=32, image_size=176, steps=6,
-                                      prefetch=2, decode_workers=8,
-                                      train_step=True, model="resnet50")),
-        ("resnet_predecoded", bench_resnet,
-         dict(batch=32, image_size=176, steps=6, prefetch=8,
-              decode_workers=8, train_step=True, model="resnet50",
-              predecoded=True)),
-        ("vit", bench_vit, dict(batch=32, image_size=176, steps=6, prefetch=2,
-                                decode_workers=8, raid=4,
-                                raid_chunk=512 * 1024, train_step=True,
-                                model="vit_b16")),
-        ("parquet", bench_parquet, dict(rows=500_000, row_groups=16,
-                                        prefetch=2, unit_batch=4, raid=0,
-                                        raid_chunk=512 * 1024)),
-        ("parquet_raid0", bench_parquet, dict(rows=500_000, row_groups=16,
-                                              prefetch=2, unit_batch=4,
-                                              raid=4,
-                                              raid_chunk=512 * 1024,
-                                              disk_rate=True)),
-        ("parquet_wide", bench_parquet, dict(rows=200_000, row_groups=8,
-                                             prefetch=2, unit_batch=4,
-                                             raid=0, raid_chunk=512 * 1024,
-                                             columns=16)),
-        ("parquet_plain", bench_parquet, dict(rows=200_000, row_groups=4,
-                                              prefetch=4, unit_batch=1,
-                                              raid=0, raid_chunk=512 * 1024,
-                                              columns=16,
-                                              compression="none",
-                                              dtype="float32",
-                                              disk_rate=True)),
-    ]
-    out: dict = {"bench": "all", "failed": []}
-    for name, fn, extra in phases:
-        try:
-            t0 = time.perf_counter()
-            out[name] = fn(argparse.Namespace(**{**common, **extra}))
-            out[name]["wall_s"] = round(time.perf_counter() - t0, 1)
-        except Exception as e:  # noqa: BLE001 - keep the matrix going
-            out[name] = {"error": repr(e)}
-            out["failed"].append(name)
-            print(f"bench {name} failed: {e!r}", file=sys.stderr)
-    return out
-
-
-def _add_decode_flags(p: argparse.ArgumentParser) -> None:
-    """Decode-path A/B flags shared by the JPEG vision arms (defaults: all
-    three optimizations ON, per StromConfig)."""
-    p.add_argument("--full-decode", action="store_true", dest="full_decode",
-                   help="disable reduced-scale JPEG decode (A/B the "
-                        "SOF-header 1/2 / 1/4 / 1/8 IDCT fast path)")
-    p.add_argument("--no-slot-decode", action="store_true",
-                   dest="no_slot_decode",
-                   help="disable direct-to-slot decode: workers return rows "
-                        "and the batch is np.stack'd (the legacy copy path)")
-    p.add_argument("--no-overlap-put", action="store_true",
-                   dest="no_overlap_put",
-                   help="disable overlapped shard delivery: decode the whole "
-                        "batch, then device_put each device group serially")
-    p.add_argument("--no-stream", action="store_true", dest="no_stream",
-                   help="disable intra-batch streaming (ISSUE 5): restore "
-                        "the gather-ALL -> decode-ALL -> put-ALL barrier "
-                        "path — the A/B control for the completion-driven "
-                        "read->decode->put dataflow (batches bit-identical)")
-    p.add_argument("--stream", action="store_true", dest="stream",
-                   help="explicitly enable intra-batch streaming (the "
-                        "default; pairs with --no-stream for A/B scripts)")
-    p.add_argument("--no-native-decode", action="store_true",
-                   dest="no_native_decode",
-                   help="disable the libjpeg-turbo native binding (ISSUE "
-                        "12): decode through cv2, the pre-v2 path "
-                        "(bit-identical output)")
-    p.add_argument("--no-fuse-decode", action="store_true",
-                   dest="no_fuse_decode",
-                   help="disable fused-run decode dispatch: one pool task "
-                        "per sample, the pre-v2 shape (bit-identical)")
-    p.add_argument("--no-roi-decode", action="store_true",
-                   dest="no_roi_decode",
-                   help="disable ROI/partial-MCU decode: always decode the "
-                        "full (or reduced) frame before cropping")
-    p.add_argument("--decode-cache", action="store_true",
-                   dest="decode_cache",
-                   help="admit first-epoch decode OUTPUT into the hot "
-                        "cache (needs --hot-cache) so repeat epochs pay "
-                        "only crop+resize — predecoded-on-the-fly")
-    p.add_argument("--no-decode2-phases", action="store_true",
-                   dest="no_decode2",
-                   help="skip the decode-v2 bench phases (native-vs-cv2 "
-                        "A/B epochs + decoded-cache cold/warm pair)")
-
-
-def _add_cache_flags(p: argparse.ArgumentParser) -> None:
-    """Hot-set cache knobs shared by the vision arms (ISSUE 4): default OFF
-    (repeat traffic re-reads NVMe, the pre-cache behavior)."""
-    p.add_argument("--hot-cache", type=int, nargs="?",
-                   const=256 * 1024 * 1024, default=0,
-                   dest="hot_cache_bytes", metavar="BYTES",
-                   help="enable the hot-set host cache with this byte "
-                        "budget (no value: 256MiB). Adds a cold/warm epoch "
-                        "phase pair to the bench output — warm epochs must "
-                        "serve from RAM, not NVMe")
-    p.add_argument("--no-hot-cache", action="store_true", dest="no_hot_cache",
-                   help="force the cache off (overrides --hot-cache)")
-    p.add_argument("--hot-cache-admit", default="second_touch",
-                   choices=["second_touch", "always"], dest="hot_cache_admit",
-                   help="admission policy: second_touch (first epoch "
-                        "observes, second serves — scan-resistant) or "
-                        "always (force-admit on first read)")
-    p.add_argument("--readahead-window", type=int, default=0,
-                   dest="readahead_window", metavar="BATCHES",
-                   help="epoch-aware readahead: warm the sampler's next N "
-                        "batches into the hot cache from a background "
-                        "thread that yields to demand reads (0 = off; "
-                        "needs --hot-cache)")
-
-
 def build_parser() -> argparse.ArgumentParser:
-    """The ``strom-bench`` argument parser: each subcommand's namespace
-    carries its bench function as ``fn`` (``args.fn(args)`` runs it)."""
+    """The ``strom-bench`` argument parser: ``daemon`` and ``check``."""
     ap = argparse.ArgumentParser(prog="strom-bench")
     sub = ap.add_subparsers(dest="cmd", required=True)
-
-    def common(p):
-        p.add_argument("--file", default=None, help="benchmark file (default: generated)")
-        p.add_argument("--size", type=int, default=1 << 30, help="bytes to read")
-        p.add_argument("--block", type=int, default=128 * 1024, help="I/O block size")
-        p.add_argument("--depth", type=int, default=32, help="queue depth")
-        p.add_argument("--iters", type=int, default=3)
-        p.add_argument("--engine", default="auto", choices=["auto", "uring", "python"])
-        p.add_argument("--tmpdir", default=os.environ.get("STROM_BENCH_DIR", "/tmp"))
-        p.add_argument("--json", action="store_true", help="print one JSON line only")
-        p.add_argument("--metrics-port", type=int, default=0,
-                       dest="metrics_port",
-                       help="serve /metrics (Prometheus), /stats (JSON) and "
-                            "/trace (event-ring dump) on 127.0.0.1:<port> "
-                            "while the bench runs (0 = off); scrape with "
-                            "curl localhost:<port>/metrics mid-run")
-        p.add_argument("--trace-out", default=None, dest="trace_out",
-                       help="dump the event ring as Trace Event JSON here "
-                            "when the bench finishes — load the file in "
-                            "chrome://tracing or https://ui.perfetto.dev")
-        p.add_argument("--flight-dir", default=os.environ.get(
-                           "STROM_FLIGHT_DIR", ""), dest="flight_dir",
-                       help="arm the flight recorder: dump an atomic crash "
-                            "bundle (trace + stats + thread stacks + "
-                            "last-N progress samples) here on SIGTERM, "
-                            "unhandled exception, or a stalled run "
-                            "(strom/obs/flight.py; empty = off)")
-        p.add_argument("--flight-stall-s", type=float, default=30.0,
-                       dest="flight_stall_s",
-                       help="no-step-progress watchdog threshold in "
-                            "seconds for the flight recorder's stall "
-                            "trigger (<= 0 disables it; signal/exception "
-                            "dumps stay armed)")
-        p.add_argument("--fault-plan", default="", dest="fault_plan",
-                       help="run under deterministic fault injection "
-                            "(strom/faults): a JSON plan file, an inline "
-                            "JSON object, or the preset 'chaos[:seed]' — "
-                            "the engine is wrapped in the FaultyEngine "
-                            "proxy and every read rides the plan's seeded "
-                            "errno/short-read/latency/stuck/death rules")
-        p.add_argument("--debug-locks", action="store_true",
-                       dest="debug_locks",
-                       help="run with the lock-order witness on "
-                            "(strom/utils/locks.py): every make_lock site "
-                            "records acquisition order into a process-wide "
-                            "graph and an inversion raises LockOrderError "
-                            "+ dumps a flight bundle instead of deadlocking "
-                            "later (also STROM_DEBUG_LOCKS=1; the chaos "
-                            "arm forces it on)")
-
-    p_nvme = sub.add_parser("nvme", help="config #1: O_DIRECT seq read -> host RAM")
-    common(p_nvme)
-    p_nvme.add_argument("--buffered", action="store_true",
-                        help="use the page-cache path instead of O_DIRECT")
-    p_nvme.add_argument("--huge", action="store_true",
-                        help="MAP_HUGETLB destination slab (A/B the 2MiB-page "
-                             "knob; silently falls back without reservation)")
-    p_nvme.add_argument("--numa-node", type=int, default=-1, dest="numa_node",
-                        help="pin the submit thread + mbind the dest slab to "
-                             "this NUMA node (A/B the affinity knob; -1 = off)")
-    p_nvme.add_argument("--per-op", action="store_true", dest="per_op",
-                        help="legacy per-block submit/wait loop instead of the "
-                             "native vectored gather")
-    p_nvme.add_argument("--sqpoll", action="store_true",
-                        help="IORING_SETUP_SQPOLL ring: kernel thread polls "
-                             "the SQ, zero syscalls per batch (A/B; wins "
-                             "only with spare cores; falls back when refused)")
-    p_nvme.add_argument("--warm", action="store_true",
-                        help="pre-warm the page cache each iter instead of "
-                             "dropping it: A/B arm for the residency hybrid "
-                             "(pair with STROM_RESIDENCY_HYBRID=0)")
-    p_nvme.set_defaults(fn=bench_nvme)
-
-    p_s2h = sub.add_parser("ssd2host",
-                           help="framework host-delivered ratio: raw engine "
-                                "read vs the delivered path up to the "
-                                "device_put boundary (alternating arms, "
-                                "best-of-N; the box-feasible >=0.90 form)")
-    common(p_s2h)
-    p_s2h.add_argument("--raid", type=int, default=0,
-                       help="measure on a RAID0 striped set of this many "
-                            "members (framework arm stripe-decodes through "
-                            "the alias; raw arm reads the members "
-                            "contiguously through a bare engine)")
-    p_s2h.add_argument("--raid-chunk", type=int, default=512 * 1024,
-                       dest="raid_chunk", help="RAID0 chunk size")
-    p_s2h.set_defaults(fn=bench_ssd2host, iters=4)
-
-    p_s2t = sub.add_parser("ssd2tpu", help="async SSD->TPU copy loop")
-    common(p_s2t)
-    p_s2t.add_argument("--chunk", type=int, default=64 * 1024 * 1024,
-                       help="bytes per async copy")
-    p_s2t.add_argument("--prefetch", type=int, default=2, help="copies in flight")
-    p_s2t.set_defaults(fn=bench_ssd2tpu)
-
-    p_llama = sub.add_parser("llama", help="config #4: packed-token loader tokens/s")
-    common(p_llama)
-    p_llama.add_argument("--batch", type=int, default=32)
-    p_llama.add_argument("--seq-len", type=int, default=2047, dest="seq_len")
-    p_llama.add_argument("--steps", type=int, default=50)
-    p_llama.add_argument("--prefetch", type=int, default=2)
-    p_llama.add_argument("--train-step", action="store_true", dest="train_step",
-                         help="phase 2: a real jitted train step consumes the "
-                              "batches (the 0-data-stall measurement)")
-    p_llama.add_argument("--model", default="small", choices=["tiny", "small"],
-                         help="LlamaConfig preset for --train-step")
-    p_llama.add_argument("--attn", default="flash", choices=["dense", "flash"],
-                         help="attention path for --train-step")
-    p_llama.add_argument("--bounded-steps", type=int, default=0,
-                         dest="bounded_steps",
-                         help="with --train-step: run an extra phase of this "
-                              "many steps with an execution-paced consumer "
-                              "(per-step host delay = measured step time) at "
-                              "--bounded-prefetch depth — the bounded-depth "
-                              "0-stall demonstration (0 = off)")
-    p_llama.add_argument("--bounded-prefetch", type=int, default=4,
-                         dest="bounded_prefetch",
-                         help="prefetch depth for the bounded 0-stall phase")
-    p_llama.add_argument("--auto-prefetch", action="store_true",
-                         dest="auto_prefetch",
-                         help="auto-tune prefetch depth in the --train-step "
-                              "phase: grow on stalls, shrink when lead time "
-                              "is ample, bounded by the slab pool "
-                              "(--prefetch is the starting depth)")
-    p_llama.set_defaults(fn=bench_llama)
-
-    p_rn = sub.add_parser("resnet", help="config #2: JPEG loader images/s")
-    common(p_rn)
-    p_rn.add_argument("--batch", type=int, default=64)
-    p_rn.add_argument("--image-size", type=int, default=224, dest="image_size")
-    p_rn.add_argument("--steps", type=int, default=20)
-    p_rn.add_argument("--prefetch", type=int, default=2)
-    p_rn.add_argument("--decode-workers", type=int, default=8, dest="decode_workers")
-    p_rn.add_argument("--train-step", action="store_true", dest="train_step",
-                      help="also run a REAL jitted ResNet train step over the "
-                           "loader (the 0-data-stall north-star measurement)")
-    p_rn.add_argument("--model", default="resnet50",
-                      choices=["tiny", "resnet50"],
-                      help="ResNet config for --train-step")
-    p_rn.add_argument("--predecoded", action="store_true",
-                      help="decode-free loader over a decode-once staged "
-                           "shard (strom.formats.predecoded): pure engine "
-                           "gather + device_put, no per-step JPEG decode")
-    p_rn.add_argument("--bounded-steps", type=int, default=0,
-                      dest="bounded_steps",
-                      help="with --train-step: extra phase of this many "
-                           "steps with an execution-paced consumer at "
-                           "--bounded-prefetch depth (non-degenerate "
-                           "0-stall demonstration; 0 = off)")
-    p_rn.add_argument("--bounded-prefetch", type=int, default=4,
-                      dest="bounded_prefetch",
-                      help="prefetch depth for the bounded 0-stall phase")
-    p_rn.add_argument("--auto-prefetch", action="store_true",
-                      dest="auto_prefetch",
-                      help="auto-tune prefetch depth in the --train-step "
-                           "phase (grow on stalls, shrink on ample lead; "
-                           "--prefetch is the starting depth)")
-    _add_decode_flags(p_rn)
-    _add_cache_flags(p_rn)
-    p_rn.set_defaults(fn=bench_resnet)
-
-    p_vit = sub.add_parser("vit", help="config #3: WDS .tar -> ViT loader "
-                                       "images/s over a RAID0 striped set")
-    common(p_vit)
-    p_vit.add_argument("--batch", type=int, default=64)
-    p_vit.add_argument("--image-size", type=int, default=224, dest="image_size")
-    p_vit.add_argument("--steps", type=int, default=20)
-    p_vit.add_argument("--prefetch", type=int, default=2)
-    p_vit.add_argument("--decode-workers", type=int, default=8, dest="decode_workers")
-    p_vit.add_argument("--raid", type=int, default=4,
-                       help="RAID0 member count (config #3: 4xNVMe)")
-    p_vit.add_argument("--raid-chunk", type=int, default=512 * 1024,
-                       dest="raid_chunk", help="RAID0 chunk size")
-    p_vit.add_argument("--train-step", action="store_true", dest="train_step",
-                       help="also run a REAL jitted ViT train step over the "
-                            "loader (the 0-data-stall north-star measurement)")
-    p_vit.add_argument("--model", default="vit_b16",
-                       choices=["tiny", "vit_b16"],
-                       help="ViT config for --train-step (image_size is "
-                            "overridden to --image-size)")
-    p_vit.add_argument("--predecoded", action="store_true",
-                       help="decode-free loader: the tar staged once as a "
-                            "packed uint8 shard, STRIPED over the RAID0 "
-                            "members — pure stripe-decoded engine gather")
-    p_vit.add_argument("--bounded-steps", type=int, default=0,
-                       dest="bounded_steps",
-                       help="with --train-step: extra phase of this many "
-                            "steps with an execution-paced consumer at "
-                            "--bounded-prefetch depth (non-degenerate "
-                            "0-stall demonstration; 0 = off)")
-    p_vit.add_argument("--bounded-prefetch", type=int, default=4,
-                       dest="bounded_prefetch",
-                       help="prefetch depth for the bounded 0-stall phase")
-    p_vit.add_argument("--auto-prefetch", action="store_true",
-                       dest="auto_prefetch",
-                       help="auto-tune prefetch depth in the --train-step "
-                            "phase (grow on stalls, shrink on ample lead; "
-                            "--prefetch is the starting depth)")
-    _add_decode_flags(p_vit)
-    _add_cache_flags(p_vit)
-    p_vit.set_defaults(fn=bench_vit)
-
-    p_pq = sub.add_parser("parquet", help="config #5: PG-Strom-style columnar "
-                                          "scan fan-out rows/s")
-    common(p_pq)
-    p_pq.add_argument("--rows", type=int, default=2_000_000)
-    p_pq.add_argument("--row-groups", type=int, default=32, dest="row_groups")
-    p_pq.add_argument("--prefetch", type=int, default=2)
-    p_pq.add_argument("--unit-batch", type=int, default=1, dest="unit_batch",
-                      help="row groups concatenated per device dispatch "
-                           "(amortizes per-call latency; scan aggregates "
-                           "are row-decomposable so results are identical)")
-    p_pq.add_argument("--raid", type=int, default=0,
-                      help="scan from a RAID0 striped set of this many "
-                           "members (0 = plain file) — the reference's "
-                           "flagship md-raid0-of-NVMe deployment shape")
-    p_pq.add_argument("--raid-chunk", type=int, default=512 * 1024,
-                      dest="raid_chunk", help="RAID0 chunk size")
-    p_pq.add_argument("--columns", type=int, default=1,
-                      help="select this many columns (value + N-1 float64 "
-                           "feature columns): the WIDE-projection arm, "
-                           "where selected bytes/row is large enough for "
-                           "selected_gbps to mean scan bandwidth")
-    p_pq.add_argument("--cpu-device", action="store_true", dest="cpu_device",
-                      help="run the jitted aggregate on the host backend "
-                           "instead of the default device")
-    p_pq.add_argument("--compression", default="snappy",
-                      choices=["snappy", "none"],
-                      help="generated fixture's column-chunk compression. "
-                           "'none' writes PLAIN-encoded chunks so decode is "
-                           "buffer reinterpretation and selected_gbps "
-                           "measures I/O, not a single-core codec (ignored "
-                           "with --file: it describes the fixture)")
-    p_pq.add_argument("--disk-rate", action="store_true", dest="disk_rate",
-                      help="also measure the same run's raw engine read rate "
-                           "over the same bytes-on-disk (disk_read_gbps): "
-                           "the I/O yardstick selected_gbps compares against")
-    p_pq.add_argument("--dtype", default="float64",
-                      choices=["float64", "float32"],
-                      help="generated fixture's value/feature column dtype "
-                           "(float32: device dispatch aliases instead of "
-                           "downcasting under jax's x64-off default)")
-    p_pq.add_argument("--pushdown", action="store_true",
-                      help="also run the plan-time predicate pushdown A/B "
-                           "(ISSUE 19): the same scan pushed vs post-hoc "
-                           "over a monotone-keyed fixture — identical "
-                           "aggregates, strictly fewer submitted bytes "
-                           "(pushdown_ok gates both)")
-    p_pq.add_argument("--pushdown-selectivity", type=float, default=0.25,
-                      dest="pushdown_selectivity",
-                      help="fraction of rows the pushed predicate keeps "
-                           "(the monotone fixture makes this the fraction "
-                           "of row groups that survive refutation)")
-    p_pq.set_defaults(fn=bench_parquet)
-
-    p_all = sub.add_parser("all", help="every BASELINE config, quick shapes, "
-                                       "one combined JSON; exit 3 if any "
-                                       "phase fails. --file applies to nvme/"
-                                       "ssd2host/ssd2tpu/llama and --iters "
-                                       "to nvme/ssd2tpu (ssd2host runs 2 "
-                                       "alternating passes per arm); the "
-                                       "other phases are format-bound to "
-                                       "generated fixtures and single-pass")
-    common(p_all)
-    p_all.set_defaults(fn=bench_all, size=256 * 1024 * 1024)
-
-    p_mt = sub.add_parser(
-        "multitenant",
-        help="ISSUE 7 fairness arm: 2 vision + 1 parquet tenant "
-             "concurrently on ONE context through the multi-tenant "
-             "scheduler; per-tenant items/s, vs_solo, queue-wait p50/p99 "
-             "(mt_<tenant>_* columns, keys single-sourced in "
-             "strom.sched.scheduler.SCHED_FIELDS)")
-    common(p_mt)
-    p_mt.add_argument("--batch", type=int, default=8)
-    p_mt.add_argument("--image-size", type=int, default=64, dest="image_size")
-    p_mt.add_argument("--steps", type=int, default=6,
-                      help="timed batches per vision tenant")
-    p_mt.add_argument("--rows", type=int, default=200_000,
-                      help="parquet fixture rows")
-    p_mt.add_argument("--pq-iters", type=int, default=2, dest="pq_iters",
-                      help="full scans the parquet tenant runs")
-    p_mt.set_defaults(fn=bench_multitenant)
-
-    p_chaos = sub.add_parser(
-        "chaos",
-        help="ISSUE 9 resilience arm: the resnet JPEG loader run clean, "
-             "then under a seeded fault plan (EIO + short reads + latency "
-             "spikes on the engine op stream); chaos_ok=1 = the faulted "
-             "run completed with batches bit-identical to the clean pass, "
-             "chaos_slowdown = the bounded price paid (chaos_* columns, "
-             "keys single-sourced in "
-             "strom.engine.resilience.CHAOS_BENCH_FIELDS)")
-    common(p_chaos)
-    p_chaos.add_argument("--batch", type=int, default=16)
-    p_chaos.add_argument("--image-size", type=int, default=64,
-                         dest="image_size")
-    p_chaos.add_argument("--steps", type=int, default=6)
-    p_chaos.add_argument("--prefetch", type=int, default=2)
-    p_chaos.add_argument("--decode-workers", type=int, default=4,
-                         dest="decode_workers")
-    p_chaos.add_argument("--seed", type=int, default=0,
-                         help="fault-plan seed when --fault-plan is unset "
-                              "(the arm then runs the 'chaos:<seed>' "
-                              "preset)")
-    p_chaos.set_defaults(fn=bench_chaos)
-
-    p_ckpt = sub.add_parser(
-        "checkpoint",
-        help="ISSUE 13 write-path arm: engine checkpoint save/restore of "
-             "the llama train state (chunked op='write' gathers, crash-"
-             "safe tmp+rename, restore via memcpy_ssd2tpu) rated vs a "
-             "pickle-to-filesystem baseline, plus a warm-spill epoch pair "
-             "over an engine-written rawbin fixture (ckpt_*/spill_* "
-             "columns, keys single-sourced in strom.ckpt.checkpoint."
-             "CKPT_FIELDS and strom.delivery.spill.SPILL_FIELDS)")
-    common(p_ckpt)
-    p_ckpt.add_argument("--model", default="small",
-                        choices=["tiny", "small", "llama3_8b"],
-                        help="LlamaConfig preset whose train state is "
-                             "checkpointed (default: small — a few hundred "
-                             "MB of params+opt, enough to rate MB/s)")
-    p_ckpt.set_defaults(fn=bench_checkpoint)
-
-    p_res = sub.add_parser(
-        "resume",
-        help="ISSUE 14 preemption-safety arm: async snapshot-then-commit "
-             "save stall vs the synchronous save wall on the llama train "
-             "state (ckpt_async_* columns, keys single-sourced in "
-             "strom.ckpt.async_save.CKPT_ASYNC_FIELDS), then a kill/"
-             "restart recovery cycle — subprocess trainer SIGKILL'd at a "
-             "seeded mid-epoch step, restarted from last_committed + "
-             "StepToken, remaining batch stream asserted bit-identical "
-             "(resume_* columns, keys single-sourced in "
-             "strom.ckpt.jobstate.RESUME_FIELDS)")
-    common(p_res)
-    p_res.add_argument("--model", default="small",
-                       choices=["tiny", "small", "llama3_8b"],
-                       help="LlamaConfig preset whose train state the "
-                            "async-save stall is measured on")
-    p_res.add_argument("--saves", type=int, default=4,
-                       help="async saves to measure (writer drained "
-                            "between saves; stall = pure snapshot)")
-    p_res.add_argument("--seed", type=int, default=0,
-                       help="harness seed (kill step + fixture)")
-    p_res.add_argument("--signal", default="KILL",
-                       choices=["KILL", "TERM"],
-                       help="how the victim trainer dies")
-    p_res.set_defaults(fn=bench_resume)
-
-    p_dist = sub.add_parser(
-        "dist",
-        help="ISSUE 15 distributed data plane arm: N-process ingest over "
-             "a shared engine-written token fixture — per-host engines, "
-             "balanced shard ownership, peer extent service (an extent "
-             "hot on host A serves host B over the socket, no duplicate "
-             "SSD read). dist_ok=1 = every worker bit-identical to the "
-             "single-process pipeline; dist_peer_hit_ratio = batch bytes "
-             "served peer-to-peer (keys single-sourced in "
-             "strom.dist.peers.DIST_BENCH_FIELDS)")
-    common(p_dist)
-    p_dist.add_argument("--procs", type=int, default=2,
-                        help="worker processes (each its own engine + "
-                             "cache + peer server)")
-    p_dist.add_argument("--steps", type=int, default=6)
-    p_dist.add_argument("--batch", type=int, default=16,
-                        help="GLOBAL batch rows per step (split across "
-                             "the workers)")
-    p_dist.add_argument("--seq-len", type=int, dest="seq_len", default=64)
-    p_dist.add_argument("--files", type=int, default=4,
-                        help="fixture shard files (ownership is balanced "
-                             "across workers by size)")
-    p_dist.add_argument("--records", type=int, default=128,
-                        help="rows per fixture shard")
-    p_dist.add_argument("--seed", type=int, default=0)
-    p_dist.add_argument("--mode", default="host", choices=["host", "mesh"],
-                        help="host = numpy assembly (jax-free workers); "
-                             "mesh = jax.distributed + per-host "
-                             "memcpy_ssd2tpu into "
-                             "make_array_from_single_device_arrays")
-    p_dist.add_argument("--devices-per-proc", type=int,
-                        dest="devices_per_proc", default=1,
-                        help="virtual CPU devices per worker (mesh mode)")
-    p_dist.add_argument("--peer-compress", action="store_true",
-                        dest="peer_compress",
-                        help="also rerun the multi-process pass with the "
-                             "compressed peer wire (ISSUE 19): same fleet, "
-                             "same seed, bit-identical batches, "
-                             "compressed-vs-raw wire bytes reported")
-    p_dist.add_argument("--batch-ab", action="store_true", dest="batch_ab",
-                        help="also rerun the multi-process pass with the "
-                             "batched transport OFF (ISSUE 20): same "
-                             "fleet, same seed, bit-identical batches, "
-                             "dist_batch_vs_single = batched rate over "
-                             "per-extent-RTT rate")
-    p_dist.set_defaults(fn=bench_dist)
-
-    p_tune = sub.add_parser(
-        "tune",
-        help="closed-loop knob autotuner arm (ISSUE 16): the same "
-             "shuffled block-read workload on hand knobs vs after the "
-             "coordinate-descent search — tuned_vs_hand is the "
-             "bench_sentinel >= 1.0 gate; --profile persists the "
-             "converged knobs")
-    common(p_tune)
-    p_tune.add_argument("--cache-bytes", type=int, default=32 << 20,
-                        dest="cache_bytes",
-                        help="hot-cache budget the cache knob searches "
-                             "around (the fixture file should exceed it "
-                             "so the budget knob has a gradient)")
-    p_tune.add_argument("--trials", type=int, default=16,
-                        help="controller beats (one measured epoch each)")
-    p_tune.add_argument("--profile", default="",
-                        help="tune profile JSON: loaded before the search "
-                             "when it exists (warm start), converged "
-                             "knobs saved back after")
-    p_tune.set_defaults(fn=bench_tune, size=128 * 1024 * 1024, iters=3)
 
     p_daemon = sub.add_parser(
         "daemon",
@@ -3226,8 +136,23 @@ def build_parser() -> argparse.ArgumentParser:
                                "from it when it exists, save the "
                                "converged knobs back on graceful "
                                "shutdown (with --tune)")
-    _add_cache_flags(p_daemon)
-    p_daemon.set_defaults(fn=cmd_daemon)
+    p_daemon.add_argument("--hot-cache", type=int, nargs="?",
+                          const=256 * 1024 * 1024, default=0,
+                          dest="hot_cache_bytes", metavar="BYTES",
+                          help="enable the hot-set host cache with this "
+                               "byte budget (no value: 256MiB; default off)")
+    p_daemon.add_argument("--hot-cache-admit", default="second_touch",
+                          choices=["second_touch", "always"],
+                          dest="hot_cache_admit",
+                          help="admission policy: second_touch (first epoch "
+                               "observes, second serves — scan-resistant) "
+                               "or always (force-admit on first read)")
+    p_daemon.add_argument("--readahead-window", type=int, default=0,
+                          dest="readahead_window", metavar="BATCHES",
+                          help="epoch-aware readahead: warm the sampler's "
+                               "next N batches into the hot cache from a "
+                               "background thread that yields to demand "
+                               "reads (0 = off; needs --hot-cache)")
 
     p_check = sub.add_parser("check", help="≙ CHECK_FILE: report a file's data-path tier")
     p_check.add_argument("path")
@@ -3252,26 +177,8 @@ def main(argv: list[str] | None = None) -> int:
              "reasons": list(rep.reasons)}
         print(json.dumps(d, indent=None if args.json else 2))
         return 0
-    from strom.utils.compile_cache import enable_compile_cache
-
-    enable_compile_cache()
-    out = args.fn(args)
-    if getattr(args, "trace_out", None):
-        from strom.obs.chrome_trace import dump
-
-        # an unwritable trace path must not sink the completed bench's
-        # result JSON (same policy as the partial-artifact writes)
-        try:
-            print(f"trace written to {dump(args.trace_out)} "
-                  f"(load in chrome://tracing or ui.perfetto.dev)",
-                  file=sys.stderr)
-        except OSError as e:
-            print(f"trace dump to {args.trace_out} failed: {e}",
-                  file=sys.stderr)
-    print(json.dumps(out))
-    # a failed phase in the combined matrix must fail the process: CI
-    # running `strom-bench all` should not read errors-in-JSON as green
-    return 3 if out.get("failed") else 0
+    print(json.dumps(cmd_daemon(args)))
+    return 0
 
 
 if __name__ == "__main__":

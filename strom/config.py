@@ -77,10 +77,6 @@ class StromConfig:
                                        # spare cores exist; auto-falls back
                                        # when the kernel refuses it, and
                                        # supersedes coop_taskrun when active
-    uring_sqpoll: bool = False         # ISSUE 16 spelling of the same knob
-                                       # (daemon long-lived rings); either
-                                       # flag arms SQPOLL — __post_init__
-                                       # folds this one into sqpoll
     ring_recovery_s: float = 0.0       # MultiRingEngine quarantine recovery
                                        # cooldown: > 0 rebuilds a quarantined
                                        # member after this many seconds and
@@ -148,24 +144,9 @@ class StromConfig:
     # union then transferring serially. Implies decode_to_slot mechanics.
     decode_overlap_put: bool = True
 
-    # decode path v2 (ISSUE 12 tentpole — strom/formats/jpeg.py):
-    # decode_native: decode through the libjpeg-turbo binding in _core
-    # (sc_jpeg_decode) — no cv2 per-call setup, no BGR intermediate;
-    # bit-exact against the cv2 path for full/reduced decode and probed at
-    # build time (hosts without the headers silently keep cv2).
-    decode_native: bool = True
-    # decode_fuse_runs: one decode-pool task decodes a RUN of samples
-    # (auto-tuned length) instead of one task per sample, amortizing the
-    # per-task queue/contextvar/span overhead that dominates at ~1ms
-    # images. Off = the one-task-per-sample dispatch, bit-identical.
-    decode_fuse_runs: bool = True
-    # decode_roi: partial-MCU decode — since the RandomResizedCrop
-    # rectangle is fixed in full-res coordinates BEFORE decode, the native
-    # path decodes only the crop's scanlines/iMCU columns (turbo's
-    # jpeg_skip_scanlines/jpeg_crop_scanline), composing with the
-    # reduced_denom rule. Progressive members and frame-spanning crops
-    # ride the full decode; requires decode_native to engage.
-    decode_roi: bool = True
+    # decode path v2 (strom/formats/jpeg.py): the
+    # libjpeg-turbo binding, fused-run dispatch and ROI decode are always
+    # on (cv2 where the binding did not build).
     # decode_cache: predecoded-on-the-fly — admit first-epoch decode
     # OUTPUT (full-frame RGB8, keyed by member extent + decode-params
     # fingerprint) into the hot cache, so epoch >= 2 pays only
@@ -196,7 +177,7 @@ class StromConfig:
     # admission policy: "second_touch" (first epoch observes via a
     # block-granular touch ledger, the second admits — one-shot scans never
     # displace the hot set) or "always" (force-admit on first read: the
-    # knob for known-repeating workloads and the warm/cold bench arms)
+    # knob for known-repeating workloads)
     hot_cache_admit: str = "second_touch"
     # touch-ledger quantum: admission tracking is block-granular so the
     # second-touch test is stable across epochs even though coalescing
@@ -319,11 +300,6 @@ class StromConfig:
     numa_node: int = -1                # -1 = auto-discover from the device
     irq_affinity: bool = False
 
-    # extent-aware gather planning: split chunks at FIEMAP extent boundaries
-    # and submit in physical-address order (helps fragmented files; no-op on
-    # contiguous ones). FIEMAP is probed once per registered file and cached.
-    extent_aware: bool = True
-
     # residency-aware hybrid reads: probe per-range page-cache residency
     # (cachestat(2), else mincore) and serve WARM ranges through the buffered
     # fd — a memcpy from the cache — instead of re-reading them from media
@@ -387,7 +363,7 @@ class StromConfig:
     # preset ("chaos[:seed]"). Non-empty wraps the engine in FaultyEngine
     # at context construction, injecting deterministic errno / short-read
     # / bit-flip / latency / stuck / engine-death faults per the plan's
-    # matchers — any bench arm or test runs under reproducible chaos.
+    # matchers — any workload or test runs under reproducible chaos.
     fault_plan: str = ""
 
     # observability
@@ -420,8 +396,7 @@ class StromConfig:
     # runtime cross-check of the static hierarchy tools/stromlint
     # enforces. Off = plain threading.Lock, zero overhead. Enable via
     # STROM_DEBUG_LOCKS=1 (covers module-level locks created at import)
-    # or this flag (enabled before the context constructs its subsystems;
-    # the chaos bench arm runs with it on).
+    # or this flag (enabled before the context constructs its subsystems).
     debug_locks: bool = False
     # snapshot history (strom/obs/history.py — ISSUE 8 tentpole): when the
     # live server is on, a background thread samples the global registry
@@ -494,8 +469,6 @@ class StromConfig:
                              "unbatched transport)")
         if self.dist_conn_pool_size < 1:
             raise ValueError("dist_conn_pool_size must be >= 1")
-        if self.uring_sqpoll and not self.sqpoll:
-            object.__setattr__(self, "sqpoll", True)
         if self.ring_recovery_s < 0:
             raise ValueError("ring_recovery_s must be >= 0 (0 = sticky)")
         if self.tune_interval_s <= 0:

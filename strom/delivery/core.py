@@ -917,7 +917,7 @@ class StromContext:
         never experiments blind or on a tenant already missing its target.
         *profile_path* warm-starts from a saved :class:`strom.tune.Profile`;
         ``start=False`` builds the controller without the driver thread
-        (the bench arms beat it manually). Idempotent."""
+        (the caller beats it with ``step()``). Idempotent."""
         if getattr(self, "_closed", False):
             raise RuntimeError("StromContext is closed")
         if self._tuner is not None:
@@ -1246,7 +1246,7 @@ class StromContext:
             self.scope.set_gauge("coalesce_ops_in_last", n_in)
             self.scope.set_gauge("coalesce_ops_out_last", len(chunks))
 
-        if cfg.extent_aware and chunks and not member_cache:
+        if chunks and not member_cache:
             # extent-aware planning for plain-file gathers of every source
             # kind (whole-file reads AND format-reader ExtentLists): group
             # into per-file runs, each submitted in physical-address order.
@@ -2090,12 +2090,11 @@ class StromContext:
         on hardware whose host->device link is slower than the SSD, the
         end-to-end delivered/raw ratio measures the link, while
         host-delivered/raw measures the framework (the box-feasible form of
-        the >=90%-of-raw target, BASELINE.json:5 — see bench.py's
-        ``vs_baseline_host``).
+        the >=90%-of-raw target, BASELINE.json:5).
 
         *out*: preallocated aligned destination of at least the read's size
-        (a dest the caller registered with the engine rides READ_FIXED, same
-        as the raw bench arm); default: a fresh aligned slab.
+        (a dest the caller registered with the engine rides READ_FIXED);
+        default: a fresh aligned slab.
         """
         if self._closed:
             raise RuntimeError("StromContext is closed")

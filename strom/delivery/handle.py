@@ -14,12 +14,13 @@ transfer, which is exactly the "completion becomes an XLA token" design
 from __future__ import annotations
 
 import concurrent.futures
-import threading
-import time
 from typing import Any, Callable
 
 from strom.utils.stats import global_stats
-from strom.utils.locks import make_lock
+
+
+def _count_done(_f) -> None:
+    global_stats.add("handles_completed")
 
 
 class DMAHandle:
@@ -30,15 +31,10 @@ class DMAHandle:
         self._future = future
         self.nbytes = nbytes
         self.label = label
-        self.submitted_at = time.monotonic()
-        self._done_at: float | None = None
-        self._lock = make_lock("app.handle")
-        future.add_done_callback(self._on_done)
-
-    def _on_done(self, _f) -> None:
-        with self._lock:
-            self._done_at = time.monotonic()
-        global_stats.add("handles_completed")
+        # a plain function, not a bound method: the future must not hold
+        # the handle, or handle and future form a cycle and the delivered
+        # array lives until the cyclic GC runs
+        future.add_done_callback(_count_done)
 
     # -- MEMCPY_WAIT equivalents -------------------------------------------
     def done(self) -> bool:
@@ -61,11 +57,6 @@ class DMAHandle:
         """Full barrier: also waits for the host→HBM transfer itself."""
         arr = self.result(timeout)
         return arr.block_until_ready() if hasattr(arr, "block_until_ready") else arr
-
-    @property
-    def elapsed_s(self) -> float:
-        end = self._done_at if self._done_at is not None else time.monotonic()
-        return end - self.submitted_at
 
     def __repr__(self) -> str:
         state = "done" if self.done() else "pending"

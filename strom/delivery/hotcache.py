@@ -23,8 +23,8 @@ Design points, in the order they bit previous subsystems:
 - **Second-touch admission** (``hot_cache_admit="second_touch"``): the first
   epoch only *observes* (a block-granular touch ledger, bounded LRU), the
   second admits — one-shot scans never displace the hot set. Force-admit
-  (``"always"``) is the knob for known-repeating workloads and the warm/cold
-  bench arms; readahead always force-admits (warming IS the prediction).
+  (``"always"``) is the knob for known-repeating workloads; readahead
+  always force-admits (warming IS the prediction).
 - **Refcounted eviction.** Entries are pinned while anything reads them — a
   serve-memcpy, or a ``device_put`` sourced zero-copy from the cached slab
   (the full-hit fast path in ``memcpy_ssd2tpu``). Eviction under byte
@@ -63,10 +63,8 @@ from strom.utils.locks import make_lock
 
 ADMIT_POLICIES = ("second_touch", "always")
 
-# bench-JSON columns the warm/cold epoch phase pair emits (cli.py
-# _cache_epoch_phases), single-sourced so the driver's per-arm copy loop
-# (bench.py) and the compare_rounds "cache" section cannot drift from the
-# producer — the same contract STALL_FIELDS enforces for stall attribution
+# columns (warm/cold epoch pair) of the checked-in pre-chip benchmark
+# records that tools/compare_rounds.py reads for its "cache" section
 CACHE_BENCH_FIELDS = (
     "cold_images_per_s",
     "warm_images_per_s",
@@ -143,10 +141,8 @@ class HotCache:
         # zero source-engine reads. None = single-tier behavior unchanged.
         self.spill = None
         # phase gate: a disabled cache serves/admits/warms nothing (entries
-        # are kept). The bench arms use it to scope the cache to the
-        # cold/warm epoch pair so the pre-existing headline phases
-        # (flat-out img/s, train stalls, stall attribution) keep their
-        # round-over-round meaning; library contexts stay always-on.
+        # are kept). Tests use it to compare cached and uncached passes on
+        # one context; library contexts stay always-on.
         self.enabled = True
         self._lock = make_lock("cache.meta")
         # skey -> entries sorted by lo (disjoint ranges per skey)

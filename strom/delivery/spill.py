@@ -53,10 +53,8 @@ import numpy as np
 from strom.delivery.buffers import size_class
 from strom.utils.locks import make_lock
 
-# bench-JSON columns the spill epoch phase emits (cli.py bench_checkpoint's
-# spill pass), single-sourced so the driver's copy loop (bench.py) and the
-# compare_rounds "write path" section cannot drift from the producer — the
-# same contract CACHE_BENCH_FIELDS / CKPT_FIELDS enforce.
+# columns (spill epoch pass) of the checked-in pre-chip benchmark records
+# that tools/compare_rounds.py reads for its "write path" section.
 SPILL_FIELDS = (
     "spill_hit_bytes",
     "spill_hits",

@@ -50,10 +50,8 @@ from strom.engine.base import (DeadlineExceeded, EngineError,
 from strom.obs import request as _request
 from strom.obs.events import ring as _events_ring
 
-# bench-JSON columns the streaming arms emit (cli.py _stream_stats_delta),
-# single-sourced so the driver's per-arm copy loop (bench.py) and the
-# compare_rounds "streaming" section cannot drift from the producer — the
-# same contract STALL_FIELDS / CACHE_BENCH_FIELDS enforce.
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "streaming" section.
 STREAM_FIELDS = (
     "stream_batches",
     "stream_inflight_peak",

@@ -32,7 +32,7 @@ hashes from the single-process pipeline's row stream, so any divergence
 
 Run one worker: ``python -m strom.dist.launch --rank R --nproc N ...``;
 :func:`launch_local` spawns and joins all N; :func:`measure_ingest` is
-the one-call form the ``strom-bench dist`` arm and the dryrun tail use.
+the one-call form the dryrun tail uses.
 """
 
 from __future__ import annotations
@@ -339,7 +339,7 @@ def run_worker(args: argparse.Namespace) -> dict:
         dist = ctx.stats(sections=["dist"]).get("dist", {})
         if rank == 0 and ctx.cluster_view is not None:
             # one last scrape with every worker still alive, then fold
-            # the federation gauges into the result the bench arm reads
+            # the federation gauges into the result measure_ingest reports
             ctx.cluster_view.poll_now()
             result.update(ctx.cluster_view.stats())
         # per-host trace file: tools/trace_report.py merges these into one
@@ -458,9 +458,7 @@ def launch_local(nproc: int, data_dir: str, workdir: str, *,
 
 def make_fixture(data_dir: str, *, files: int = 4, records: int = 48,
                  seq_len: int = 16, seed: int = 7) -> list[str]:
-    """A small multi-file token fixture (plain ``tofile`` — jax-free;
-    the bench arm writes its fixture through the engine write path
-    instead)."""
+    """A small multi-file token fixture (plain ``tofile``, jax-free)."""
     os.makedirs(data_dir, exist_ok=True)
     rng = np.random.default_rng(seed)
     paths = []
@@ -483,10 +481,9 @@ def measure_ingest(procs: int, workdir: str, *, data_dir: "str | None" = None,
     """The whole acceptance in one call: launch *procs* workers, verify
     bit-identity against the single-process reference, fold the measured
     rates + peer traffic into the ``DIST_BENCH_FIELDS`` columns (the
-    ``strom-bench dist`` arm and the dryrun tail both ride this). With a
-    kill injection armed (*die_rank* >= 0) the acceptance covers the
-    SURVIVORS: each must exit clean and bit-identical — the dead rank is
-    expected to vanish."""
+    dryrun tail rides this). With a kill injection armed (*die_rank* >= 0)
+    the acceptance covers the SURVIVORS: each must exit clean and
+    bit-identical — the dead rank is expected to vanish."""
     if data_dir is None:
         data_dir = os.path.join(workdir, "data")
         make_fixture(data_dir, seq_len=seq_len)

@@ -144,11 +144,11 @@ DIST_FIELDS = (
     # (codec didn't pay / name unknown)
 ) + tuple(k for k in _COMP_FIELDS if k.startswith("peer_"))
 
-# bench-JSON columns the dist arm emits (cli.py bench_dist → bench.py copy
-# loop → compare_rounds "distributed" section; parity-tested like
-# CACHE_BENCH_FIELDS). dist_ok folds the whole acceptance into one bit:
-# every worker exited 0 AND every per-host batch stream was bit-identical
-# to the single-process pipeline's corresponding rows.
+# the columns strom.dist.launch.measure_ingest reports, and those of the
+# checked-in pre-chip benchmark records that tools/compare_rounds.py reads
+# for its "distributed" section. dist_ok folds the whole acceptance into
+# one bit: every worker exited 0 AND every per-host batch stream was
+# bit-identical to the single-process pipeline's corresponding rows.
 DIST_BENCH_FIELDS = (
     "dist_ok",
     "dist_procs",

@@ -45,8 +45,8 @@ from strom.utils.locks import make_lock
 
 # Counters the resilience layer feeds (single-sourced, same contract as
 # STALL_FIELDS / STREAM_FIELDS / SCHED_FIELDS): the ctx.stats()
-# ["resilience"] section, the per-arm bench columns (cli._resil_delta),
-# the compare_rounds "resilience" section and tools/lint_stats_names.py
+# ["resilience"] section, the compare_rounds "resilience" section over
+# the checked-in pre-chip benchmark records and tools/lint_stats_names.py
 # all read this tuple, so a restyled spelling cannot fork a column from
 # its producer.
 RESILIENCE_FIELDS = (
@@ -67,8 +67,8 @@ RESILIENCE_FIELDS = (
     "faults_injected",
 )
 
-# Chaos bench arm columns (cli.bench_chaos → bench.py copy loop →
-# compare_rounds "resilience" section; parity-tested like CACHE_BENCH_FIELDS)
+# chaos columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "resilience" section
 CHAOS_BENCH_FIELDS = (
     "chaos_ok",
     "chaos_slowdown",

@@ -4,10 +4,10 @@ A seeded, rule-based :class:`FaultPlan` decides — reproducibly — which
 engine ops fail and how (errno, short read, bit-flip corruption, latency
 spike, stuck completion, engine death), and :class:`FaultyEngine` is a
 full-API engine proxy that applies those decisions to any wrapped engine.
-Wired via ``StromConfig.fault_plan`` / ``--fault-plan`` so any bench arm
-or test runs under deterministic chaos; the resilience layer (engine
-retries, circuit breaker + failover, hedged reads) is soak-tested against
-exactly these plans.
+Wired via ``StromConfig.fault_plan`` (``STROM_FAULT_PLAN``) so any
+workload or test runs under deterministic chaos; the resilience layer
+(engine retries, circuit breaker + failover, hedged reads) is soak-tested
+against exactly these plans.
 """
 
 from strom.faults.plan import Fault, FaultPlan, FaultRule

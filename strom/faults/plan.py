@@ -8,10 +8,10 @@ draws, bit-flip positions) comes from the plan's RNG in op order, so the
 same seed over the same op sequence injects the SAME fault sequence —
 the determinism contract tests/test_faults.py pins.
 
-Plans load from three spellings (``FaultPlan.from_spec``, the
-``--fault-plan`` flag): a JSON file path, an inline JSON object string,
-or the named preset ``"chaos[:seed]"`` — the seeded EIO + short-read +
-latency-spike mix the chaos bench arm runs under.
+Plans load from three spellings (``FaultPlan.from_spec``,
+``StromConfig.fault_plan``): a JSON file path, an inline JSON object
+string, or the named preset ``"chaos[:seed]"`` — a seeded EIO +
+short-read + latency-spike mix.
 """
 
 from __future__ import annotations
@@ -245,7 +245,7 @@ class FaultPlan:
 
     @classmethod
     def chaos(cls, seed: int = 0) -> "FaultPlan":
-        """The chaos bench arm's preset: transient EIO + short reads +
+        """The chaos preset: transient EIO + short reads +
         latency spikes at rates the retry/hedge machinery must absorb
         with bit-identical output and bounded slowdown. No engine_death
         or stuck rules — those are for targeted tests, not a throughput

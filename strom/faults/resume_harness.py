@@ -28,8 +28,8 @@ is bit-identical to the reference from the restart step on, the restart
 step equals the committed token's serial (nothing skipped, nothing
 replayed beyond the un-checkpointed tail — NEVER from epoch start), the
 final train state matches the uninterrupted run's, and no orphaned tmp
-checkpoint survives. Wired as the ``strom-bench resume`` arm (cli.py) and
-tier-1 tests (tests/test_resume_harness.py); verdicts mirror onto
+checkpoint survives. Run by ``python -m strom.faults.resume_harness run``
+and the tier-1 tests (tests/test_resume_harness.py); verdicts mirror onto
 /metrics via ``set_resume_gauges``.
 """
 
@@ -230,8 +230,8 @@ def run_kill_resume(workdir: str, *, seed: int = 0, steps: "int | None" = None,
                     timeout_s: float = 300.0) -> dict:
     """One full kill→restart→verify cycle. Returns the RESUME_FIELDS
     verdict dict (plus diagnostics); never raises on a FAILED contract —
-    ``resume_ok=0`` with ``failures`` naming what broke (the bench arm
-    records it, tests assert on it). The kill step is a seeded draw
+    ``resume_ok=0`` with ``failures`` naming what broke (``main``'s exit
+    code follows it, tests assert on it). The kill step is a seeded draw
     strictly inside the first epoch, after at least one commit."""
     os.makedirs(workdir, exist_ok=True)
     ckdir = os.path.join(workdir, "ckpt")

@@ -97,8 +97,7 @@ class DecodedCache:
     @property
     def enabled(self) -> bool:
         """Follows the backing cache's phase gate: a disabled hot cache
-        serves/admits no decoded frames either (the bench arms scope both
-        to their epoch pairs through the one flag)."""
+        serves/admits no decoded frames either."""
         return self._hot_cache is not None and self._hot_cache.enabled
 
     def key(self, path: str, lo: int, hi: int) -> tuple:

@@ -42,8 +42,8 @@ Decode-path scheduling (ISSUE 2 tentpole):
   batch — one truncated JPEG in a million-sample epoch is data loss of one
   sample, not of the run.
 
-Decode path v2 (ISSUE 12 tentpole; knobs ``decode_native`` /
-``decode_fuse_runs`` / ``decode_roi`` / ``decode_cache``):
+Decode path v2 (the pipelines always take the native
+binding, fused runs and ROI decode; ``decode_cache`` is a config knob):
 
 - **Native turbo bindings**: :data:`decode_native` resolves lazily to a
   ctypes wrapper over ``sc_jpeg_decode`` in strom/_core (libjpeg-turbo,
@@ -97,10 +97,9 @@ if _HAVE_CV2:
     import cv2
 
 
-# bench-JSON columns the decode-v2 phase set emits (cli._decode2_phases),
-# single-sourced so the driver's per-arm copy loop (bench.py), the
-# compare_rounds "decode v2" section and the bench_sentinel gates cannot
-# drift from the producer — the same contract CACHE_BENCH_FIELDS enforces
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "decode v2" section and
+# tools/bench_sentinel.py gates on
 DECODE2_FIELDS = (
     "decode_native_img_per_s",
     "decode_cv2_img_per_s",

@@ -94,18 +94,3 @@ class TokenShardSet:
 
     def batch_shape(self, n_records: int) -> tuple[int, int]:
         return (n_records, self.record_tokens)
-
-
-def write_token_shard(ctx, path: str, tokens: np.ndarray, *,
-                      tenant: "str | None" = None,
-                      fsync: bool = True) -> int:
-    """Write a packed-token shard through the ENGINE write path (ISSUE 13
-    front 4): the bench fixtures are generated by the same
-    O_DIRECT/scheduler-granted machinery that later reads them, so fixture
-    generation exercises — and is bounded by — the write path under test,
-    instead of side-stepping it with ``tofile``. *tokens* is any integer
-    array; it lands row-major exactly as :class:`TokenShardSet` slices it.
-    Returns bytes written."""
-    arr = np.ascontiguousarray(tokens)
-    return ctx.pwrite(path, arr.reshape(-1).view(np.uint8),
-                      tenant=tenant, fsync=fsync)

@@ -27,6 +27,8 @@ from strom.models.llama import attention
 
 @dataclasses.dataclass(frozen=True)
 class ViTConfig:
+    """Defaults are ViT-B/16 at 224."""
+
     image_size: int = 224
     patch: int = 16
     d_model: int = 768
@@ -48,10 +50,6 @@ class ViTConfig:
     @property
     def jdtype(self):
         return jnp.dtype(self.dtype)
-
-    @classmethod
-    def vit_b16(cls) -> "ViTConfig":
-        return cls()
 
     @classmethod
     def tiny(cls) -> "ViTConfig":

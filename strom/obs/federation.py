@@ -44,9 +44,9 @@ from typing import Callable, Mapping
 from strom.utils.locks import make_lock
 from strom.utils.stats import _Histogram, global_stats
 
-# the bench-JSON cluster columns, single-sourced so the dist bench arm,
-# bench.py's copy list, compare_rounds' report section and the parity test
-# cannot drift apart (same contract as DIST_FIELDS / STALL_FIELDS)
+# the cluster columns strom.dist.launch.measure_ingest reports, and those
+# of the checked-in pre-chip benchmark records that tools/compare_rounds.py
+# reads
 FED_FIELDS = (
     "cluster_hosts",
     "cluster_hosts_unhealthy",
@@ -263,7 +263,7 @@ class ClusterView:
         }
 
     def stats(self) -> dict:
-        """The FED_FIELDS dict (the dist bench arm's copy source)."""
+        """The FED_FIELDS dict (measure_ingest copies it)."""
         with self._lock:
             return self._fields_locked()
 

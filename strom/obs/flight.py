@@ -16,7 +16,7 @@ black box for that case, sized so it can stay on for every run:
   per-thread Python stacks (``sys._current_frames``), and the last-N
   flight samples. The bundle is written to a temp dir and ``os.rename``d
   into place, so a half-written bundle can never masquerade as a whole
-  one (the same atomicity contract bench.py's partial-JSON flush has).
+  one.
 - The live server's ``/flight`` route captures the same bundle on demand
   from a running process — "jstack for the data plane".
 
@@ -165,8 +165,7 @@ def _write_bundle(flight_dir: str, cap: dict, reason: str,
     """Write one capture document as an atomic bundle dir under
     *flight_dir* and return its path. Contents land in a ``.tmp-`` dir
     first and rename into place LAST, so readers never see a partial
-    bundle (the same atomicity contract bench.py's partial-JSON flush
-    has). Shared by :meth:`FlightRecorder.dump` and the recorder-less
+    bundle. Shared by :meth:`FlightRecorder.dump` and the recorder-less
     :func:`dump_capture` (the lock-order witness's cycle dump)."""
     name = f"flight-{os.getpid()}-{reason}-{serial:03d}"
     final = os.path.join(flight_dir, name)

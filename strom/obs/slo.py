@@ -42,8 +42,8 @@ SLO_FIELDS = (
     "slo_burning",
 )
 
-# per-arm bench columns (cli vision arms emit, bench.py copies,
-# compare_rounds' "request latency / SLO" section reads — parity-tested)
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "request latency / SLO" section
 SLO_BENCH_FIELDS = (
     "req_lat_p50_us",
     "req_lat_p99_us",

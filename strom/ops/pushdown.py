@@ -53,10 +53,9 @@ PUSHDOWN_FIELDS = (
     "parquet_pushdown_rows_masked",
 )
 
-# single-sourced bench-artifact columns for the near-data A/B pair: the cli
-# pushdown arm (pushed-vs-unpushed parquet scan) and the dist arm's
-# compressed-vs-raw wire pass produce them, bench.py copies them, and
-# compare_rounds' "pushdown" section renders them (parity-tested both ways)
+# columns of the checked-in pre-chip benchmark records that
+# tools/compare_rounds.py reads for its "pushdown" section (the near-data
+# A/B pair: pushed-vs-unpushed parquet scan, compressed-vs-raw wire)
 PUSHDOWN_BENCH_FIELDS = (
     "pushdown_ok",
     "parquet_pushdown_rows_per_s",

@@ -237,11 +237,6 @@ class Pipeline:
         return self._decode_pool.decode_errors \
             if self._decode_pool is not None else 0
 
-    @property
-    def prefetch_depth_trace(self) -> list[tuple[int, int]]:
-        """(step, depth) at every controller move, starting depth included."""
-        return list(self._prefetcher.depth_trace)
-
     def straggler_report(self, threshold: float = 1.25):
         """Cross-host step-time skew (collective: every process must call)."""
         return self.monitor.report(threshold)

@@ -488,9 +488,6 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
                              decode_reduced_scale: bool | None = None,
                              decode_to_slot: bool | None = None,
                              decode_overlap_put: bool | None = None,
-                             decode_native: bool | None = None,
-                             decode_fuse_runs: bool | None = None,
-                             decode_roi: bool | None = None,
                              decode_cache: bool | None = None,
                              opgraph: Any = None,
                              opgraph_fuse: bool | None = None,
@@ -543,12 +540,9 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
     to_slot = cfg.decode_to_slot if decode_to_slot is None else decode_to_slot
     overlap_put = cfg.decode_overlap_put if decode_overlap_put is None \
         else decode_overlap_put
-    # decode path v2 knobs (ISSUE 12): native turbo binding, fused-run
-    # dispatch, ROI/partial-MCU decode, decoded-output cache
-    native = cfg.decode_native if decode_native is None else decode_native
-    fuse = cfg.decode_fuse_runs if decode_fuse_runs is None \
-        else decode_fuse_runs
-    use_roi = cfg.decode_roi if decode_roi is None else decode_roi
+    # decode path v2: the native turbo binding (cv2 where it did
+    # not build), fused-run dispatch and ROI decode are always on; the
+    # decoded-output cache is a knob
     use_dcache = cfg.decode_cache if decode_cache is None else decode_cache
     pscope = ctx.scope.scoped(**(scope if scope is not None
                                  else {"pipeline": "vision"}))
@@ -566,7 +560,7 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
         from strom.formats.decoded_cache import DecodedCache
         from strom.formats import jpeg as _jpeg
 
-        eng = "turbo" if (native and _jpeg.native_available()) else "cv2"
+        eng = "turbo" if _jpeg.native_available() else "cv2"
         dcache = DecodedCache(ctx.hot_cache, tenant=tname,
                               fingerprint=f"rgb8/{eng}", scope=pscope)
         # peer fabric v2 (ISSUE 20): register the cache so this host's
@@ -574,7 +568,6 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
         # below can pull frames a PEER already decoded
         ctx.attach_decoded_cache(dcache)
     tf = transform or make_train_transform(image_size, reduced_scale=reduced,
-                                           native=native, roi=use_roi,
                                            dcache=dcache)
     try:
         tf_out_ok = "out" in inspect.signature(tf).parameters
@@ -625,7 +618,7 @@ def make_wds_vision_pipeline(ctx: StromContext, paths: Sequence[str], *,
     # the built-in transform decodes in worker processes (the pool sees
     # tf.spec): one shared slab per batch being built or still landing on
     # the device, so the ring is one deeper than the batches in flight
-    pool = DecodePool(decode_workers, fuse_runs=fuse, transform=tf,
+    pool = DecodePool(decode_workers, transform=tf,
                       slabs=(max_depth if auto else depth) + 1)
     ctx.register_tunable("decode_pool", pool)
     shared = to_slot and pool.offloads(tf)

@@ -65,11 +65,9 @@ from strom.utils.locks import make_condition
 from strom.sched.budget import AdmissionGate
 from strom.sched.tenant import PRIORITIES, PRIORITY_ORDER, Tenant
 
-# bench-JSON column suffixes the multitenant bench arm emits per tenant
-# (cli.py bench_multitenant, prefixed mt_<tenant>_), single-sourced so the
-# driver's copy loop (bench.py) and the compare_rounds "multi-tenant"
-# section cannot drift from the producer — the same contract STALL_FIELDS /
-# CACHE_BENCH_FIELDS / STREAM_FIELDS enforce.
+# per-tenant column suffixes (prefixed mt_<tenant>_) of the checked-in
+# pre-chip benchmark records that tools/compare_rounds.py reads for its
+# "multi-tenant" section.
 SCHED_FIELDS = (
     "items_per_s",
     "vs_solo",

@@ -12,8 +12,8 @@ objective (goodput / items-per-second), with two safety invariants:
   in-flight trial and proposes nothing — it never experiments on a
   workload that is already missing its target.
 
-Profiles (the converged knob values) persist as JSON per bench arm
-(``--profile`` on the cli) so a tuned workload starts where the last run
+Profiles (the converged knob values) persist as JSON per workload
+(``strom-bench daemon --tune --profile``) so a tuned workload starts where the last run
 ended instead of re-searching from the hand defaults.
 """
 

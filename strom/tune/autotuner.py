@@ -2,7 +2,7 @@
 
 One :class:`Autotuner` owns a set of :class:`~strom.tune.knobs.Knob`
 surfaces and a ``metrics_fn`` returning the live objective (higher is
-better — goodput_pct for a training context, items/s for a bench arm) plus
+better — goodput_pct for a training context, items/s for a read loop) plus
 the SLO-burn flag. ``step()`` advances a two-beat state machine:
 
 - **propose**: pick the next knob round-robin and move it one step in its
@@ -22,8 +22,8 @@ Safety invariants (tested on a fake clock in tests/test_tune.py):
   and proposes nothing (``tune_holds`` counts these) — it never
   experiments on a tenant that is already missing its target.
 
-The driver thread (``start()``) is optional; tests and the bench arms
-call ``step()`` directly.
+The background thread (``start()``) is optional; tests call ``step()``
+directly.
 """
 
 from __future__ import annotations
@@ -54,10 +54,9 @@ TUNE_FIELDS = (
     "tuned_vs_baseline",
 )
 
-# bench-JSON columns the tune arm (cli.py bench_tune) and the nvme arm's
-# SQPOLL A/B emit — the compare_rounds "kernel bypass & autotune" section
-# and the bench_sentinel gates (tuned_vs_hand up, sqpoll syscalls/GB down)
-# read these names; same single-sourcing contract as CACHE_BENCH_FIELDS
+# columns (hand vs tuned knobs, SQPOLL A/B) of the checked-in pre-chip
+# benchmark records that tools/compare_rounds.py reads for its "kernel
+# bypass & autotune" section and tools/bench_sentinel.py gates on
 TUNE_BENCH_FIELDS = (
     "hand_items_per_s",
     "tuned_items_per_s",
@@ -107,7 +106,7 @@ def stall_weighted_metrics(base_fn: Callable[[], dict], *,
 @dataclasses.dataclass
 class Profile:
     """A persisted knob assignment: what the tuner converged to for one
-    workload (bench arm), reloadable so the next run starts there."""
+    workload, reloadable so the next run starts there."""
 
     name: str
     knobs: dict[str, float]
@@ -279,8 +278,8 @@ class Autotuner:
 
     def settle(self) -> str:
         """Evaluate the in-flight trial (if any) against the current
-        objective WITHOUT proposing a new one — the terminal beat for
-        bench arms, which must measure the converged state, not a
+        objective WITHOUT proposing a new one — the terminal beat before
+        a profile is saved, which must hold the converged state, not a
         half-evaluated experiment. Returns ``"accept"``, ``"revert"``
         or ``"idle"``."""
         m = self.metrics_fn() or {}

@@ -1,7 +1,7 @@
 """Where JAX's persistent compilation cache lives, for every entry point.
 
-``chip_smoke.py``, ``bench.py`` and the ``strom.cli`` entry call
-:func:`enable_compile_cache` before their first compile. When
+``chip_smoke.py`` calls :func:`enable_compile_cache` before its first
+compile. When
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and nothing is set
 in code. Otherwise the cache goes to a fixed directory inside the checkout
 ``strom`` was imported from (listed in its ``.gitignore``): the path is part

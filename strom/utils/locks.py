@@ -30,9 +30,7 @@ the hot paths never pay for a feature they aren't using. Locks created
 BEFORE the witness is enabled stay plain — enable via the env var (covers
 module-level locks created at import) or ``StromConfig.debug_locks``
 (enabled first thing in ``StromContext.__init__``, before the engine and
-every subsystem lock is constructed). The chaos bench arm runs with the
-witness on, so the seeded-fault op stream cross-validates the static
-hierarchy every round.
+every subsystem lock is constructed).
 """
 
 from __future__ import annotations

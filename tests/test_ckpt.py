@@ -170,8 +170,9 @@ class TestPickleBaseline:
 
 
 def test_ckpt_fields_single_sourced():
-    """CKPT_FIELDS names must be exactly what the bench arm emits (the
-    lint_stats_names *_FIELDS scan rides the literal)."""
+    """CKPT_FIELDS names the write-path columns of the checked-in records
+    without duplicates (the lint_stats_names *_FIELDS scan rides the
+    literal)."""
     from strom.ckpt.checkpoint import CKPT_FIELDS
 
     assert "ckpt_save_mb_per_s" in CKPT_FIELDS

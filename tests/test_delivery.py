@@ -77,6 +77,25 @@ def test_async_handle(ctx, data_file):
     np.testing.assert_array_equal(np.asarray(arr), data[: 1024 * 1024])
 
 
+def test_async_handle_freed_without_cyclic_gc(ctx, data_file):
+    """A finished handle and its array go when the last reference does:
+    nothing the handle registered on its future points back at it, so
+    the cyclic GC is not needed to free a delivered array."""
+    import gc
+    import weakref
+
+    path, _ = data_file
+    gc.disable()
+    try:
+        h = ctx.memcpy_ssd2tpu(path, length=65536, async_=True)
+        h.result(timeout=30)
+        ref = weakref.ref(h)
+        del h
+        assert ref() is None
+    finally:
+        gc.enable()
+
+
 def test_async_many_in_flight(ctx, data_file):
     path, data = data_file
     handles = [ctx.memcpy_ssd2tpu(path, offset=i * 65536, length=65536, async_=True)
@@ -139,7 +158,7 @@ def test_ssd2host_plain(ctx, data_file):
 
 def test_ssd2host_out_buffer(ctx, data_file):
     """out=: the caller's preallocated (registrable) dest IS the returned
-    array — zero-copy all the way, like the raw bench arm."""
+    array — zero-copy all the way."""
     from strom.delivery.buffers import alloc_aligned, buf_addr
 
     path, data = data_file
@@ -157,60 +176,24 @@ def test_ssd2host_out_buffer(ctx, data_file):
         ctx.memcpy_ssd2host(path, length=n, out=alloc_aligned(2 * n)[::2])
 
 
-def test_bench_ssd2host_smoke(tmp_path, rng, engine_name):
-    """The strom-bench ssd2host subcommand's phase function: both arms run,
-    the ratio is finite, and the fields bench.py consumes are present."""
-    import argparse
+def test_ssd2host_through_striped_alias_is_byte_exact(tmp_path, rng,
+                                                      engine_name):
+    """memcpy_ssd2host reads a whole file striped RAID0-style over four
+    members back through the striped alias, byte for byte."""
+    from strom.config import StromConfig
+    from strom.delivery.core import StromContext
+    from strom.engine.raid0 import stripe_file
 
-    from strom.cli import bench_ssd2host
-
-    n = 4 << 20
-    data = rng.integers(0, 256, n, dtype=np.uint8)
-    p = tmp_path / "ratio.bin"
-    data.tofile(p)
-    res = bench_ssd2host(argparse.Namespace(
-        file=str(p), size=n, block=128 * 1024, depth=8, iters=2,
-        engine=engine_name, tmpdir=str(tmp_path), json=True))
-    assert res["bench"] == "ssd2host" and res["bytes"] == n
-    assert res["raw_gbps"] > 0 and res["host_gbps"] > 0
-    assert res["vs_raw"] > 0 and res["passes"] == 2
-    # per-pass audit arrays: one entry per pass, best == max (VERDICT.md
-    # r4 next #3)
-    assert len(res["raw_gbps_passes"]) == 2
-    assert len(res["host_gbps_passes"]) == 2
-    assert res["raw_gbps"] == max(res["raw_gbps_passes"])
-    assert res["host_gbps"] == max(res["host_gbps_passes"])
-
-
-def test_bench_ssd2host_raid_smoke(tmp_path, rng, engine_name):
-    """--raid: the framework arm reads the whole logical file through the
-    striped alias byte-exactly (checked via memcpy_ssd2host against the
-    source), and the phase reports the striped-shape fields."""
-    import argparse
-
-    from strom.cli import bench_ssd2host
-
-    n = 4 << 20
+    n = 4 << 20  # a multiple of the 256KiB stripe
     data = rng.integers(0, 256, n, dtype=np.uint8)
     p = tmp_path / "ratio_raid.bin"
     data.tofile(p)
     chunk = 64 * 1024
-    res = bench_ssd2host(argparse.Namespace(
-        file=str(p), size=n, block=128 * 1024, depth=8, iters=2,
-        engine=engine_name, tmpdir=str(tmp_path), json=True,
-        raid=4, raid_chunk=chunk))
-    assert res["raid_members"] == 4
-    assert res["bytes"] == n  # 4MiB is a multiple of the 256KiB stripe
-    assert res["raw_gbps"] > 0 and res["host_gbps"] > 0 and res["vs_raw"] > 0
-    # integrity: the striped-alias host path must return the source bytes
-    # (the bench arms only time; this is the correctness side)
-    from strom.config import StromConfig
-    from strom.delivery.core import StromContext
-
+    members = [f"{p}.r{i}of4.c{chunk}" for i in range(4)]
+    assert stripe_file(str(p), members, chunk) == n
     ctx = StromContext(StromConfig(engine=engine_name, queue_depth=8,
                                    num_buffers=8))
     try:
-        members = [f"{p}.r{i}of4.c{chunk}" for i in range(4)]
         virt = str(tmp_path / "ratio.raid0")
         ctx.register_striped(virt, members, chunk, size=n)
         got = ctx.memcpy_ssd2host(virt, length=n)

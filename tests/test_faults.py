@@ -739,9 +739,9 @@ class TestResilienceSurfaces:
             ctx.close()
 
     def test_chaos_fields_match_cli_arm_keys(self):
-        """CHAOS_BENCH_FIELDS (the producer tuple cli.bench_chaos emits)
-        and the compare_rounds resilience section must agree — a rename on
-        either side is a silently dead column."""
+        """CHAOS_BENCH_FIELDS (the chaos columns of the checked-in
+        records) and the compare_rounds resilience section must agree — a
+        rename on either side is a silently dead column."""
         import tools.compare_rounds as cr
 
         assert list(CHAOS_BENCH_FIELDS) == list(cr.RESIL_KEYS)

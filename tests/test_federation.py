@@ -368,8 +368,8 @@ def test_two_proc_merged_trace_links_hosts(tmp_path):
 
 
 def test_fed_fields_lift_into_measure_ingest(tmp_path):
-    """measure_ingest folds rank 0's federation gauges into the bench
-    columns (the dist arm's copy source)."""
+    """measure_ingest folds rank 0's federation gauges into its result
+    columns."""
     from strom.dist.launch import measure_ingest
 
     res = measure_ingest(2, str(tmp_path), steps=3, batch=8, seq_len=16)

@@ -137,8 +137,8 @@ class TestBundle:
 
 class TestTriggers:
     def test_sigterm_dumps_and_chains(self, tmp_path):
-        """Child installs a prior SIGTERM handler (the bench.py emergency
-        flush shape), then the recorder; a SIGTERM must dump the bundle
+        """Child installs a prior SIGTERM handler (a program's own
+        emergency flush), then the recorder; a SIGTERM must dump the bundle
         AND still run the prior handler."""
         child = tmp_path / "child.py"
         fdir = tmp_path / "fl"

@@ -14,7 +14,7 @@ import pytest
 from strom.config import StromConfig
 from strom.delivery.core import StromContext
 from strom.delivery.extents import ExtentList
-from strom.delivery.hotcache import CACHE_BENCH_FIELDS, HotCache, Readahead
+from strom.delivery.hotcache import HotCache, Readahead
 from strom.delivery.shard import Segment
 
 KiB = 1024
@@ -521,18 +521,3 @@ def test_sampler_peek_is_epoch_aware():
     # the epoch-0 permutation covered all records exactly once
     seen = np.sort(np.concatenate([first] + upcoming[:2]))
     np.testing.assert_array_equal(seen, np.arange(12))
-
-
-def test_cache_bench_fields_match_producer():
-    """The driver's per-arm copy loop and compare_rounds consume exactly the
-    keys cli._cache_epoch_phases produces (the CACHE_BENCH_FIELDS
-    single-source contract — see also tests/test_compare_rounds.py)."""
-    import inspect
-
-    from strom.cli import _cache_epoch_phases
-
-    src = inspect.getsource(_cache_epoch_phases)
-    for key in CACHE_BENCH_FIELDS:
-        assert f'"{key}"' in src, \
-            f"CACHE_BENCH_FIELDS names {key!r} but _cache_epoch_phases " \
-            "does not produce it"

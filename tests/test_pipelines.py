@@ -468,52 +468,6 @@ class TestParquetScan:
         assert snap1.get("parquet_decode_bytes", 0) \
             == snap0.get("parquet_decode_bytes", 0)
 
-    def test_bench_parquet_plain_disk_rate_smoke(self, tmp_path):
-        """strom-bench parquet --compression none --disk-rate: the plain
-        arm's artifact fields exist and cohere (vs_disk = best scan / best
-        bare gather of the same extents; per-pass lists recorded)."""
-        import argparse
-
-        from strom.cli import bench_parquet
-
-        out = bench_parquet(argparse.Namespace(
-            file=None, size=0, block=4096, depth=8, iters=1,
-            engine="python", tmpdir=str(tmp_path), json=True,
-            rows=20_000, row_groups=4, prefetch=2, unit_batch=1,
-            raid=0, raid_chunk=512 * 1024, columns=4,
-            compression="none", dtype="float32", disk_rate=True,
-            cpu_device=True))
-        assert out["compression"] == "none"
-        assert out["plain_decoded_bytes"] > 0
-        assert out["pyarrow_decoded_bytes"] == 0
-        assert len(out["selected_gbps_passes"]) == 2
-        assert len(out["disk_gbps_passes"]) == 2
-        assert out["disk_read_gbps"] == max(out["disk_gbps_passes"])
-        assert out["vs_disk"] == pytest.approx(
-            max(out["selected_gbps_passes"]) / out["disk_read_gbps"],
-            rel=1e-2)
-
-    def test_bench_parquet_raid_disk_rate_smoke(self, tmp_path):
-        """--raid + --disk-rate: the bare-gather yardstick expands logical
-        extents to member ops (the bench does the stripe math, the engine
-        reads member ranges) — the striped scan gets a vs_disk too, with
-        the scan's own hit count proving the data path."""
-        import argparse
-
-        from strom.cli import bench_parquet
-
-        out = bench_parquet(argparse.Namespace(
-            file=None, size=0, block=4096, depth=8, iters=1,
-            engine="python", tmpdir=str(tmp_path), json=True,
-            rows=20_000, row_groups=4, prefetch=2, unit_batch=2,
-            raid=2, raid_chunk=64 * 1024, columns=4,
-            compression="none", dtype="float32", disk_rate=True,
-            cpu_device=True))
-        assert out["raid_members"] == 2
-        assert out["vs_disk"] is not None and out["vs_disk"] > 0
-        assert len(out["disk_gbps_passes"]) == 2
-        assert out["plain_decoded_bytes"] > 0  # striped + direct decode
-
     def test_decode_path_counters_in_prometheus(self, ctx, tmp_path):
         """The decode-path counters are observability surface (≙ the
         reference's /proc counters): after a scan they must appear in the

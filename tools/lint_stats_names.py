@@ -66,14 +66,14 @@ def _is_metric_receiver(recv: "str | None") -> bool:
 # STREAM_FIELDS, FLIGHT_FIELDS, SENTINEL_FIELDS, SCHED_FIELDS,
 # DIST_FIELDS/DIST_BENCH_FIELDS (strom/dist/peers.py, ISSUE 15),
 # FED_FIELDS (strom/obs/federation.py, ISSUE 18), the
-# compare_rounds *_KEYS column lists, cli _DECODE_COUNTERS, ...): their
+# compare_rounds *_KEYS column lists, ...): their
 # literals name the SAME series the producers feed, so a restyled
 # spelling here forks a dashboard column exactly like a restyled call
 # site (ISSUE 4 satellite: bench/report columns are linted tier-1)
 _FIELDS_NAME = re.compile(r"^_?[A-Z][A-Z0-9_]*_(?:FIELDS|KEYS|COUNTERS)$")
 
 # source roots that feed the global registry
-DEFAULT_ROOTS = ("strom", "tools", "bench.py")
+DEFAULT_ROOTS = ("strom", "tools")
 
 # HTTP route literals in the live server's handlers: `path == "/metrics"`
 # comparisons inside strom/obs/server.py. Every one must be documented in
@@ -136,8 +136,13 @@ def scan_routes(root_dir: str) -> tuple[set[str], list[str]]:
 
 
 def _dict_keys(node: "ast.AST | None") -> list[str]:
-    """String keys of a dict LITERAL (else nothing — dynamic dicts can't
-    be linted)."""
+    """String keys of a dict LITERAL, or of the literals in either branch
+    of ``a if c else {...}`` / ``a or {...}`` (the pipelines' default
+    scopes); dynamic dicts can't be linted."""
+    if isinstance(node, ast.IfExp):
+        return _dict_keys(node.body) + _dict_keys(node.orelse)
+    if isinstance(node, ast.BoolOp):
+        return [k for v in node.values for k in _dict_keys(v)]
     if not isinstance(node, ast.Dict):
         return []
     return [k.value for k in node.keys

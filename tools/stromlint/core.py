@@ -38,7 +38,7 @@ RULES = (
 # source roots stromlint audits (tests are exercised separately via
 # explicit paths; fixture modules under tests/lint_fixtures must never
 # count against the tree)
-DEFAULT_ROOTS = ("strom", "tools", "bench.py")
+DEFAULT_ROOTS = ("strom", "tools")
 
 _PRAGMA_RE = re.compile(
     r"#\s*stromlint:\s*ignore\[([a-zA-Z0-9_,\- ]+)\]\s*(?:--\s*(\S.*))?")

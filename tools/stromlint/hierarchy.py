@@ -42,7 +42,6 @@ LOCK_RANKS = {
     "app.demand": 7,           # demand-read gate counter
     "app.put": 8,              # serialize_device_put
     "app.prefetch": 9,         # Prefetcher queue state
-    "app.handle": 10,          # DMAHandle completion stamp
     "app.vision_futs": 11,     # streamed-batch decode future list
     "app.jpeg_errs": 12,       # DecodePool error tally
     "app.parquet_footer": 13,  # footer read-once (takes engine reads)

@@ -308,8 +308,8 @@ def _report(events: list[dict], *, steps: bool, requests: int = 10) -> None:
                       f"{s.decode_us / 1e3:>9.2f}{s.put_us / 1e3:>9.2f}"
                       f"{s.read_us / 1e3:>9.2f}{s.compute_us / 1e3:>10.2f}")
         else:
-            print("\n(no step windows in trace: run a --train-step bench, "
-                  "or consume a pipeline, to get stall attribution)")
+            print("\n(no step windows in trace: run a training loop over "
+                  "a pipeline to get stall attribution)")
 
     if requests:
         reqs = request_rollup(events, top=requests)
