@@ -15,11 +15,15 @@ expert-parallel rank holds it.
   ``n_experts``, softmax, greedy top-k without renormalisation; the rank
   holds experts ``held_lo … held_lo + n_held - 1`` and computes their part
   of the result for every (token, held expert) pair: the pairs are sorted by
-  expert and run through ``lax.ragged_dot``, so no pair is dropped and none
-  is padded to a capacity. The shared experts (one SwiGLU of width
-  ``n_shared × expert_ff``) run on every token once. What experts held on
-  other ranks would add is left out: on one chip the layer has no exchange.
-  The layer returns how many pairs each held expert computed.
+  expert, held ones first, and run through ``lax.ragged_dot``, so no pair is
+  dropped and none is padded to a capacity. The sorted list is cut into
+  chunks of :func:`chunk_rows` rows, and only the chunks up to the last held
+  pair are gathered, computed and scattered back, forward and backward: the
+  work follows the pairs this rank holds, not all ``top_k`` picks of every
+  token. The shared experts (one SwiGLU of width ``n_shared × expert_ff``)
+  run on every token once. What experts held on other ranks would add is
+  left out: on one chip the layer has no exchange. The layer returns how
+  many pairs each held expert computed and how many chunks it ran.
 - **Loss.** Next-token cross-entropy over the held vocabulary rows, in
   token chunks so that only one chunk's logits exist at a time, plus
   ``aux_alpha`` × the sequence-wise balance loss of every expert layer,
@@ -38,6 +42,7 @@ layer's low-precision copy is alive at a time.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -256,58 +261,194 @@ def route(h, router, cfg: DeepseekV2Config, batch: int):
     return idx, weight, aux
 
 
+def chunk_rows(pairs: int, cfg: DeepseekV2Config) -> int:
+    """Rows per chunk of the sorted pair list: the power of two nearest to
+    a sixth of the held pairs a uniform router gives (*pairs* × n_held /
+    n_experts), at most *pairs*; all *pairs* in one chunk where every
+    expert is held, since then no chunk can be skipped and each would only
+    add its fixed cost (its own float32 gradient of every held expert's
+    weights). 4,096 at DeepSeek-V2-Lite's 8 of 64 held and 32,768 tokens;
+    16 at the tiny config's 288 pairs with 4 of 16 held."""
+    if cfg.n_held == cfg.n_experts:
+        return pairs
+    share = pairs * cfg.n_held / cfg.n_experts / 6
+    return min(1 << round(math.log2(max(share, 1.0))), pairs)
+
+
+def _grouped(x, w, sizes, valid):
+    # The TPU's grouped product leaves the rows past the last group
+    # unwritten, in its output and in its input's gradient: device memory
+    # as it was, NaN at times. Every product's input and output pass
+    # through a select, so that nothing from those rows reaches a value or,
+    # through the selects' transposes, a gradient (a multiply by zero would
+    # keep a NaN).
+    x = jnp.where(valid, x, 0)
+    return jnp.where(valid, lax.ragged_dot(x, w, sizes), 0)
+
+
+def _swiglu_gate(g, u, scale):
+    # the gate weight *scale* multiplies the input of the (linear) down
+    # projection
+    return jax.nn.silu(g) * u * scale[:, None].astype(g.dtype)
+
+
+def _expert_ffn(rows, scale, w_gate, w_up, w_down, sizes, valid):
+    """Each row's SwiGLU through its group's expert, times its gate
+    weight."""
+    act = _swiglu_gate(_grouped(rows, w_gate, sizes, valid),
+                       _grouped(rows, w_up, sizes, valid), scale)
+    return _grouped(act, w_down, sizes, valid)
+
+
+# per group g: x[rows of g]ᵀ · dy[rows of g], the grouped product's weight
+# gradient ([groups, x's width, dy's width])
+_PER_GROUP = lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(([0], [0]), ([], [])),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _expert_ffn_vjp(rows, scale, w, w_t, sizes, valid, dout):
+    """:func:`_expert_ffn` recomputed and pulled back by hand: the rows' and
+    gate weights' gradients, and the three weights' in float32 (*w*: gate,
+    up, down; *w_t*: the same with their last two axes swapped, made once
+    outside the chunk loop, so that the rows' gradient products read a
+    weight in the layout they need without copying it every chunk)."""
+    def grouped(x, w_):
+        return jnp.where(valid, lax.ragged_dot(x, w_, sizes), 0)
+
+    def per_group(x, dy):
+        return lax.ragged_dot_general(x, dy, sizes, _PER_GROUP,
+                                      preferred_element_type=jnp.float32)
+
+    x = jnp.where(valid, rows, 0)
+    act, pull = jax.vjp(_swiglu_gate, grouped(x, w[0]), grouped(x, w[1]),
+                        scale)
+    dout = jnp.where(valid, dout, 0)
+    dg, du, dscale = pull(grouped(dout, w_t[2]))
+    dg, du = jnp.where(valid, dg, 0), jnp.where(valid, du, 0)
+    drows = jnp.where(valid, lax.ragged_dot(dg, w_t[0], sizes)
+                      + lax.ragged_dot(du, w_t[1], sizes), 0)
+    return drows, dscale, (per_group(x, dg), per_group(x, du),
+                           per_group(jnp.where(valid, act, 0), dout))
+
+
+def _chunk(c, order, sizes, k: int, C: int):
+    """Chunk *c* of the sorted pair list: its pair ids [C], their tokens,
+    each group's rows inside the chunk [n_held], and which rows lie inside
+    a group [C, 1] (only the chunk that holds the last held pair has rows
+    past it)."""
+    c0 = c * C
+    pairs = lax.dynamic_slice_in_dim(order, c0, C)
+    ends = jnp.cumsum(sizes)
+    local = jnp.clip(ends, c0, c0 + C) - jnp.clip(ends - sizes, c0, c0 + C)
+    valid = (c0 + jnp.arange(C) < ends[-1])[:, None]
+    return pairs, pairs // k, local, valid
+
+
+def _live_chunks(sizes, C: int):
+    return (jnp.sum(sizes) + C - 1) // C
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _dispatch(h, weight, w_gate, w_up, w_down, order, sizes, k, C):
+    return _dispatch_fwd(h, weight, w_gate, w_up, w_down, order, sizes, k,
+                         C)[0]
+
+
+def _dispatch_fwd(h, weight, w_gate, w_up, w_down, order, sizes, k, C):
+    """The live chunks' outputs scatter-added by token into a float32
+    [N, d]; chunks past the last held pair are never entered."""
+    with jax.named_scope("moe.experts"):
+        def body(c, y):
+            pairs, tok, local, valid = _chunk(c, order, sizes, k, C)
+            out = _expert_ffn(h[tok], weight[pairs], w_gate, w_up, w_down,
+                              local, valid)
+            return y.at[tok].add(out.astype(jnp.float32))
+
+        y = lax.fori_loop(0, _live_chunks(sizes, C), body,
+                          jnp.zeros(h.shape, jnp.float32))
+    return y, (h, weight, w_gate, w_up, w_down, order, sizes)
+
+
+def _dispatch_bwd(k, C, res, dy):
+    """The live chunks again, each recomputed and pulled back: the rows'
+    gradient scatter-added by token, and the held weights' summed over the
+    chunks, both in float32. A chunk adds to the weights' gradient only the
+    experts it holds rows of (a group's gradient with no rows is not read:
+    the grouped product need not write it), so a chunk costs its rows and
+    its experts, not every held expert."""
+    h, weight, w_gate, w_up, w_down, order, sizes = res
+    ends = jnp.cumsum(sizes)
+    with jax.named_scope("moe.experts"):
+        w = (w_gate, w_up, w_down)
+        w_t = tuple(jnp.swapaxes(a, 1, 2) for a in w)
+
+        def body(c, acc):
+            dh, dweight, *dw = acc
+            pairs, tok, local, valid = _chunk(c, order, sizes, k, C)
+            drows, dscale, dchunk = _expert_ffn_vjp(
+                h[tok], weight[pairs], w, w_t, local, valid,
+                dy[tok].astype(h.dtype))
+
+            def add(g, dw):
+                return tuple(lax.dynamic_update_index_in_dim(
+                    a, a[g] + jnp.where(local[g] > 0, b[g], 0), g, 0)
+                    for a, b in zip(dw, dchunk))
+
+            # the experts with rows in [c·C, c·C + C): a contiguous range
+            dw = lax.fori_loop(jnp.sum(ends <= c * C),
+                               jnp.sum(ends - sizes < (c + 1) * C), add,
+                               tuple(dw))
+            return (dh.at[tok].add(drows.astype(jnp.float32)),
+                    dweight.at[pairs].add(dscale), *dw)
+
+        zero = [jnp.zeros(a.shape, jnp.float32)
+                for a in (h, weight, *w)]
+        dh, dweight, *dw = lax.fori_loop(0, _live_chunks(sizes, C), body,
+                                         tuple(zero))
+        return (dh.astype(h.dtype), dweight.astype(weight.dtype),
+                *(g.astype(a.dtype) for g, a in zip(dw, w)), None, None)
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
 def held_experts(h, idx, weight, lp, cfg: DeepseekV2Config):
     """The held experts' part of the routed output: h [N, d] → ([N, d],
-    pairs computed per held expert [n_held] int32). Every (token, expert)
-    pair whose expert is held is computed; the rest sort past the last
-    group, which ``ragged_dot`` leaves out."""
+    pairs computed per held expert [n_held] int32, chunks computed int32).
+    Every (token, expert) pair whose expert is held is computed. The pairs
+    are sorted by expert, held ones first, and cut into chunks of
+    :func:`chunk_rows` rows; only the chunks up to the last held pair are
+    gathered, computed through ``ragged_dot`` and scattered back to their
+    tokens, forward and backward. The rest of the list does no work."""
     N, k = idx.shape
+    C = chunk_rows(N * k, cfg)
     with jax.named_scope("moe.experts"):
         local = idx.reshape(-1) - cfg.held_lo
         group = jnp.where((local >= 0) & (local < cfg.n_held), local,
                           cfg.n_held)
         order = jnp.argsort(group, stable=True)
-        sizes = jnp.zeros((cfg.n_held + 1,), jnp.int32).at[group].add(1)
-        sizes = sizes[:cfg.n_held]
-        # The TPU's grouped product leaves the rows past the last group
-        # unwritten, in its output and in its input's gradient: device
-        # memory as it was, NaN at times. Every product's input and output
-        # pass through a select, so that nothing from those rows reaches a
-        # value or, through the selects' transposes, a gradient (a multiply
-        # by zero would keep a NaN).
-        valid = (jnp.arange(N * k) < jnp.sum(sizes))[:, None]
-
-        def grouped(x, w):
-            x = jnp.where(valid, x, 0)
-            return jnp.where(valid, lax.ragged_dot(x, w, sizes), 0)
-
-        rows = h[order // k]                                    # [N*k, d]
-        act = (jax.nn.silu(grouped(rows, lp["w_gate"]))
-               * grouped(rows, lp["w_up"]))
-        # the gate weight scales the input of the (linear) down projection
-        act = act * weight.reshape(-1)[order][:, None].astype(act.dtype)
-        out = grouped(act, lp["w_down"])
-        # back to token order, one top-k slot at a time: a [N, k, d] array
-        # would pad k to the TPU's 8 sublanes
-        back = jnp.argsort(order).reshape(N, k)
-        y = out[back[:, 0]].astype(jnp.float32)
-        for j in range(1, k):
-            y = y + out[back[:, j]]
-    return y.astype(h.dtype), sizes
+        order = jnp.pad(order, (0, -(N * k) % C))
+        sizes = jnp.sum(group[:, None] == jnp.arange(cfg.n_held), axis=0,
+                        dtype=jnp.int32)
+        y = _dispatch(h, weight.reshape(-1), lp["w_gate"], lp["w_up"],
+                      lp["w_down"], order, sizes, k, C)
+        return y.astype(h.dtype), sizes, _live_chunks(sizes, C)
 
 
 def moe_block(x, lp, cfg: DeepseekV2Config, positions, attn_fn):
-    """One expert layer: (x', seq-aux loss, pairs per held expert)."""
+    """One expert layer: (x', seq-aux loss, pairs per held expert, chunks
+    computed)."""
     lp = _low(lp, cfg)
     x = mla(x, lp, cfg, positions, attn_fn)
     B, S, d = x.shape
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps).reshape(B * S, d)
     idx, weight, aux = route(h, lp["router"], cfg, B)
-    routed, sizes = held_experts(h, idx, weight, lp, cfg)
+    routed, sizes, chunks = held_experts(h, idx, weight, lp, cfg)
     with jax.named_scope("moe.shared"):
         shared = swiglu(h, lp["shared_gate"], lp["shared_up"],
                         lp["shared_down"])
-    return x + (routed + shared).reshape(B, S, d), aux, sizes
+    return x + (routed + shared).reshape(B, S, d), aux, sizes, chunks
 
 
 def dense_block(x, lp, cfg: DeepseekV2Config, positions, attn_fn):
@@ -321,7 +462,7 @@ def dense_block(x, lp, cfg: DeepseekV2Config, positions, attn_fn):
 def hidden(params, inputs, cfg: DeepseekV2Config, attn_fn,
            remat: bool = False):
     """inputs [B, S] → (final-normed hidden [B, S, d], seq-aux [L],
-    pairs per held expert [L, n_held])."""
+    pairs per held expert [L, n_held], chunks computed [L])."""
     B, S = inputs.shape
     positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
     x = params["embed"][inputs].astype(cfg.jdtype)
@@ -333,17 +474,17 @@ def hidden(params, inputs, cfg: DeepseekV2Config, attn_fn,
         x = dense(x, lp, cfg, positions, attn_fn)
 
     def body(carry, lp):
-        y, aux, sizes = moe(carry, lp, cfg, positions, attn_fn)
-        return y, (aux, sizes)
+        y, *per_layer = moe(carry, lp, cfg, positions, attn_fn)
+        return y, per_layer
 
-    x, (aux, sizes) = lax.scan(body, x, params["moe"])
-    return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux, sizes
+    x, (aux, sizes, chunks) = lax.scan(body, x, params["moe"])
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps), aux, sizes, chunks
 
 
 def forward(params, inputs, cfg: DeepseekV2Config, attn_fn):
     """inputs [B, S] → logits [B, S, vocab] float32 (tests; the loss never
     holds all logits at once)."""
-    x, _, _ = hidden(params, inputs, cfg, attn_fn)
+    x = hidden(params, inputs, cfg, attn_fn)[0]
     with jax.named_scope("lm_head"):
         return jnp.dot(x, params["lm_head"].astype(cfg.jdtype),
                        preferred_element_type=jnp.float32)
@@ -371,13 +512,14 @@ def next_token_loss(params, tokens, cfg: DeepseekV2Config, attn_fn,
                     remat: bool = False):
     """tokens [B, S+1] (a record: inputs and the next token) → (mean
     cross-entropy + aux_alpha × Σ seq-aux, {"expert_assignments":
-    [n_moe_layers, n_held] int32})."""
+    [n_moe_layers, n_held] int32, "expert_chunks": [n_moe_layers] int32})."""
     inputs, targets = tokens[:, :-1], tokens[:, 1:]
     B, S = inputs.shape
-    x, aux, sizes = hidden(params, inputs, cfg, attn_fn, remat)
+    x, aux, sizes, live = hidden(params, inputs, cfg, attn_fn, remat)
     with jax.named_scope("lm_head"):
         chunks = math.gcd(cfg.loss_chunks, B * S)
         ce = _xent_sum(x.reshape(B * S, -1),
                        params["lm_head"].astype(cfg.jdtype),
                        targets.reshape(-1), chunks) / (B * S)
-    return ce + cfg.aux_alpha * jnp.sum(aux), {"expert_assignments": sizes}
+    return ce + cfg.aux_alpha * jnp.sum(aux), {"expert_assignments": sizes,
+                                               "expert_chunks": live}

@@ -197,8 +197,10 @@ def make_deepseek_v2_train_step(cfg, mesh: Mesh,
     """The DeepSeek-V2 step: tokens [B, S+1] P("dp") from
     ``make_llama_pipeline``, float32 masters, bfloat16 compute, per-layer
     remat, MLA through the flash kernel at the model's softmax scale.
-    Metrics: ``loss``, ``grad_norm`` and ``expert_assignments`` ([expert
-    layers, held experts] int32: the (token, held expert) pairs computed)."""
+    Metrics: ``loss``, ``grad_norm``, ``expert_assignments`` ([expert
+    layers, held experts] int32: the (token, held expert) pairs computed)
+    and ``expert_chunks`` ([expert layers] int32: the chunks of the sorted
+    pair list each layer computed, ⌈held pairs / ``chunk_rows``⌉)."""
     from strom.models import deepseek_v2
     from strom.ops.flash_attention import make_flash_attention
     from strom.parallel.sharding import deepseek_v2_param_shardings

@@ -7,6 +7,7 @@ the step's metrics, and the token pipeline at the cell's record length."""
 
 import importlib.util
 import os
+from functools import partial
 
 import numpy as np
 import optax
@@ -121,7 +122,7 @@ def test_every_rank_s_share_sums_to_the_uncut_layer():
                                            held_lo=4 * rank)
             share = {k: lp[k][4 * rank:4 * rank + 4]
                      for k in ("w_gate", "w_up", "w_down")}
-            out, n = dv.held_experts(h, idx, weight, share, cfg)
+            out, n, _ = dv.held_experts(h, idx, weight, share, cfg)
             total = total + out
             counts.append(n)
     assert rel(total, want) < 1e-5
@@ -141,16 +142,94 @@ def test_no_pair_is_dropped_when_one_expert_takes_every_token():
     weight = jax.nn.softmax(jax.random.normal(jax.random.key(9),
                                               idx.shape), -1)
     with jax.default_matmul_precision("highest"):
-        out, sizes = dv.held_experts(h, idx, weight, lp, cfg)
-        want = jnp.zeros_like(h)
-        for e in range(cfg.n_held):
-            w = jnp.sum(jnp.where(idx == e, weight, 0.0), axis=-1)
-            want = want + w[:, None] * ref.swiglu(
-                h, lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e],
-                ref._mm(None))
+        out, sizes, _ = dv.held_experts(h, idx, weight, lp, cfg)
+        want = held_reference(h, idx, weight, lp, cfg)
     assert int(sizes[0]) == N                       # expert 0: every token
     assert int(jnp.sum(sizes)) == int(jnp.sum(idx < cfg.n_held))
     assert rel(out, want) < 1e-5
+
+
+def held_reference(h, idx, weight, lp, cfg):
+    """The held experts' routed output computed densely: every token
+    through every held expert, weighted by its gate weight for it."""
+    want = jnp.zeros_like(h)
+    for e in range(cfg.n_held):
+        w = jnp.sum(jnp.where(idx == cfg.held_lo + e, weight, 0.0), axis=-1)
+        want = want + w[:, None] * ref.swiglu(
+            h, lp["w_gate"][e], lp["w_up"][e], lp["w_down"][e],
+            ref._mm(None))
+    return want
+
+
+def picks_with_held(held, cfg, n, seed):
+    """[n, top_k] distinct expert ids per token of which exactly *held*
+    pairs, spread over random tokens and slots, pick a held expert."""
+    rng = np.random.default_rng(seed)
+    others = np.arange(cfg.n_held, cfg.n_experts)
+    idx = np.stack([rng.choice(others, cfg.top_k, replace=False)
+                    for _ in range(n)]) if len(others) else \
+        np.stack([rng.permutation(cfg.n_experts)[:cfg.top_k]
+                  for _ in range(n)])
+    if len(others):
+        for p in rng.permutation(n * cfg.top_k)[:held]:
+            t, j = divmod(int(p), cfg.top_k)
+            idx[t, j] = rng.choice(np.setdiff1d(np.arange(cfg.n_held),
+                                                idx[t]))
+    assert int(np.sum(idx < cfg.n_held)) == held
+    return jnp.asarray(idx, jnp.int32)
+
+
+# T held pairs of N·k pairs at the tiny size (top-3), where chunk_rows
+# gives 16 rows with 4 of 16 held (288 pairs: whole chunks; 300: the tail
+# chunk is padded) and one chunk of every pair with all 16 held
+@pytest.mark.parametrize("held,n_held,N,C_want", [
+    (0, 4, 96, 16), (48, 4, 96, 16), (17, 4, 96, 16), (288, 16, 96, 288),
+    (290, 4, 100, 16)],
+    ids=["none", "whole_chunks", "one_past_a_chunk", "every_pair",
+         "padded_tail"])
+def test_chunked_dispatch_matches_the_dense_layer(held, n_held, N, C_want):
+    """The chunked dispatch against every token through every held expert,
+    float32 at HIGHEST: the output and the gradients of h, the gate weights
+    and the three expert weights, at T = 0, a multiple of the chunk, one
+    past a chunk, every pair held, and a last chunk past the pair list."""
+    cfg = dv.DeepseekV2Config.tiny(dtype="float32", n_held=n_held)
+    lp, _ = moe_layer(cfg)
+    h = jax.random.normal(jax.random.key(8), (N, cfg.d_model), jnp.float32)
+    C = dv.chunk_rows(N * cfg.top_k, cfg)
+    assert C == C_want
+    assert (N * cfg.top_k) % C == (12 if N == 100 else 0)
+    idx = picks_with_held(held, cfg, N, seed=held)
+    weight = jax.nn.softmax(jax.random.normal(jax.random.key(10),
+                                              idx.shape), -1)
+    cot = jax.random.normal(jax.random.key(11), h.shape)
+    experts = {k: lp[k] for k in ("w_gate", "w_up", "w_down")}
+
+    def loss(fn, h, weight, experts):
+        return jnp.sum(fn(h, idx, weight, experts, cfg) * cot)
+
+    def program(*a):
+        return dv.held_experts(*a)[0]
+
+    with jax.default_matmul_precision("highest"):
+        out, sizes, chunks = jax.jit(
+            lambda h, w, e: dv.held_experts(h, idx, w, e, cfg))(
+                h, weight, experts)
+        want = held_reference(h, idx, weight, experts, cfg)
+        grads = jax.jit(jax.grad(partial(loss, program), argnums=(0, 1, 2)))(
+            h, weight, experts)
+        want_grads = jax.grad(partial(loss, held_reference),
+                              argnums=(0, 1, 2))(h, weight, experts)
+    assert int(jnp.sum(sizes)) == held
+    assert int(chunks) == -(-held // C)
+    if held == 0:
+        np.testing.assert_array_equal(out, 0)
+        for g in jax.tree.leaves(grads):
+            np.testing.assert_array_equal(g, 0)
+        return
+    assert rel(out, want) < 1e-5
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree.leaves(want_grads)):
+        assert rel(g, w) < 1e-5, jax.tree_util.keystr(path)
 
 
 def unwritten_ragged_dot(real):
@@ -224,11 +303,33 @@ def test_step_returns_loss_grad_norm_and_assignments():
     state, m = step(state, toks)
     state, m = step(state, toks)
     assert step._cache_size() == 1
-    assert set(m) == {"loss", "grad_norm", "expert_assignments"}
+    assert set(m) == {"loss", "grad_norm", "expert_assignments",
+                      "expert_chunks"}
     counts = np.asarray(m["expert_assignments"])
     assert counts.shape == (cfg.n_moe_layers, cfg.n_held)
     assert counts.dtype == np.int32 and 0 < counts.sum() <= 2 * 128 * 3 * 2
     assert np.isfinite(float(m["loss"])) and float(m["grad_norm"]) > 0
+
+
+def test_step_counts_the_chunks_each_expert_layer_computed():
+    """expert_chunks is ⌈T / C⌉ per expert layer, T the layer's held pairs
+    and C the chunk rows at the step's 2 × 128 tokens × top-3."""
+    from strom.parallel.train import (init_deepseek_v2_train_state,
+                                      make_deepseek_v2_train_step)
+
+    cfg = dv.DeepseekV2Config.tiny()
+    mesh = Mesh(np.array(jax.devices()[:1]), ("dp",))
+    opt = optax.adamw(4.2e-4)
+    state = init_deepseek_v2_train_state(jax.random.key(1), cfg, mesh, opt)
+    step = make_deepseek_v2_train_step(cfg, mesh, opt)
+    _, m = step(state, jax.device_put(tokens(15),
+                                      NamedSharding(mesh, P("dp", None))))
+    C = dv.chunk_rows(2 * 128 * cfg.top_k, cfg)
+    held = np.asarray(m["expert_assignments"]).sum(axis=1)
+    chunks = np.asarray(m["expert_chunks"])
+    assert C == 32 and chunks.dtype == np.int32
+    assert held.min() > C and chunks.shape == (cfg.n_moe_layers,)
+    np.testing.assert_array_equal(chunks, -(-held // C))
 
 
 def test_shardings_follow_the_rules_on_a_tp_mesh():
@@ -285,3 +386,21 @@ def test_token_pipeline_at_the_cell_s_record_length(tmp_path):
                 np.testing.assert_array_equal(got, records[next(order)])
     finally:
         ctx.close()
+
+
+def test_profile_tool_types_the_expert_layer_s_operations():
+    """tools/expert_profile.py types each operation of a compiled module
+    by what it fuses: the held experts' backward keeps its sort, chunk
+    loops, row gathers and scatter-adds under ``moe.experts``."""
+    from benchmark.configs.deepseek_v2_lite_pretrain import (classify,
+                                                             scope_map)
+    from tools.expert_profile import hlo_types
+
+    lp, h = moe_layer(TINY32)
+    idx, weight, _ = dv.route(h, lp["router"], TINY32, batch=1)
+    text = jax.jit(jax.grad(lambda h, idx: jnp.sum(dv.held_experts(
+        h, idx, weight, lp, TINY32)[0]))).lower(h, idx).compile().as_text()
+    types, scopes = hlo_types(text), scope_map(text)
+    seen = {t for name, t in types.items()
+            if classify(scopes.get(name, name)) == "moe.experts"}
+    assert {"sort", "loop", "gather", "scatter"} <= seen, seen

@@ -11,6 +11,7 @@ back without one.
 
 import functools
 import os
+import re
 
 import numpy as np
 import pytest
@@ -113,6 +114,49 @@ def test_plan_tiles_fit_vmem_at_wider_heads(one_chip, dqk, dv, dtype):
                     .astype(jnp.float32).sum(), argnums=(0, 1, 2))
     text = jax.jit(grad).lower(q, q, v).compile().as_text()
     assert text.count("tpu_custom_call") >= 3
+
+
+def test_held_experts_compile_for_v5e_without_a_row_per_pair(one_chip):
+    """The expert layer at the benchmark cell's shape (32,768 tokens, top-6,
+    8 of 64 experts held), forward and backward under ``jax.checkpoint`` as
+    the step runs it: the chip's compiler takes the chunk loops around the
+    grouped products, and no array has a row for each of the 196,608
+    (token, expert) pairs: only the live chunks' rows are gathered. Nor does
+    a chunk loop's body copy an expert weight into another layout: the
+    transposed weights its backward reads are made once, outside."""
+    from strom.models import deepseek_v2 as dv
+
+    cfg = dv.DeepseekV2Config()
+    N, d, f, k = 8 * 4096, cfg.d_model, cfg.expert_ff, cfg.top_k
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(h, idx, weight, experts):
+        layer = jax.checkpoint(
+            lambda h, w, e: dv.held_experts(h, idx, w, e, cfg)[0])
+        return jnp.sum(layer(h, weight, experts).astype(jnp.float32))
+
+    experts = {"w_gate": shape(cfg.n_held, d, f),
+               "w_up": shape(cfg.n_held, d, f),
+               "w_down": shape(cfg.n_held, f, d)}
+    text = jax.jit(jax.grad(loss, argnums=(0, 2, 3))).lower(
+        shape(N, d), shape(N, k, dtype=jnp.int32),
+        shape(N, k, dtype=jnp.float32), experts).compile().as_text()
+    assert "ragged-dot" in text
+    for width in (d, f):
+        assert f"[{N * k},{width}]" not in text, width
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    weight = re.compile(rf"= bf16\[{cfg.n_held},({d},{f}|{f},{d})\]\S* "
+                        r"(copy|transpose|fusion)\(")
+    comp, copies = None, []
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$", line)
+        if head and " = " not in line:
+            comp = head.group(1)
+        elif comp in bodies and weight.search(line):
+            copies.append(line.strip()[:100])
+    assert len(bodies) >= 2 and not copies, copies
 
 
 def test_ring_flash_compiles_on_four_chips(topo):
